@@ -1,0 +1,652 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py            # one chip: phase `train`, then phase `serve`
+    python3 chip_smoke.py --chips 4  # four chips: sharded training only
+
+Drives the two main paths through the entry points a user would call, at
+the full width and depth of ``LlamaConfig.llama_1b`` with seeded random
+weights, and checks what comes out:
+
+- ``train``: ``tfcluster.run`` -> ``cluster.train`` pushes seeded token
+  records through the real data plane (driver feeder -> shm ring or
+  manager queue -> ``DataFeed.batch_stream`` -> ``DevicePrefetcher``)
+  into ``build_train_step`` on the node, which owns the chip.
+- ``serve``: a child saves a checkpoint, starts ``serve_model`` with the
+  continuous-batching engine on it, answers concurrent ``/generate``
+  requests and compares tokens and logprobs with one plain forward.
+- ``--chips 4``: the same 1B step over an ``fsdp=4`` mesh in one node
+  process, and beside it the same seeds on one device.
+
+THIS process never initialises a JAX backend: a chip belongs to one
+process at a time, so each phase runs in a process that owns it alone and
+has exited before the next starts. Children get an explicit
+``JAX_PLATFORMS``, so that "no chip" is an error and never a CPU run.
+
+Prints one JSON object per phase, then as the LAST line
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+Any failed check or exception ends the run with a non-zero exit and no
+such line.
+
+Rehearsal without the chip (explicit arguments, never a fallback)::
+
+    python3 chip_smoke.py --config tiny --platform cpu [--chips 4]
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+# `train` holds the bench's memory setting (bench.py _bench_llama): fp32
+# params, bf16 Adam moments, no remat. `logit_tol` bounds, for every
+# emitted token, max(logits) - logits[token] under the plain forward, and
+# |engine logprob - forward logprob|; `loss_rtol` bounds the fsdp=4 run's
+# loss against the one-device run's.
+CONFIGS = {
+    "1b": dict(
+        model="1b",
+        overrides={},
+        seq=1024, batch=8, warmup=3, steps=5,
+        remat="none", moments="bf16",
+        prompt_lens=(128, 200, 256, 320, 384, 512),
+        widths="128,256,512", slots=8, max_new=64,
+        # bf16 end to end: the engine's cached decode and the forward's
+        # full-sequence attention round differently
+        logit_tol=0.15, loss_rtol=1e-2,
+    ),
+    "tiny": dict(
+        model="tiny",
+        overrides={"dtype": "float32", "remat": False},
+        seq=64, batch=8, warmup=3, steps=5,
+        remat="none", moments="bf16",
+        prompt_lens=(5, 9, 12, 14, 16, 7),
+        widths="8,16", slots=4, max_new=8,
+        logit_tol=1e-3, loss_rtol=1e-4,
+    ),
+}
+COMPARE_STEPS = 3  # --chips 4: losses compared with the one-device run
+LARGE_PARAM = 4096  # elements: every matrix; norm scales stay replicated
+
+
+def emit(obj: dict) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+def require(report: dict, checks: dict) -> None:
+    """Record ``checks`` in the phase's report, print it, and fail the
+    run unless every one holds."""
+    report["checks"] = checks
+    report["ok"] = all(checks.values())
+    emit(report)
+    if not report["ok"]:
+        bad = sorted(k for k, v in checks.items() if not v)
+        raise SystemExit(f"chip_smoke: phase {report['phase']} failed: {bad}")
+
+
+def child_env(platform: str, chips: int) -> dict[str, str]:
+    """The explicit platform every child runs under. On the CPU the
+    device count is explicit too (an inherited XLA_FLAGS must not decide
+    how many devices a rehearsal sees)."""
+    env = {"JAX_PLATFORMS": platform}
+    if platform == "cpu":
+        flags = re.sub(
+            r"--xla_force_host_platform_device_count=\d+", "",
+            os.environ.get("XLA_FLAGS", ""),
+        )
+        env["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={chips}"
+        ).strip()
+    return env
+
+
+def device_report() -> dict:
+    import jax
+
+    d = jax.devices()[0]
+    return {
+        "platform": d.platform, "kind": d.device_kind,
+        "count": len(jax.devices()),
+    }
+
+
+def cache_entries(cache_dir: str) -> int:
+    return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+
+def peak_bytes() -> list:
+    """``peak_bytes_in_use`` per device (None where the backend keeps no
+    memory statistics, as the CPU's)."""
+    import jax
+
+    return [
+        (d.memory_stats() or {}).get("peak_bytes_in_use")
+        for d in jax.devices()
+    ]
+
+
+# -- phase `train` (and --chips 4) -------------------------------------------
+
+
+def _llama_config(c: dict, **kw):
+    from tensorflowonspark_tpu.tools.generate_text import _load_config
+
+    import dataclasses
+
+    return dataclasses.replace(
+        _load_config(
+            argparse.Namespace(
+                model=c["model"], config_overrides=json.dumps(c["overrides"])
+            )
+        ),
+        **kw,
+    )
+
+
+def _train_setup(c: dict, mesh, seed: int):
+    """Model, sharded state and step — wired exactly as
+    ``benchmarks/real_chip.py:bench_llama1b`` wires them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tensorflowonspark_tpu.compute import (
+        TrainState, build_train_step, optim, shard_state,
+    )
+    from tensorflowonspark_tpu.models.llama import (
+        Llama, llama_loss_fn, llama_param_shardings,
+    )
+    from tensorflowonspark_tpu.parallel import use_mesh
+
+    cfg = _llama_config(
+        c, max_seq_len=c["seq"], remat=c["remat"] != "none",
+        remat_policy=c["remat"], attention_impl="auto",
+    )
+    model = Llama(cfg)
+    with use_mesh(mesh):
+        params = model.init(
+            jax.random.PRNGKey(seed), np.zeros((2, c["seq"]), np.int32)
+        )["params"]
+    tx = optim.adamw(
+        1e-4, moment_dtype=jnp.bfloat16 if c["moments"] == "bf16" else None
+    )
+    psh = llama_param_shardings(params, mesh)
+    params = jax.tree.map(jax.device_put, params, psh)
+    state = shard_state(TrainState.create(params, tx), mesh, psh)
+    token_loss = llama_loss_fn(model)
+    step = build_train_step(
+        lambda p, bt: token_loss(p, bt["tokens"]), tx, mesh,
+        param_shardings=psh,
+    )
+    return state, step
+
+
+def _placement(state, n_devices: int) -> dict:
+    """Does every large parameter, and each Adam moment of it, have a
+    shard of 1/n of its bytes on each of n distinct devices?"""
+    import jax
+
+    def spread(tree) -> tuple[int, int]:
+        large = ok = 0
+        for leaf in jax.tree.leaves(tree):
+            if leaf.size < LARGE_PARAM:
+                continue
+            large += 1
+            shards = leaf.addressable_shards
+            ok += (
+                len({s.device for s in shards}) == n_devices
+                and all(
+                    s.data.nbytes * n_devices == leaf.nbytes for s in shards
+                )
+            )
+        return large, ok
+
+    adam = next(s for s in state.opt_state if hasattr(s, "mu"))
+    out = {}
+    for name, tree in (
+        ("params", state.params), ("mu", adam.mu), ("nu", adam.nu)
+    ):
+        large, ok = spread(tree)
+        out[name] = {"large_leaves": large, "spread_evenly": ok}
+    return out
+
+
+def train_node(args: dict, ctx) -> None:
+    """``map_fun`` of the ``train`` phase; runs in the node process, which
+    holds the chip(s)."""
+    import jax
+    import numpy as np
+
+    from tensorflowonspark_tpu import native
+    from tensorflowonspark_tpu.compute.mesh import make_mesh, shard_batch
+    from tensorflowonspark_tpu.feed import DevicePrefetcher
+    from tensorflowonspark_tpu.feed import columnar
+    from tensorflowonspark_tpu.utils.util import compile_cache_dir
+
+    c = CONFIGS[args["config"]]
+    n_dev = jax.device_count()
+    # all of this process's devices on 'fsdp', as
+    # examples/llama/llama_fsdp.py --fsdp -1 builds it
+    mesh = ctx.mesh({"fsdp": -1})
+    t0 = time.perf_counter()
+    state, step = _train_setup(c, mesh, args["seed"])
+    jax.block_until_ready(state)
+    report = {
+        "device": device_report(),
+        "mesh": {k: v for k, v in mesh.shape.items() if v > 1},
+        "n_params": sum(x.size for x in jax.tree.leaves(state.params)),
+        "init_seconds": round(time.perf_counter() - t0, 2),
+        "cache_dir": compile_cache_dir(),
+    }
+    if n_dev > 1:
+        report["placement"] = _placement(state, n_dev)
+
+    feed = ctx.get_data_feed(input_mapping={"tokens": "tokens"})
+    losses = []
+    host_batch = None
+    with DevicePrefetcher.from_feed(
+        feed, c["batch"], mesh, multiple_of=n_dev
+    ) as pf:
+        for i, batch in enumerate(pf):
+            if i == 0:
+                host_batch = {"tokens": np.asarray(batch["tokens"])}
+                t0 = time.perf_counter()
+            state, loss = step(state, batch)
+            losses.append(loss)
+            if i == 0:  # the first call compiles
+                jax.block_until_ready(loss)
+                report["first_step_seconds"] = round(
+                    time.perf_counter() - t0, 2
+                )
+            if i + 1 == c["warmup"]:
+                jax.block_until_ready(loss)
+                t0 = time.perf_counter()
+    jax.block_until_ready((state, losses))
+    dt = time.perf_counter() - t0
+    report["steps"] = len(losses)
+    report["step_seconds"] = dt / max(1, len(losses) - c["warmup"])
+    report["losses"] = [float(x) for x in losses]
+    report["peak_bytes_in_use"] = peak_bytes()
+    report["native_library"] = native.available()
+    report["frames_by_path"] = {
+        p: int(columnar.metrics()["frames"].value(path=p))
+        for p in ("shm", "tcp")
+    }
+    # the very program that just ran (jax keeps the executable: no
+    # second compile)
+    compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    report["tpu_custom_call"] = text.count("tpu_custom_call")
+    report["collectives"] = {
+        k: len(re.findall(rf"\b{k}(?:-start)?\(", text))
+        for k in ("all-gather", "all-reduce", "reduce-scatter")
+    }
+    ma = compiled.memory_analysis()
+    report["memory_analysis"] = {
+        k: getattr(ma, k + "_size_in_bytes")
+        for k in ("argument", "output", "alias", "temp")
+    }
+
+    if n_dev > 1:
+        # The same seeds on one device, in this process: the sharded
+        # state is freed first — both do not fit on device 0.
+        del state, step, batch, compiled, loss, losses
+        mesh1 = make_mesh({"fsdp": 1}, devices=jax.devices()[:1])
+        state, step = _train_setup(c, mesh1, args["seed"])
+        batch = shard_batch(mesh1, host_batch)
+        ref = []
+        for _ in range(COMPARE_STEPS):
+            state, loss = step(state, batch)
+            ref.append(float(loss))
+        report["one_device_losses"] = ref
+        report["one_device_tpu_custom_call"] = (
+            step.lower(state, batch).as_text().count("tpu_custom_call")
+        )
+
+    with open(args["report"], "w") as f:
+        json.dump(report, f)
+
+
+def phase_train(c: dict, a: argparse.Namespace, workdir: str) -> dict:
+    import numpy as np
+
+    from tensorflowonspark_tpu.cluster import tfcluster
+    from tensorflowonspark_tpu.cluster.tfcluster import InputMode
+
+    rows = np.random.default_rng(a.seed).integers(
+        0, _llama_config(c).vocab_size,
+        size=(c["batch"], c["seq"] + 1), dtype=np.int32,
+    )
+    # ONE partition of exactly one batch, fed once per epoch: every step
+    # sees the same batch, so the loss must fall
+    partitions = [[{"tokens": row} for row in rows]]
+    n_steps = c["warmup"] + c["steps"]
+    report_path = os.path.join(workdir, "train.json")
+    cluster = tfcluster.run(
+        train_node,
+        {"config": a.config, "seed": a.seed, "report": report_path},
+        num_executors=1,
+        input_mode=InputMode.SPARK,
+        env=child_env(a.platform, a.chips),
+    )
+    try:
+        cluster.train(partitions, num_epochs=n_steps)
+        dead = cluster.dead_nodes()
+    finally:
+        # EndOfFeed ends the node's batch_stream; raises what the node's
+        # error ferry carried, or a non-zero node exit
+        cluster.shutdown(timeout=1000)
+    with open(report_path) as f:
+        report = {"phase": "train", "config": a.config, **json.load(f)}
+    report["dead_nodes"] = dead
+    losses = report["losses"]
+    on_tpu = report["device"]["platform"] == "tpu"
+    checks = {
+        "steps": report["steps"] == n_steps,
+        "losses_finite": all(np.isfinite(losses)),
+        "loss_fell": losses[-1] < losses[0],
+        "error_ferry_quiet": dead == [],
+        "feed_through_data_plane": sum(report["frames_by_path"].values())
+        == n_steps,
+    }
+    if on_tpu:
+        checks["kernel_in_step"] = report["tpu_custom_call"] > 0
+        checks["peak_bytes_reported"] = all(
+            isinstance(b, int) and b > 0 for b in report["peak_bytes_in_use"]
+        )
+    if a.chips > 1:
+        ref = report["one_device_losses"]
+        checks["sharded_evenly"] = all(
+            p["large_leaves"] > 0 and p["spread_evenly"] == p["large_leaves"]
+            for p in report["placement"].values()
+        )
+        checks["collectives_in_step"] = (
+            sum(report["collectives"].values()) > 0
+        )
+        checks["losses_match_one_device"] = bool(
+            np.allclose(
+                losses[:COMPARE_STEPS], ref, rtol=c["loss_rtol"], atol=0
+            )
+        )
+        if on_tpu:
+            checks["kernel_in_one_device_step"] = (
+                report["one_device_tpu_custom_call"] > 0
+            )
+    require(report, checks)
+    return report
+
+
+# -- phase `serve` -------------------------------------------------------------
+
+
+def _http(port: int, path: str, body: dict | None = None):
+    import urllib.request
+
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}{path}",
+        data=None if body is None else json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"},
+    )
+    with urllib.request.urlopen(req, timeout=600) as r:
+        return r.status, r.read().decode()
+
+
+def serve_child(a: argparse.Namespace) -> None:
+    """Phase ``serve``; a process of its own, which holds the chip."""
+    from tensorflowonspark_tpu.utils.util import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    cached_before = cache_entries(cache_dir)
+
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tensorflowonspark_tpu.compute.checkpoint import CheckpointManager
+    from tensorflowonspark_tpu.models.llama import Llama, generate
+    from tensorflowonspark_tpu.tools import serve_model
+
+    c = CONFIGS[a.config]
+    report = {
+        "phase": "serve", "config": a.config, "device": device_report(),
+        "cache_dir": cache_dir, "cache_entries_at_start": cached_before,
+    }
+    cfg = _llama_config(c)
+    t0 = time.perf_counter()
+    params = Llama(cfg).init(
+        jax.random.PRNGKey(a.seed), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    params = jax.tree.map(lambda x: x.astype(cfg.dtype), params)
+    ckpt = os.path.join(a.workdir, "ckpt")
+    with CheckpointManager(ckpt, async_save=False) as mgr:
+        mgr.save(0, {"params": params})
+    del params
+    report["checkpoint_seconds"] = round(time.perf_counter() - t0, 2)
+
+    t0 = time.perf_counter()
+    server = serve_model.make_server(
+        None, port=0,
+        gen=dict(
+            checkpoint=ckpt, model=c["model"],
+            config_overrides=json.dumps(c["overrides"]),
+            engine="continuous", slots=c["slots"], widths=c["widths"],
+            max_new_tokens=c["max_new"], decode_block=4, pipeline_depth=2,
+            warmup=True,
+        ),
+    )
+    report["server_ready_seconds"] = round(time.perf_counter() - t0, 2)
+    port = server.server_address[1]
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    engine = server.gen_engine
+    _, params = engine.current_weights()
+    leaves = jax.tree.leaves(params)
+
+    rng = np.random.default_rng(a.seed)
+    prompts = [
+        rng.integers(0, cfg.vocab_size, size=n).tolist()
+        for n in c["prompt_lens"]
+    ]
+    answers: list = [None] * len(prompts)
+
+    def ask(i: int) -> None:
+        body = {"prompts": [prompts[i]], "logprobs": True}
+        if i == 0:  # one request streams: NDJSON, a line per token
+            status, text = _http(port, "/generate", {**body, "stream": True})
+            lines = [json.loads(x) for x in text.splitlines()]
+            done = lines[-1]
+            answers[i] = dict(
+                status=status, tokens=done["completion"],
+                logprobs=done["logprobs"],
+                streamed=[x["token"] for x in lines[:-1]],
+            )
+        else:
+            status, text = _http(port, "/generate", body)
+            out = json.loads(text)
+            answers[i] = dict(
+                status=status, tokens=out["completions"][0],
+                logprobs=out["logprobs"][0],
+            )
+
+    t0 = time.perf_counter()
+    threads = [
+        threading.Thread(target=ask, args=(i,)) for i in range(len(prompts))
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    report["requests_seconds"] = round(time.perf_counter() - t0, 2)
+    # an ask() that raised left its slot None
+    answered = all(x is not None for x in answers)
+
+    # One plain full-sequence forward (einsum attention, no cache, no
+    # kernel) of the same params over prompt + completion, right-padded
+    # to one shape: causal attention keeps the padding out of every
+    # position that is read.
+    plain = Llama(dataclasses.replace(cfg, attention_impl="xla"))
+    width = max(c["prompt_lens"]) + c["max_new"]
+    seqs = np.zeros((len(prompts), width), np.int32)
+    for i, x in enumerate(answers):
+        row = prompts[i] + list(x["tokens"] if x else [])
+        seqs[i, : len(row)] = row
+
+    @jax.jit
+    def forward(p, t):
+        lg = plain.apply({"params": p}, t).astype(jnp.float32)
+        return lg, jax.nn.log_softmax(lg, axis=-1)
+
+    logits, logp = map(np.asarray, forward(params, jnp.asarray(seqs)))
+    worst_gap = worst_lp = 0.0
+    for i, x in enumerate(answers):
+        for j, (tok, lp) in enumerate(zip(x["tokens"], x["logprobs"])):
+            at = len(prompts[i]) - 1 + j  # the position that predicts tok
+            worst_gap = max(
+                worst_gap, float(logits[i, at].max() - logits[i, at, tok])
+            )
+            worst_lp = max(worst_lp, abs(float(logp[i, at, tok]) - lp))
+    report["max_logit_gap"] = worst_gap
+    report["max_logprob_diff"] = worst_lp
+    report["logit_tol"] = c["logit_tol"]
+
+    stats_status, stats = _http(port, "/stats")
+    metrics_status, metrics = _http(port, "/metrics")
+    engine.close()
+    after = json.loads(_http(port, "/stats")[1])
+    server.shutdown()
+    server.server_close()
+    report["peak_bytes_in_use"] = peak_bytes()
+    report["engine"] = {
+        k: after.get(k)
+        for k in ("completed", "steps", "tokens_emitted", "watchdog_fires",
+                  "stopped_cleanly")
+    }
+    checks = {
+        "all_200": answered
+        and all(x["status"] == 200 for x in answers)
+        and stats_status == 200,
+        "asked_tokens": all(
+            len(x["tokens"]) == len(x["logprobs"]) == c["max_new"]
+            for x in answers
+        ),
+        "stream_matches": answers[0]["streamed"] == answers[0]["tokens"],
+        "tokens_near_argmax": worst_gap <= c["logit_tol"],
+        "logprobs_match": worst_lp <= c["logit_tol"],
+        "weights_on_device": all(
+            isinstance(x, jax.Array)
+            and x.devices() == {jax.devices()[0]}
+            for x in leaves
+        ),
+        "watchdog_quiet": after["watchdog_fires"] == 0,
+        "stopped_cleanly": after["stopped_cleanly"] is True,
+        "metrics_answer": metrics_status == 200
+        and "engine_requests_total" in metrics,
+    }
+    if cfg.dtype == jnp.float32:
+        # exact greedy equality with models/llama.py:generate holds only
+        # in float32; in bf16 a near-tie may break either way
+        checks["tokens_equal_generate"] = all(
+            np.asarray(
+                generate(
+                    Llama(cfg), params, jnp.asarray([prompts[i]]),
+                    c["max_new"],
+                )
+            )[0].tolist()
+            == x["tokens"]
+            for i, x in enumerate(answers)
+        )
+    require(report, checks)
+
+
+def phase_serve(a: argparse.Namespace, workdir: str) -> dict:
+    cmd = [
+        sys.executable, os.path.abspath(__file__), "--serve-child",
+        "--config", a.config, "--platform", a.platform,
+        "--seed", str(a.seed), "--workdir", workdir,
+    ]
+    out = subprocess.run(
+        cmd, env={**os.environ, **child_env(a.platform, 1)},
+        stdout=subprocess.PIPE, text=True, cwd=HERE,
+    )
+    sys.stdout.write(out.stdout)
+    sys.stdout.flush()
+    if out.returncode != 0:
+        raise SystemExit(f"chip_smoke: serve child exited {out.returncode}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+# -- parent ------------------------------------------------------------------
+
+
+def main(argv: list[str] | None = None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only sharded training over an fsdp=4 mesh "
+                    "and its one-device comparison")
+    ap.add_argument("--config", choices=sorted(CONFIGS), default="1b",
+                    help="tiny: rehearsal size")
+    ap.add_argument("--platform", choices=("tpu", "cpu"), default="tpu",
+                    help="cpu: rehearsal; the children then run on the "
+                    "CPU backend with --chips virtual devices")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--serve-child", action="store_true",
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--workdir", help=argparse.SUPPRESS)
+    a = ap.parse_args(argv)
+
+    if a.serve_child:
+        serve_child(a)
+        return
+
+    # fails here, before anything starts, where the program is absent
+    from tensorflowonspark_tpu.utils.util import compile_cache_dir
+
+    c = CONFIGS[a.config]
+    parent = {"phase": "parent", "cache_dir": compile_cache_dir()}
+    workdir = tempfile.mkdtemp(prefix="chip_smoke_")
+    try:
+        reports = [phase_train(c, a, workdir)]
+        if a.chips == 1:
+            parent["cache_entries_after_train"] = cache_entries(
+                parent["cache_dir"]
+            )
+            reports.append(phase_serve(a, workdir))
+    finally:
+        shutil.rmtree(workdir, ignore_errors=True)
+
+    from jax._src import xla_bridge  # no public way to ask without initialising
+
+    device = reports[0]["device"]
+    checks = {
+        "one_device_in_every_phase": all(
+            r["device"] == device for r in reports
+        ),
+        "platform_asked_for": device["platform"] == a.platform,
+        "count_asked_for": device["count"] == a.chips,
+        "parent_off_jax": not xla_bridge.backends_are_initialized(),
+    }
+    if a.chips == 1 and a.config != "tiny":
+        # (a rehearsal's sub-second compiles stay under JAX's threshold
+        # for writing an entry: nothing to require of it)
+        checks["serve_saw_train_cache_entries"] = (
+            reports[1]["cache_entries_at_start"]
+            >= parent["cache_entries_after_train"]
+            > 0
+        )
+    require(parent, checks)
+    emit({"ok": True, "device": device})
+
+
+if __name__ == "__main__":
+    main()

@@ -2,7 +2,7 @@
 
 Reference parity: the estimator-path examples
 (``examples/mnist/estimator/mnist_spark.py`` + ``pipeline.TFEstimator``,
-SURVEY.md §2.4/§3.4) applied to the BASELINE.md "BERT-base fine-tune via
+SURVEY.md §2.4/§3.4) applied to the BASELINE.json "BERT-base fine-tune via
 the Estimator pipeline" config. Synthetic task: sequence classification
 where the label is derivable from token statistics, so loss actually drops.
 

@@ -1,4 +1,4 @@
-"""Llama fine-tune with FSDP over the ICI mesh — the BASELINE.md headline.
+"""Llama fine-tune with FSDP over the ICI mesh — the headline config.
 
 Reference parity: there is no reference equivalent (TFoS topped out at
 data-parallel, SURVEY.md §2.3); this is the config BASELINE.json adds:
@@ -7,7 +7,9 @@ scales from a tiny CPU smoke run to the real thing by flags: mesh axes,
 model size, remat, and checkpoint/resume are all config.
 
 MFU accounting: 6*P*T model flops per token (fwd+bwd) over the measured
-step time, against per-chip peak (float from --peak-tflops; v4 bf16 = 275).
+step time, against the per-chip peak ``benchmarks/peaks.py`` publishes for
+the device's ``device_kind`` (a TPU kind missing there is an error; on the
+CPU no utilisation is computed).
 
 Usage::
 
@@ -255,17 +257,23 @@ def main_fun(args, ctx):
 
     step_time = dt / args.steps
     tokens_per_step = args.batch_size * args.seq
-    model_flops = 6 * n_params * tokens_per_step  # fwd+bwd, no attn term
-    mfu = model_flops / step_time / jax.device_count() / (
-        args.peak_tflops * 1e12
-    )
-    print(
-        f"node{ctx.executor_id}: {n_params / 1e6:.1f}M params, "
+    device = jax.devices()[0]
+    line = (
+        f"node{ctx.executor_id}: {n_params / 1e6:.1f}M params on "
+        f"{jax.device_count()} x {device.device_kind}, "
         f"step {step_time * 1e3:.1f}ms, "
         f"{tokens_per_step / step_time:.0f} tokens/sec "
-        f"({tokens_per_step / step_time / jax.device_count():.0f} /chip), "
-        f"MFU {mfu * 100:.1f}%"
+        f"({tokens_per_step / step_time / jax.device_count():.0f} /chip)"
     )
+    if device.platform == "tpu":
+        from benchmarks.peaks import peak_for
+
+        model_flops = 6 * n_params * tokens_per_step  # fwd+bwd, no attn term
+        mfu = model_flops / step_time / jax.device_count() / (
+            peak_for(device).bf16_tflops * 1e12
+        )
+        line += f", MFU {mfu * 100:.1f}%"
+    print(line)
     if ckpt is not None:
         # Single-controller: chief-only (independent replicas would race
         # on the directory). Multi-controller: collective all-process save
@@ -463,9 +471,6 @@ def parse_args(argv=None):
         "--quantize-decode",
         action="store_true",
         help="int8 weight-only storage for the --generate decode pass",
-    )
-    p.add_argument(
-        "--peak-tflops", type=float, default=275.0, help="per-chip bf16 peak"
     )
     p.add_argument(
         "--remat", choices=("full", "dots", "none"), default="full",

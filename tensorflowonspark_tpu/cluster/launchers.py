@@ -45,11 +45,31 @@ class LocalLauncher:
         env: dict[str, str] | None = None,
     ) -> None:
         merged = {**self.env, **(env or {})}
+        # What the nodes will ask JAX for: their JAX_PLATFORMS, else
+        # what jax defaults to on this host — "tpu,cpu" where it sees
+        # TPU chips, read here from this process's import of it.
+        import jax
+
+        platforms = (
+            merged.get("JAX_PLATFORMS")
+            or os.environ.get("JAX_PLATFORMS")
+            or jax.config.jax_platforms
+            or ""
+        )
+        if num_nodes > 1 and "tpu" in platforms.split(","):
+            # A host's chips belong to ONE process at a time: every
+            # further node would fail in libtpu ("multi-process
+            # lockfile") at its first jax call. Say so before any starts.
+            raise ValueError(
+                f"{num_nodes} node processes on this host would all ask "
+                f"for its TPU (JAX platforms {platforms!r}); run ONE node "
+                "process per host — it drives all of the host's chips — "
+                "or launch CPU-only nodes with env=cpu_only_env()"
+            )
         ctx = mp.get_context("spawn")
-        # Env vars must be in place BEFORE the child interpreter boots:
-        # sitecustomize-style hooks (e.g. TPU plugin registration) run at
-        # interpreter start, long before _child_main gets to apply env.
-        # Spawn inherits the parent's environ at exec, so set/restore here.
+        # Env vars must be in place BEFORE the child interpreter boots
+        # (jax snapshots JAX_PLATFORMS/XLA_FLAGS at import). Spawn
+        # inherits the parent's environ at exec, so set/restore here.
         saved = {k: os.environ.get(k) for k in merged}
         os.environ.update(merged)
         try:

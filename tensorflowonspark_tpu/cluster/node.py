@@ -238,8 +238,10 @@ def run_node(
     # 5. run the user fn; ferry exceptions to the driver via the error queue
     #    (reference: the 'error' queue contract in TFSparkNode)
     try:
+        util.enable_compile_cache()
         if cluster_meta.get("auto_initialize_distributed", True):
             ctx.initialize_distributed()
+        _claim_accelerator()
         map_fun(tf_args, ctx)
         publish_node_state(mgr, "finished")
     except Exception as map_err:
@@ -259,6 +261,26 @@ def run_node(
     # 6. linger until the driver collected results and posted STOP, so the
     #    output queue (which lives in this process) survives until drained
     _await_stop(mgr, timeout=cluster_meta.get("linger_secs", 1800))
+
+
+def _claim_accelerator() -> None:
+    """A node whose JAX platforms name the TPU (``JAX_PLATFORMS``, or
+    jax's own default on a host where it sees TPU chips) takes its chips
+    NOW, not at ``map_fun``'s first jax call: a chip belongs to one
+    process at a time, so a node that cannot get it (another process
+    holds it: libtpu's "multi-process lockfile" error) must fail before
+    ``map_fun`` spends minutes on set-up. Runs inside ``run_node``'s
+    error ferry, so the driver sees the reason. CPU-only nodes are
+    untouched: their ``map_fun`` may never use JAX at all."""
+    import jax
+
+    if "tpu" not in (jax.config.jax_platforms or "").split(","):
+        return
+    devices = jax.local_devices()
+    logger.info(
+        "holding %d %s device(s): %s",
+        len(devices), devices[0].platform, devices[0].device_kind,
+    )
 
 
 def _start_heartbeater(

@@ -1912,11 +1912,11 @@ def run(
         launcher = LocalLauncher()
     try:
         # env rides the launch call (never mutate a caller's launcher):
-        # per-node interpreters must see it at boot, when TPU-plugin
-        # sitecustomize hooks run. Custom launchers advertise support by
-        # accepting an `env` kwarg; silently dropping it could let boot
-        # hooks dial the chip from processes the caller wanted CPU-only,
-        # so an env-less launcher + env is a loud error.
+        # per-node interpreters must see it at boot, when jax reads
+        # JAX_PLATFORMS. Custom launchers advertise support by accepting
+        # an `env` kwarg; silently dropping it could let processes the
+        # caller wanted CPU-only take the chip, so an env-less launcher
+        # + env is a loud error.
         import inspect
 
         sig = inspect.signature(launcher.launch).parameters

@@ -214,20 +214,13 @@ class CheckpointManager:
 
             def do_restore():
                 failpoint("checkpoint.restore")
-                try:
-                    return self._mgr.restore(step)
-                except KeyError:
-                    # Layout drift shim (the utils/compat.py probe
-                    # pattern): a CheckpointManager-written step stores
-                    # its tree under the composite item name "default",
-                    # and current orbax refuses an args-less restore on
-                    # a manager that has not saved in this process ("no
-                    # handler registered for item 'default'"). Naming
-                    # the handler explicitly restores the same tree on
-                    # every orbax version that has StandardRestore.
-                    return self._mgr.restore(
-                        step, args=ocp.args.StandardRestore()
-                    )
+                # A CheckpointManager-written step stores its tree under
+                # the composite item name "default"; naming the handler
+                # restores it on a manager that has not saved in this
+                # process without orbax having to guess one.
+                return self._mgr.restore(
+                    step, args=ocp.args.StandardRestore()
+                )
 
         return _IO_RETRY.call(
             do_restore, retry_on=_IO_RETRYABLE, site="checkpoint.restore"

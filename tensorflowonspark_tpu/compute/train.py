@@ -273,7 +273,9 @@ def build_train_step(
 
     compiled: dict[str, Any] = {}
 
-    def wrapped(state: TrainState, batch):
+    def jitted(state: TrainState):
+        """The jitted step, built from the first state seen (concrete
+        or abstract: only its tree structure and shapes are read)."""
         if "fn" not in compiled:
             psh = (
                 param_shardings
@@ -290,19 +292,25 @@ def build_train_step(
                 zero_sharding=zero_sharding,
             )
             state_sh = state_shardings(state, mesh, psh, zero_sharding)
+            compiled["state_sh"] = state_sh
             compiled["fn"] = jax.jit(
                 step,
                 in_shardings=(state_sh, batch_sharding(mesh)),
                 out_shardings=(state_sh, replicated(mesh)),
                 donate_argnums=(0,) if donate else (),
             )
+        return compiled["fn"]
+
+    def wrapped(state: TrainState, batch):
+        fn = jitted(state)
+        if "n" not in compiled:
             # First-call commit: a state built without shard_state
             # (moments inherit the PARAM placement via zeros_like)
             # arrives committed off the ZeRO layout, which explicit
             # in_shardings reject rather than silently reshard.
             # device_put is a no-op for already-matching leaves, and
             # every subsequent step's input is this step's output.
-            state = jax.tree.map(jax.device_put, state, state_sh)
+            state = jax.tree.map(jax.device_put, state, compiled["state_sh"])
         # Host-side step span (obs/): measures DISPATCH time — jit
         # returns as soon as the computation is enqueued, so the
         # data-wait vs step split reads as "host blocked here" only
@@ -312,8 +320,14 @@ def build_train_step(
         # state.step: fetching the device scalar per step would sync.
         n = compiled["n"] = compiled.get("n", 0) + 1
         with obs_spans.get_tracer().step_span("train.step", step_num=n):
-            return compiled["fn"](state, batch)
+            return fn(state, batch)
 
+    # ``jax.jit``'s own AOT door on the very program ``wrapped`` runs:
+    # ``step.lower(state, batch).compile()`` gives ``as_text()`` (is the
+    # kernel in it? which collectives?) and ``memory_analysis()``.
+    # Arguments may be ``jax.ShapeDtypeStruct`` trees, so a step can be
+    # compiled for a mesh of described devices that holds no array.
+    wrapped.lower = lambda state, batch: jitted(state).lower(state, batch)
     return wrapped
 
 

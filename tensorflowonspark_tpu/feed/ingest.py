@@ -1,9 +1,11 @@
 """Driverless pull ingestion — executor-local sharded columnar readers.
 
-BASELINE.md's push-plane ceiling shows why this module exists: every
-byte of ``InputMode.SPARK`` crosses the single driver process, and the
-measured aggregate *collapses* as the cluster grows (661 MB/s at 4
-nodes → 344 at 8). The reference never had the problem because its feed
+The push plane's ceiling shows why this module exists: every byte of
+``InputMode.SPARK`` crosses the single driver process, and the aggregate
+*collapses* as the cluster grows (CPU-host rows of
+``benchmarks/feed_plane.py`` in
+``benchmarks/results/feed_plane_scaling.jsonl``). The reference never
+had the problem because its feed
 tasks ran on the executors with HDFS locality — the driver shipped
 closures, not bytes (SURVEY.md §3.2); tf.data (arXiv:2101.12127) makes
 the same move with source sharding + per-host pipelines, and the

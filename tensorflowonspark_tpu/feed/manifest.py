@@ -1,8 +1,8 @@
 """Manifest feeding — node-side feeders over the push control plane.
 
-The measured push-plane ceiling (BASELINE.md "Push-plane ceiling",
-`benchmarks/feed_plane.py`) is ~0.5–0.7 GB/s aggregate from one driver
-host: every byte of ``InputMode.SPARK`` crosses the driver. The
+The push plane has a ceiling per driver host (`benchmarks/feed_plane.py`
+measures it on a CPU host; rows in `benchmarks/results/`): every byte of
+``InputMode.SPARK`` crosses the driver. The
 reference never had this problem because its feed tasks ran *on the
 executors* with HDFS data locality — the driver shipped closures, not
 bytes (SURVEY.md §3.2).

@@ -2,7 +2,7 @@
 
 The reference's "models" were its examples (SURVEY.md §2.4: MNIST keras/
 estimator, U-Net segmentation, cifar10/inception legacy); the rebuild's
-baseline configs add ResNet-50, BERT-base, and Llama-2 (BASELINE.md). All
+baseline configs add ResNet-50, BERT-base, and Llama-2 (BASELINE.json). All
 models are flax.linen modules designed for bf16 MXU math and mesh sharding
 (see each model's ``param_shardings``).
 """

@@ -1,4 +1,4 @@
-"""BERT encoder family (BERT-base is the BASELINE.md text/estimator config).
+"""BERT encoder family (BERT-base is the BASELINE.json text/estimator config).
 
 Parity note: the reference had no transformer models of its own — its
 "models" layer was the examples tree (SURVEY.md §2.4) and the estimator

@@ -11,7 +11,7 @@ TPU-first design notes:
 - Megatron-style mesh sharding rules in :func:`llama_param_shardings`:
   'fsdp' shards every matrix's non-TP dimension; 'model' (TP) shards
   attention heads and MLP hidden. DP/FSDP is the parity target
-  (BASELINE.md Llama-2-7B config); TP rules ship so scaling past FSDP is a
+  (BASELINE.json Llama-2-7B config); TP rules ship so scaling past FSDP is a
   sharding change, not a rewrite (SURVEY.md §2.3 implication).
 """
 
@@ -118,7 +118,7 @@ class LlamaConfig:
 
     @staticmethod
     def llama_1b(**overrides) -> "LlamaConfig":
-        """The BASELINE.md single-chip benchmark config (953M params)."""
+        """The single-chip benchmark config (953M params)."""
         base = dict(
             vocab_size=32000,
             hidden_size=2048,

@@ -1,4 +1,4 @@
-"""ResNet-v1.5 family (ResNet-50 is the BASELINE.md image config).
+"""ResNet-v1.5 family (ResNet-50 is the BASELINE.json image config).
 
 Parity note: the reference's image-classification story was the
 Inception/cifar10 example trees and the "near-linear scaling" README chart

@@ -213,7 +213,7 @@ def _build_llama(variant, tiny):
         cfg = L.LlamaConfig.mistral_7b()
     elif variant == "qwen2_7b":
         cfg = L.LlamaConfig.qwen2_7b()
-    else:  # llama_1b (the BASELINE.md benchmark config)
+    else:  # llama_1b (the single-chip benchmark config)
         cfg = L.LlamaConfig.llama_1b()
     model = L.Llama(cfg)
 

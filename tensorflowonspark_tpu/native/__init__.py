@@ -9,15 +9,19 @@ reference delegated to external native code (SURVEY.md §2.2):
   fast path (replaces the reference's pickle+socket proxy hot loop,
   SURVEY.md §3.2).
 
-The library is compiled on demand with the toolchain's ``g++`` (cached
-next to the sources, rebuilt when they change). Callers must tolerate
-``load_library()`` returning None — every user has a pure-Python
-fallback, so the framework works without a C++ toolchain.
+The library is compiled on demand with the toolchain's ``g++`` and cached
+next to the sources under a name that carries a hash of those sources
+and of the compile command: a product built from anything else — a stale
+copy whose mtime a file copy made newer than the sources, say — is
+never loaded. Callers must tolerate ``load_library()`` returning None —
+every user has a pure-Python fallback, so the framework works without a
+C++ toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import platform
@@ -28,8 +32,7 @@ logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ("tfrecord.cc", "shmring.cc")
-_HEADERS = ("crc32c.h",)  # staleness check only; not on the compile line
-_LIB_NAME = "libtfos_native.so"
+_HEADERS = ("crc32c.h",)  # part of the build key; not on the compile line
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -42,17 +45,18 @@ def _build_dir() -> str:
     return d
 
 
-def _needs_build(lib_path: str) -> bool:
-    if not os.path.exists(lib_path):
-        return True
-    lib_mtime = os.path.getmtime(lib_path)
-    return any(
-        os.path.getmtime(os.path.join(_DIR, s)) > lib_mtime
-        for s in _SOURCES + _HEADERS
-    )
+def build_key(paths, cmd) -> str:
+    """Short hash of the source files' bytes and the compile command —
+    the part of a build product's name that ties it to what it was
+    built from."""
+    h = hashlib.sha256(" ".join(cmd).encode())
+    for path in paths:
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:12]
 
 
-def _compile(lib_path: str) -> None:
+def _compile_cmd() -> list[str]:
     cmd = [
         os.environ.get("CXX", "g++"),
         "-O3",
@@ -64,24 +68,33 @@ def _compile(lib_path: str) -> None:
     if platform.machine() in ("x86_64", "AMD64"):
         cmd.append("-msse4.2")  # hardware crc32c
     cmd += [os.path.join(_DIR, s) for s in _SOURCES]
-    cmd += ["-o", lib_path, "-lrt", "-pthread"]
+    return cmd
+
+
+def _compile(cmd: list[str], lib_path: str) -> None:
+    cmd = cmd + ["-o", lib_path, "-lrt", "-pthread"]
     logger.info("building native library: %s", " ".join(cmd))
     subprocess.run(cmd, check=True, capture_output=True, text=True)
 
 
 def load_library() -> ctypes.CDLL | None:
-    """Build (if stale) and dlopen the native library; None on failure."""
+    """Build (unless this very source was built already) and dlopen the
+    native library; None on failure."""
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        lib_path = os.path.join(_build_dir(), _LIB_NAME)
         try:
-            if _needs_build(lib_path):
+            cmd = _compile_cmd()
+            key = build_key(
+                [os.path.join(_DIR, s) for s in _SOURCES + _HEADERS], cmd
+            )
+            lib_path = os.path.join(_build_dir(), f"libtfos_native-{key}.so")
+            if not os.path.exists(lib_path):
                 tmp = lib_path + f".tmp.{os.getpid()}"
-                _compile(tmp)
+                _compile(cmd, tmp)
                 os.replace(tmp, lib_path)  # atomic vs concurrent builders
             lib = ctypes.CDLL(lib_path)
             _bind(lib)

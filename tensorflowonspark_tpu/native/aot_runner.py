@@ -2,8 +2,9 @@
 
 The runner binary itself never touches Python — this module only
 discovers the TensorFlow pip package's headers/libraries, compiles the
-binary on demand (cached in ``native/build/``), and offers a subprocess
-convenience wrapper for tests and tooling.
+binary on demand (cached in ``native/build/`` under a name keyed on the
+source, like the native library), and offers a subprocess convenience
+wrapper for tests and tooling.
 """
 
 from __future__ import annotations
@@ -33,8 +34,9 @@ def _tf_base() -> str | None:
 
 
 def build_runner() -> str | None:
-    """Compile (if stale) and return the runner binary path; None when
-    TensorFlow or the C++ toolchain is unavailable."""
+    """Compile (unless this very source was built already) and return the
+    runner binary path; None when TensorFlow or the C++ toolchain is
+    unavailable."""
     global _bin, _build_failed
     if _bin is not None or _build_failed:
         return _bin
@@ -50,25 +52,26 @@ def build_runner() -> str | None:
             _DIR, "build"
         )
         os.makedirs(build_dir, exist_ok=True)
-        bin_path = os.path.join(build_dir, _BIN_NAME)
-        if not os.path.exists(bin_path) or os.path.getmtime(
-            bin_path
-        ) < os.path.getmtime(_SOURCE):
+        cmd = [
+            os.environ.get("CXX", "g++"),
+            "-O2",
+            "-std=c++17",
+            "-Wall",
+            _SOURCE,
+            f"-I{os.path.join(base, 'include')}",
+            f"-L{base}",
+            "-l:libtensorflow_cc.so.2",
+            "-l:libtensorflow_framework.so.2",
+            f"-Wl,-rpath,{base}",
+        ]
+        from tensorflowonspark_tpu.native import build_key
+
+        bin_path = os.path.join(
+            build_dir, f"{_BIN_NAME}-{build_key([_SOURCE], cmd)}"
+        )
+        if not os.path.exists(bin_path):
             tmp = bin_path + f".tmp.{os.getpid()}"  # atomic vs concurrent builders
-            cmd = [
-                os.environ.get("CXX", "g++"),
-                "-O2",
-                "-std=c++17",
-                "-Wall",
-                _SOURCE,
-                f"-I{os.path.join(base, 'include')}",
-                f"-L{base}",
-                "-l:libtensorflow_cc.so.2",
-                "-l:libtensorflow_framework.so.2",
-                f"-Wl,-rpath,{base}",
-                "-o",
-                tmp,
-            ]
+            cmd += ["-o", tmp]
             logger.info("building aot_runner: %s", " ".join(cmd))
             try:
                 subprocess.run(cmd, check=True, capture_output=True, text=True)

@@ -14,9 +14,8 @@ was captured for directly:
    each event's self time subtracts its nested children.
 2. **Attribution** (:func:`attribution`): every op classified into
    MXU/matmul, vector/fusion, copy/layout, infeed/outfeed, collective,
-   or host — the breakdown that turns "58.1% MFU with a 42% non-MXU
-   residual" from a mystery into a table (which round 5 could not
-   produce; VERDICT.md).
+   or host — the breakdown that turns "an MFU with a large non-MXU
+   residual" from a mystery into a table.
 3. **Report artifact** (:func:`build_report` / :func:`write_report`):
    one JSON dict with lane totals, top ops, and the attribution table —
    what ``bench.py`` commits under ``benchmarks/results/`` on every

@@ -1,12 +1,13 @@
 """Pallas TPU kernels for BatchNorm channel statistics.
 
-Why (measured, rounds 3-4, real v5e chip): the ResNet-50 train step spends
+Why (figures from traces taken before PR 1 on an earlier installation;
+not measured on this one): the ResNet-50 train step spent
 ~45% of its time in XLA's `convert_reduce_fusion` ops — the BN statistics
 reductions. The op *count* (~2 fused passes per BN layer) shows XLA already
 merges the sibling reductions; the *rate* is the problem: the 97 reduce
 fusions move ~9-14 GB of activations but take 44.5 ms/step, i.e. ~20-30%
-of the chip's HBM streaming bandwidth (`benchmarks/results/` traces,
-BASELINE.md analysis). These kernels pin the streaming loop explicitly —
+of the chip's HBM streaming bandwidth. These kernels pin the streaming
+loop explicitly —
 one DMA'd (block_rows x block_cols) bf16 tile per grid step, fp32
 accumulation in registers, per-channel partial sums revisiting a
 VMEM-resident output block — so the stats passes run at the DMA rate the
@@ -23,13 +24,13 @@ caller derives ``sum(dy*xhat) = invstd * (sum(dy*x) - mean*sum(dy))`` in
 fp32 (same cancellation class as the one-pass variance, accepted and
 documented in ops/batch_norm.py).
 
-Round-5 status (measured, real v5e chip): IN-CONTEXT these kernels
-REGRESS — ResNet-50 8.9% MFU vs 16.1% through the XLA reduces,
+Status (same earlier installation): IN-CONTEXT these kernels
+REGRESSED — ResNet-50 8.9% MFU vs 16.1% through the XLA reduces,
 Inception-v3 13.7% vs 18.2%. The "slow" reduce fusions were amortized:
 fused with neighboring elementwise work over conv outputs still resident
 in the fusion; an opaque ``pallas_call`` severs that and forces extra
 materialized activation round-trips that outweigh the streamed reduce's
-rate win (full post-mortem in BASELINE.md). ``impl='auto'`` therefore
+rate win. ``impl='auto'`` therefore
 never picks these kernels; they remain for explicit standalone-stats
 callers, where ``cross_stats`` measured ~2x the XLA reduce rate in
 isolation.
@@ -158,14 +159,14 @@ def cross_stats(dy: jax.Array, x: jax.Array) -> tuple[jax.Array, jax.Array]:
 def use_pallas(impl: str = "auto") -> bool:
     """'pallas' | 'xla' | 'auto'.
 
-    'auto' now ALWAYS resolves to the XLA sibling reduces. The round-5
-    chip A/B falsified the kernels' in-context premise: ResNet-50
+    'auto' ALWAYS resolves to the XLA sibling reduces. A chip A/B on an
+    earlier installation (before PR 1; not measured on this one)
+    falsified the kernels' in-context premise: ResNet-50
     measured 8.9% MFU through these kernels vs 16.1% through the XLA
     stats path (Inception-v3: 13.7% vs 18.2%) — an opaque
     ``pallas_call`` severs XLA's producer/consumer fusion around each
     BN layer, and the extra materialized activation round-trips cost
-    more than the streamed reduce saves (BASELINE.md, "Where the
-    ResNet-50 step goes"). The kernels remain for explicit
+    more than the streamed reduce saves. The kernels remain for explicit
     ``impl='pallas'`` callers that use the stats standalone (the bwd
     ``cross_stats`` pair measured ~2× the XLA reduce rate in
     isolation) — where there is no surrounding fusion to sever.

@@ -22,8 +22,8 @@ a single read-only copy. The TPU shape of the idea:
 
 Reference parity note: the reference delegated all training machinery
 to TF and had no parameter-efficient path (SURVEY.md §2.3); this is
-capability beyond it, motivated by the same HBM arithmetic as
-BASELINE.md's optimizer-state study.
+capability beyond it, motivated by the same HBM arithmetic as the
+optimizer-state footprint (``compute/optim.py``).
 """
 
 from __future__ import annotations

@@ -1,8 +1,9 @@
 """Int8 weight-only quantization for inference (decode is HBM-bound).
 
 KV-cache decode reads every weight once per generated token, so the
-resident weight bytes ARE the decode cost floor (BASELINE.md measures
-llama1b decode at ~62% of HBM bandwidth). Per-output-channel symmetric
+resident weight bytes ARE the decode cost floor (the share of HBM
+bandwidth llama1b decode reaches is not measured on this
+installation). Per-output-channel symmetric
 int8 storage halves that footprint: a 7B model's weights drop from
 ~13 GB bf16 to ~6.7 GB — the difference between fitting and not fitting
 a 16 GB chip next to its KV cache.

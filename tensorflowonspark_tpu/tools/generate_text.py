@@ -403,6 +403,9 @@ def main(argv: list[str] | None = None) -> int:
     import jax
 
     from tensorflowonspark_tpu.models.llama import Llama
+    from tensorflowonspark_tpu.utils.util import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.batch_size < 1:
         raise SystemExit("--batch-size must be >= 1")

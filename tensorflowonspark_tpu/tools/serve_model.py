@@ -2102,6 +2102,9 @@ def main(argv: list[str] | None = None) -> int:
             "(only the continuous engine hot-swaps weights)"
         )
     logging.basicConfig(level=logging.INFO)
+    from tensorflowonspark_tpu.utils.util import enable_compile_cache
+
+    enable_compile_cache()
     admin_token = None
     if args.admin_token_file:
         with open(args.admin_token_file, encoding="utf-8") as f:

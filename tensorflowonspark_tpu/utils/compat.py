@@ -10,14 +10,12 @@ The reference papered over TF 2.0/2.1 API drift (``export_saved_model``,
   off. Kept callable so reference-shaped user code ports unchanged.
 - ``is_gpu_available`` → accelerator probe.
 
-This module is also the ONE sanctioned home for jax private/moved-API
-access (``tools/tfoslint.py`` rule JX002 enforces it): symbols that have
-moved between jax releases — ``shard_map`` graduated from
-``jax.experimental.shard_map`` to top-level ``jax.shard_map`` with its
-``check_rep`` kwarg renamed to ``check_vma`` — are imported from here,
-never spelled directly at call sites. A jax too old for either location
-raises at CALL time with an actionable message instead of an
-``AttributeError`` at import/trace time.
+This module is also the ONE sanctioned import site for jax symbols that
+have moved between releases (``tools/tfoslint.py`` rule JX002,
+``pyproject.toml`` ``moved_jax_symbols``): call sites import
+``shard_map`` and ``axis_size`` from here, never from jax directly, so
+the next move is a one-file change. The code targets the one jax that
+is installed; there are no branches for others.
 """
 
 from __future__ import annotations
@@ -29,57 +27,22 @@ from tensorflowonspark_tpu.utils.device_info import (  # noqa: F401
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map`` (new-style keyword signature).
-
-    jax >= 0.5 exposes ``jax.shard_map`` (replication checking under
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map`` whose
-    equivalent kwarg is ``check_rep``. Callers use the new spelling and
-    this shim maps it back for old jax.
-    """
+    """``jax.shard_map`` (keyword signature, replication checking under
+    ``check_vma``)."""
     import jax
 
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        # The top-level promotion and the check_rep→check_vma rename
-        # landed in DIFFERENT jax releases: probe the accepted kwarg,
-        # don't infer it from the symbol's location.
-        try:
-            return fn(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma,
-            )
-        except TypeError as e:
-            if "check_vma" not in str(e):
-                raise
-            return fn(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma,
-            )
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except ImportError as e:  # pragma: no cover - ancient jax
-        raise RuntimeError(
-            "this jax has neither jax.shard_map nor "
-            "jax.experimental.shard_map; install jax >= 0.4.30"
-        ) from e
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
 
 
 def axis_size(axis_name: str) -> int:
     """Static size of a named mesh axis, inside a ``shard_map``/vmapped
-    body. ``jax.lax.axis_size`` only exists on newer jax; on 0.4.x the
-    long-standing idiom ``lax.psum(1, axis)`` constant-folds to the same
-    static int (the input is a Python scalar, so no collective runs).
-    """
-    from jax import lax
+    body."""
+    import jax
 
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def export_saved_model(state, export_dir: str, **kwargs) -> str:

@@ -3,9 +3,11 @@
 Reference parity: ``tensorflowonspark/gpu_info.py`` (``get_gpus`` parsed
 nvidia-smi, randomly picked free GPUs with retries, and emitted
 ``CUDA_VISIBLE_DEVICES``). On TPU there is no multi-tenant allocation race
-to dodge: libtpu owns the host's chips and hands each process its local
-set. What remains useful is discovery, visibility control for
-tests/colocated processes, and a capability probe.
+to dodge: libtpu hands all of a host's chips to the ONE process that
+loads it (a second process that asks fails within seconds on libtpu's
+lock file), so a host runs one accelerator process; any other is
+launched with ``utils.util.cpu_only_env()``. What remains useful is discovery and a
+capability probe.
 """
 
 from __future__ import annotations
@@ -22,8 +24,7 @@ def get_gpus(num_gpu: int = 1, worker_index: int = -1) -> str:
     """Compatibility shim for reference callers: returns a CSV of local
     device ordinals (the string the reference put in CUDA_VISIBLE_DEVICES).
 
-    On TPU hosts this is ``TPU_VISIBLE_CHIPS`` material; on CPU it is
-    informational only.
+    Informational only: one process drives all of a TPU host's chips.
     """
     devices = get_local_devices()
     n = min(num_gpu, len(devices))
@@ -126,17 +127,3 @@ def multiprocess_collectives_supported(timeout: float = 120.0) -> bool:
         "supported" if ok else "NOT supported",
     )
     return ok
-
-
-def set_visible_chips(chips: str | None) -> None:
-    """Restrict which TPU chips this process binds (set BEFORE jax init).
-
-    The moral replacement for the reference writing CUDA_VISIBLE_DEVICES in
-    ``TFSparkNode._mapfn``: on multi-process-per-host TPU setups each
-    process pins its chip subset.
-    """
-    if chips is None:
-        os.environ.pop("TPU_VISIBLE_CHIPS", None)
-    else:
-        os.environ["TPU_VISIBLE_CHIPS"] = chips
-        os.environ.setdefault("TPU_CHIPS_PER_PROCESS_BOUNDS", "1,1,1")

@@ -67,7 +67,7 @@ def file_reader_fn(args, ctx):
 def manifest_drain_fn(args, ctx):
     """SPARK-mode map_fun consuming FileManifest records: the driver
     ships paths, this node reads the files locally (the node-side
-    feeder pattern — BASELINE.md push-plane ceiling)."""
+    feeder pattern — past the push plane's ceiling)."""
     from tensorflowonspark_tpu.feed.manifest import ManifestFeed
 
     feed = ManifestFeed(ctx.get_data_feed())

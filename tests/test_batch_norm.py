@@ -242,11 +242,11 @@ def test_resnet_tiny_trains_through_pallas_bn(pallas_interpret, monkeypatch):
 
 
 def test_use_pallas_auto_always_resolves_to_xla(monkeypatch):
-    """'auto' must resolve to the XLA reduces on every backend: the
-    round-5 chip A/B measured the in-context Pallas stats path at 8.9%
-    MFU on ResNet-50 vs 16.1% through XLA (the opaque pallas_call
-    severs producer/consumer fusion around each BN layer — see
-    BASELINE.md). Only an explicit impl='pallas' opts in."""
+    """'auto' must resolve to the XLA reduces on every backend: in
+    context the opaque pallas_call severs producer/consumer fusion
+    around each BN layer (ops/bn_kernels.py; the A/B behind this was
+    taken before PR 1 and is not measured on this installation). Only
+    an explicit impl='pallas' opts in."""
     monkeypatch.setattr(bn_kernels.jax, "default_backend", lambda: "tpu")
     assert bn_kernels.use_pallas("auto") is False
     assert bn_kernels.use_pallas("pallas") is True  # explicit overrides

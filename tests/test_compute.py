@@ -166,10 +166,12 @@ def test_state_shardings_explicit_role_resolution(mesh8):
 
 
 def test_zero_train_step_matches_replicated(mesh_dp):
-    """zero_sharding on vs off on a pure data-parallel mesh: same
-    params trajectory (byte-identical on this toy — no embedding-style
-    scatter grads whose reduce order could shift), moments genuinely
-    data-partitioned only on the ZeRO leg."""
+    """zero_sharding on vs off on a pure data-parallel mesh: the same
+    loss and the same params trajectory up to gradient-reduction order
+    (reduce-scatter vs all-reduce group the sum differently, so the two
+    legs may differ by a few ulp of fp32 — not by bytes under every
+    compiler), moments genuinely data-partitioned only on the ZeRO
+    leg."""
 
     def loss_fn(params, batch):
         pred = jnp.tanh(batch["x"] @ params["w1"]) @ params["w2"]
@@ -201,15 +203,16 @@ def test_zero_train_step_matches_replicated(mesh_dp):
     s_on, l_on = run(True)
     s_off, l_off = run(False)
     assert l_on == l_off
-    on_bytes = [
-        np.asarray(x).tobytes()
-        for x in jax.tree.leaves(jax.device_get(s_on.params))
-    ]
-    off_bytes = [
-        np.asarray(x).tobytes()
-        for x in jax.tree.leaves(jax.device_get(s_off.params))
-    ]
-    assert on_bytes == off_bytes
+    # 5 AdamW steps at lr 1e-2 on O(1) weights: a reduction-order
+    # difference enters as a few ulp (2**-23 relative) of a gradient and
+    # leaves as a few ulp of the weight. 16 ulp of fp32 is the allowance;
+    # a wrong partition of the update would miss it by many orders.
+    ulp = np.finfo(np.float32).eps
+    for a, b in zip(
+        jax.tree.leaves(jax.device_get(s_on.params)),
+        jax.tree.leaves(jax.device_get(s_off.params)),
+    ):
+        np.testing.assert_allclose(a, b, rtol=16 * ulp, atol=16 * ulp)
     # the ZeRO leg's moments really are partitioned across the replicas
     assert s_on.opt_state[0].mu["w1"].sharding.spec == P("data")
     assert s_off.opt_state[0].mu["w1"].sharding.spec == P()

@@ -168,9 +168,9 @@ def test_spark_feed_ragged_tail_agreement(tmp_path):
 
 
 def test_two_process_fsdp_checkpoint_resume(tmp_path):
-    """Multi-controller checkpoint/restore across the process boundary
-    (VERDICT round-1 item 3): a tiny Llama's params + bf16-moment Adam
-    state sharded over 2 processes, saved COLLECTIVELY by both processes
+    """Multi-controller checkpoint/restore across the process boundary:
+    a tiny Llama's params + bf16-moment Adam state sharded over 2
+    processes, saved COLLECTIVELY by both processes
     (chief-only saves of cross-process-sharded arrays hang/raise), then
     restored by a brand-new cluster which must replay the post-checkpoint
     steps bit-identically."""

@@ -11,8 +11,7 @@ own server thread and fires its own requests — no cluster) are plain
 scripts run without the launcher.
 
 Subprocesses inherit this process's environ, which conftest.py pinned to
-CPU with the relay hook blanked BEFORE any of this imports — safe to
-spawn freely (see the verify skill's boot-dial warning).
+CPU BEFORE any of this imports — safe to spawn freely.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """The Llama-2-7B FSDP train step traces and lowers on the 8-way mesh.
 
-Shape-level guard for the BASELINE.md headline config ("Llama-2-7B
+Shape-level guard for the BASELINE.json headline config ("Llama-2-7B
 fine-tune, FSDP over ICI, v4-32"): no 7B-capable hardware exists in CI,
 but tracing + StableHLO lowering catches sharding-rule mismatches,
 remat/flash-attention composition breaks, and param-count drift without
@@ -86,8 +86,8 @@ def test_llama2_7b_fsdp_step_lowers():
 
 
 def test_llama2_7b_fsdp_hbm_budget():
-    """Pre-hardware HBM gate for the v4-32 north-star config (VERDICT
-    round-1 item 8): compile the PRODUCTION 7B train step (donated state,
+    """Pre-hardware HBM gate for the v4-32 north-star config: compile
+    the PRODUCTION 7B train step (donated state,
     bf16 Adam moments, chunked CE, full remat) on the 8-way virtual mesh
     and bound its per-device memory three ways:
 
@@ -163,8 +163,8 @@ def test_llama2_7b_fsdp_hbm_budget():
     assert ma.alias_size_in_bytes >= 0.9 * ma.argument_size_in_bytes
 
     # (1b) fp32 stored params (bf16 is the COMPUTE dtype) + bf16 mu +
-    # bf16 nu = 8 bytes/param, fsdp-sharded 8 ways — the measured
-    # llama1b headline recipe (BASELINE.md: bf16 moments freed 3.8 GB)
+    # bf16 nu = 8 bytes/param, fsdp-sharded 8 ways — the llama1b
+    # headline recipe (bf16 moments free 4 bytes/param)
     n_params = 6.74e9
     state_bytes_per_dev = ma.argument_size_in_bytes
     assert state_bytes_per_dev < n_params * 8 / n_dev * 1.15, (

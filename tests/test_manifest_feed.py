@@ -1,6 +1,6 @@
 """Manifest feeding: driver ships paths, nodes read files locally
 (feed/manifest.py — the node-side feeder closing the push-plane
-ceiling gap, BASELINE.md round-3 measurement)."""
+ceiling gap)."""
 
 import os
 

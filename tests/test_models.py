@@ -702,7 +702,7 @@ def test_llama_packed_reused_ids_do_not_leak(tiny_llama):
 
 
 def test_llama_packed_decode_matches_per_document(tiny_llama):
-    """The segment-masked KV cache (VERDICT round-2 missing #4): packed
+    """The segment-masked KV cache: packed
     two-document prefill under decode=True must produce exactly the
     logits each document gets when prefilled alone, and continuing a
     chosen document against the packed cache must decode the same
@@ -784,7 +784,7 @@ def test_llama_packed_decode_matches_per_document(tiny_llama):
 
 
 def test_llama_generate_mesh_sharded_matches_single_device(tiny_llama):
-    """Mesh-sharded decode (VERDICT round-2 missing #3): greedy decode
+    """Mesh-sharded decode: greedy decode
     with weights TP-sharded on 'model' and batch + KV caches sharded on
     'data' must be token-identical to the single-device decode — the
     serving-side analog of what the FSDP tests prove for training."""

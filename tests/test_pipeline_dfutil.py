@@ -158,7 +158,7 @@ def test_has_param_accessor_arity():
 def test_transform_distributed_matches_local(tmp_path):
     """cluster_size=2 routes transform over cluster nodes (per-node model
     singletons + order-preserving inference plumbing); outputs must match
-    the local path's exactly, in input order. VERDICT round-1 item 6."""
+    the local path's exactly, in input order."""
     from tensorflowonspark_tpu.compute.checkpoint import save_checkpoint
 
     export_dir = str(tmp_path / "export")
@@ -205,7 +205,7 @@ class _CountingIter:
 
 def test_transform_streams_local(tmp_path):
     """transform_iter must pull input incrementally, interleaved with
-    model calls — never list(data) (VERDICT round-2 weak #4). Verified
+    model calls — never list(data). Verified
     with a counting iterator: when the first result comes out, at most
     the prefetch window (depth-2 DevicePrefetcher: queue + in-flight +
     staging ≈ 4 batches), not the dataset, has been consumed."""

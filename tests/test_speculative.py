@@ -245,7 +245,7 @@ def test_speculative_sampling_end_to_end(target_and_draft):
 
 
 def test_speculative_int8_target_composes(target_and_draft):
-    """The serving-stack combination run_pending.sh measures: an int8
+    """The serving-stack combination: an int8
     weight-only target verified against an fp draft still emits exactly
     the int8 target's own greedy tokens (exactness is relative to
     whatever model the target IS — quantized here)."""

@@ -116,3 +116,19 @@ def test_export_tf_saved_model_roundtrip(tmp_path):
         np.testing.assert_allclose(
             got, x @ np.array([[2.0], [1.0]], np.float32) + 0.5, rtol=1e-6
         )
+
+
+def test_local_launcher_refuses_several_accelerator_nodes_on_one_host():
+    """A host's chips belong to one process at a time: several node
+    processes that would all ask for the TPU are refused before any
+    starts (CPU-only nodes, and a single accelerator node, are fine)."""
+    import pytest
+
+    from tensorflowonspark_tpu.cluster.launchers import LocalLauncher
+
+    launcher = LocalLauncher()
+    with pytest.raises(ValueError, match="ONE node process per host"):
+        launcher.launch(
+            2, print, lambda i: (i,), env={"JAX_PLATFORMS": "tpu,cpu"}
+        )
+    assert launcher.exitcodes() == []  # nothing was started
