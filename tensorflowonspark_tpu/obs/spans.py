@@ -102,7 +102,8 @@ class _SpanCtx:
     :func:`traced`. Enters a ``jax.profiler`` annotation so the span
     shows on the device timeline when a trace is active."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann", "_step_num")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann", "_step_num",
+                 "dur")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: dict,
                  step_num: int | None = None):
@@ -111,6 +112,9 @@ class _SpanCtx:
         self._args = args
         self._step_num = step_num
         self._ann = None
+        # seconds, set at exit: the one duration ring, profiler
+        # annotation and any histogram a caller feeds describe
+        self.dur: float | None = None
 
     def set(self, **args: Any) -> None:
         """Attach args discovered DURING the span body (the consumer
@@ -137,7 +141,7 @@ class _SpanCtx:
         return self
 
     def __exit__(self, *exc) -> None:
-        dur = _CLOCK() - self._t0
+        self.dur = dur = _CLOCK() - self._t0
         if self._ann is not None:
             try:
                 self._ann.__exit__(*exc)

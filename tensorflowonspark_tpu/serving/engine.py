@@ -944,11 +944,45 @@ class ContinuousBatcher:
         self._m_steps = self.metrics.counter(
             "engine_decode_steps_total", "device decode steps taken"
         )
+        self._m_live_steps = self.metrics.counter(
+            "engine_slot_steps_live_total",
+            "decode steps x rows live at dispatch (of "
+            "engine_decode_steps_total x slots; the rest ran on slots "
+            "that stood empty)",
+        )
+        self._m_fallback_steps = self.metrics.counter(
+            "engine_decode_fallback_steps_total",
+            "decode steps dispatched in a block shorter than "
+            "decode_block (admission pending, chunked job, pin)",
+        )
+        self._m_prefill_tokens = self.metrics.counter(
+            "engine_prefill_tokens_total",
+            "prompt tokens the dispatched prefill programs had to "
+            "process (after a prefix hit: the suffix only)",
+        )
+        self._m_prefill_positions = self.metrics.counter(
+            "engine_prefill_positions_total",
+            "positions the dispatched prefill programs computed "
+            "(bucket or chunk width; the excess over "
+            "engine_prefill_tokens_total is padding)",
+        )
+        # a window in which nothing was counted reads 0, not absent
+        for c in (
+            self._m_live_steps, self._m_fallback_steps,
+            self._m_prefill_tokens, self._m_prefill_positions,
+        ):
+            c.inc(0)
         self._m_phase = self.metrics.histogram(
             "engine_request_phase_seconds",
             "scheduler phase latency (queue/prefill per request; "
             "dispatch/fetch/sweep per k-step decode block shared by "
-            "all live slots)",
+            "all live slots; drain per forced drain, its fetches and "
+            "sweeps nested inside)",
+        )
+        self._m_warmup = self.metrics.histogram(
+            "engine_warmup_seconds",
+            "wall time of one warmup() call",
+            buckets=(1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0),
         )
         self._m_ttft = self.metrics.histogram(
             "engine_ttft_seconds", "time to first token"
@@ -1482,11 +1516,14 @@ class ContinuousBatcher:
         # precisely to take them before traffic.
         self._watchdog_suspended = True
         self._warming = True
+        span = self._tracer.span("engine.warmup")
         try:
-            self._warmup_requests()
+            with span:
+                self._warmup_requests()
         finally:
             self._warming = False
             self._watchdog_suspended = False
+        self._m_warmup.observe(span.dur)
 
     def _warmup_requests(self) -> None:
         max_seq = self._model.cfg.max_seq_len
@@ -1871,19 +1908,23 @@ class ContinuousBatcher:
             req.event.set()
 
     @contextlib.contextmanager
-    def _phase(self, phase: str):
+    def _phase(self, phase: str, **args):
         """Measure one scheduler phase into both surfaces: the span
         ring (``/stats`` percentiles, Chrome-trace export, XLA-timeline
-        bridge) and the Prometheus phase histogram. Also names the
-        phase for the watchdog/close diagnostics ("stuck in fetch")."""
-        t0 = time.monotonic()
+        bridge) and the Prometheus phase histogram, with the span's
+        own duration so that all of them describe one interval on one
+        clock. Also names the phase for the watchdog/close diagnostics
+        ("stuck in fetch"); phases nest (a drain holds its fetches and
+        sweeps), and the outer name comes back on exit."""
+        outer = self._current_phase
         self._current_phase = phase
+        span = self._tracer.span("engine." + phase, **args)
         try:
-            with self._tracer.span("engine." + phase):
+            with span:
                 yield
         finally:
-            self._current_phase = None
-        self._m_phase.observe(time.monotonic() - t0, phase=phase)
+            self._current_phase = outer
+        self._m_phase.observe(span.dur, phase=phase)
 
     def _observe_queue_wait(self, p: _Pending) -> None:
         now = time.monotonic()
@@ -2490,6 +2531,12 @@ class ContinuousBatcher:
         piece = job.p.tokens[start_w : start_w + c]
         toks[0, : len(piece)] = piece
         positions = np.arange(start_w, start_w + c, dtype=np.int32)[None, :]
+        # new prompt tokens only: a window shifted back recomputes
+        # start_w..next_pos, which is padding like the tail's
+        self._m_prefill_tokens.inc(
+            min(job.length, start_w + c) - job.next_pos
+        )
+        self._m_prefill_positions.inc(c)
         job.cache_1, logits = self._chunk_fn(
             self._params,
             job.cache_1,
@@ -2738,6 +2785,8 @@ class ContinuousBatcher:
         seed_1 = self._resolve_seed(p)
         bid_1, bval_1 = self._resolve_bias(p)
         ad_1 = jnp.asarray([p.adapter], jnp.int32)
+        self._m_prefill_tokens.inc(len(p.tokens))
+        self._m_prefill_positions.inc(w)
         cache_1, tok_1, pos_1, lp_1 = self._prefill_fn(w)(
             self._params,
             jnp.asarray(prompt),
@@ -2912,11 +2961,12 @@ class ContinuousBatcher:
             return
         self._drain_stalls += 1
         self._m_drains.inc(reason=reason)
-        while self._window:
-            k0, packed = self._window.popleft()
-            with self._phase("fetch"):
-                host = self._fetch_packed(packed)
-            self._sweep_block(k0, host)
+        with self._phase("drain", reason=reason):
+            while self._window:
+                k0, packed = self._window.popleft()
+                with self._phase("fetch"):
+                    host = self._fetch_packed(packed)
+                self._sweep_block(k0, host)
 
     def _finished(self, p: _Pending, out: list[int], last: int) -> bool:
         if p.cancelled:
@@ -3407,6 +3457,11 @@ class ContinuousBatcher:
                         )
                         self.steps += k
                         self._m_steps.inc(k)
+                        self._m_live_steps.inc(
+                            k * sum(e is not None for e in self._live)
+                        )
+                        if k < self._decode_block:
+                            self._m_fallback_steps.inc(k)
                         self._window.append((k, packed))
                         self._progress_ts = time.monotonic()
                 # Deferred admission first tokens resolve AFTER the

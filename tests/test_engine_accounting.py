@@ -1,0 +1,288 @@
+"""The engine's account of its own admission cycle: what prefill had to
+process against what it computed, how many of the dispatched slot-steps
+were live, what a forced drain cost, what warm-up took. Counts are held
+to what the requests themselves say, exactly; spans to each other, on
+the tracer's one clock.
+"""
+
+import logging
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tensorflowonspark_tpu.models.llama import Llama, LlamaConfig
+from tensorflowonspark_tpu.serving import ContinuousBatcher
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = LlamaConfig.tiny(dtype=jnp.float32, remat=False)
+    model = Llama(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    return model, params
+
+
+def _counter(eng, name):
+    return eng.metrics.counter(name).value()
+
+
+def _phase_series(eng, phase):
+    series = eng.metrics.window()["engine_request_phase_seconds"]["series"]
+    return series.get('{phase="%s"}' % phase)
+
+
+_A = [5, 6, 7, 8, 9, 10, 11, 12, 13]
+
+# kind -> (engine options, prompts, prompt tokens processed, positions computed)
+_PREFILL_CASES = {
+    "plain": (
+        dict(prompt_widths=(4, 8, 16)),
+        [[1], [1, 2, 3, 4], [3] * 5, [4] * 8, [5] * 9, [6] * 16],
+        1 + 4 + 5 + 8 + 9 + 16,
+        4 + 4 + 8 + 8 + 16 + 16,
+    ),
+    "chunked": (
+        dict(prompt_widths=(16,), prefill_chunk=4),
+        [[1], [1, 2, 3, 4], [3] * 5, [4] * 8, [5] * 9, [6] * 16],
+        1 + 4 + 5 + 8 + 9 + 16,
+        4 * (1 + 1 + 2 + 2 + 3 + 4),
+    ),
+    # the second request finds all of _A stored and resumes at its last
+    # token; the third resumes after _A: the suffix only is counted, in
+    # one chunk each where the first took three
+    "prefix_hit": (
+        dict(prompt_widths=(16,), prefill_chunk=4, prefix_cache=8),
+        [_A, _A, _A + [40, 41]],
+        9 + 1 + 2,
+        4 * (3 + 1 + 1),
+    ),
+    # a final chunk that would run past max_seq_len is shifted back and
+    # recomputes positions it had already: they count as positions, not
+    # as tokens
+    "shifted_back": (
+        dict(prompt_widths=(128,), prefill_chunk=48),
+        [[7] * 100],
+        100,
+        48 * 3,
+    ),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_PREFILL_CASES))
+def test_prefill_tokens_and_positions_are_exact(tiny, kind):
+    model, params = tiny
+    options, prompts, tokens, positions = _PREFILL_CASES[kind]
+    eng = ContinuousBatcher(model, params, slots=2, **options)
+    try:
+        for p in prompts:  # one at a time: the store is read in this order
+            eng.submit(p, 2, eos_id=-1)
+        assert _counter(eng, "engine_prefill_tokens_total") == tokens
+        assert _counter(eng, "engine_prefill_positions_total") == positions
+        saved = eng.stats().get("prefix_tokens_saved", 0)
+        assert tokens == sum(map(len, prompts)) - saved
+    finally:
+        eng.close()
+
+
+def test_new_counters_read_zero_before_any_work(tiny):
+    """A window with no fallback step reads 0 %, a true count: the
+    unlabelled series exist from construction."""
+    model, params = tiny
+    eng = ContinuousBatcher(model, params, slots=2, prompt_widths=(8,))
+    try:
+        snap = eng.metrics.window()
+        for name in (
+            "engine_prefill_tokens_total",
+            "engine_prefill_positions_total",
+            "engine_slot_steps_live_total",
+            "engine_decode_fallback_steps_total",
+        ):
+            assert snap[name]["series"][""] == {"value": 0.0, "delta": 0.0}
+        # unlabelled as before: slot_occupancy_pct.serve reads this series
+        assert set(snap["engine_decode_steps_total"]["series"]) <= {""}
+    finally:
+        eng.close()
+
+
+def _churn(eng, n_clients=3, rounds=4):
+    """More clients than slots, each sending again when its last request
+    ends: admissions land under live windows and queue behind full
+    slots."""
+    errors = []
+
+    def client(i):
+        try:
+            for r in range(rounds):
+                eng.submit([1 + i, 2 + r, 3], 5 + 3 * ((i + r) % 3), eos_id=-1)
+        except BaseException as e:  # noqa: BLE001 - re-raised below
+            errors.append(e)
+
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(n_clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+        assert not t.is_alive(), "client wedged"
+    if errors:
+        raise errors[0]
+
+
+def test_live_slot_steps_lie_between_emitted_and_dispatched(tiny):
+    model, params = tiny
+    eng = ContinuousBatcher(
+        model, params, slots=2, prompt_widths=(8,), decode_block=4,
+        pipeline_depth=2,
+    )
+    try:
+        _churn(eng)
+        emitted = _counter(eng, "engine_tokens_emitted_total")
+        completed = _counter(eng, "engine_requests_completed_total")
+        live = _counter(eng, "engine_slot_steps_live_total")
+        steps = _counter(eng, "engine_decode_steps_total")
+        fallback = _counter(eng, "engine_decode_fallback_steps_total")
+        assert completed == 12 and steps == eng.steps
+        # each request's first token comes from its prefill; every other
+        # emitted token was a live slot-step, and some live ones were
+        # computed past a row's end and thrown away
+        assert emitted - completed <= live <= steps * 2
+        assert 0 <= fallback <= steps
+    finally:
+        eng.close()
+
+
+def test_fallback_steps_count_blocks_shorter_than_decode_block(tiny):
+    """warmup() compiles the single-step program through a request
+    pinned to k=1: every step of that request is a fallback step, and a
+    lone request on an idle engine runs in whole blocks."""
+    model, params = tiny
+    eng = ContinuousBatcher(
+        model, params, slots=2, prompt_widths=(8,), decode_block=4
+    )
+    try:
+        eng.submit([1, 2, 3], 9, eos_id=-1)
+        assert _counter(eng, "engine_decode_fallback_steps_total") == 0
+        assert _counter(eng, "engine_decode_steps_total") % 4 == 0
+        p = eng._enqueue([0], 3, eos_id=-1, decode_block_pin=1)
+        assert p.event.wait(120) and p.error is None
+        fallback = _counter(eng, "engine_decode_fallback_steps_total")
+        assert 2 <= fallback <= 2 * eng.stats()["pipeline_depth"] + 2
+        assert _counter(eng, "engine_decode_steps_total") % 4 == fallback % 4
+    finally:
+        eng.close()
+
+
+def test_drain_phase_counts_the_stalls_and_holds_its_fetches(tiny):
+    model, params = tiny
+    eng = ContinuousBatcher(
+        model, params, slots=2, prompt_widths=(8,), decode_block=4,
+        pipeline_depth=2,
+    )
+    # stamp every fetch a drain makes, on the tracer's clock
+    in_drain = []
+    drain_fetches = []
+    drain_window, fetch_packed = eng._drain_window, eng._fetch_packed
+
+    def stamped_drain(reason):
+        in_drain.append(reason)
+        try:
+            drain_window(reason)
+        finally:
+            in_drain.pop()
+
+    def stamped_fetch(packed):
+        t0 = time.perf_counter()
+        host = fetch_packed(packed)
+        if in_drain:
+            drain_fetches.append((t0, time.perf_counter()))
+        return host
+
+    eng._drain_window, eng._fetch_packed = stamped_drain, stamped_fetch
+    try:
+        holder = threading.Thread(
+            target=lambda: eng.submit([1, 2], 100, eos_id=-1)
+        )
+        holder.start()
+        deadline = time.time() + 60
+        while eng.stats()["slots_busy"] < 1 and time.time() < deadline:
+            time.sleep(0.02)
+        time.sleep(0.2)  # let the window fill mid-decode
+        eng.submit([3], 2)  # admission under a live window -> drain
+        holder.join(timeout=120)
+        assert not holder.is_alive()
+        stalls = eng.stats()["drain_stalls"]
+        assert stalls >= 1
+        drains = [s for s in eng._tracer.spans() if s.name == "engine.drain"]
+        series = _phase_series(eng, "drain")
+        assert series["count"] == stalls == len(drains)
+        assert {s.args["reason"] for s in drains} == {"admit"}
+        # ring and histogram describe the same intervals on one clock
+        assert series["sum"] == pytest.approx(sum(s.dur for s in drains), rel=1e-9)
+        assert len(drain_fetches) >= stalls
+        for t0, t1 in drain_fetches:
+            assert any(s.ts <= t0 and t1 <= s.ts + s.dur for s in drains)
+        # the steady-state phases nest inside and keep their own names
+        steady = [
+            x for x in eng._tracer.spans()
+            if x.name in ("engine.fetch", "engine.sweep")
+        ]
+        for s in drains:
+            inner = [
+                x for x in steady
+                if s.ts <= x.ts and x.ts + x.dur <= s.ts + s.dur
+            ]
+            assert {x.name for x in inner} == {"engine.fetch", "engine.sweep"}
+            assert sum(x.dur for x in inner) <= s.dur
+        assert eng.stats()["phase_ms"]["drain"]["count"] == stalls
+    finally:
+        eng.close()
+
+
+def test_nested_phase_gives_the_watchdog_the_outer_name_back(tiny, caplog):
+    model, params = tiny
+    eng = ContinuousBatcher(model, params, slots=2, prompt_widths=(8,))
+    try:
+        # an idle scheduler blocks on its queue and enters no phase
+        assert eng._current_phase is None
+        with eng._phase("drain", reason="admit"):
+            with eng._phase("fetch"):
+                assert eng._current_phase == "fetch"
+            assert eng._current_phase == "drain"
+            with caplog.at_level(logging.ERROR):
+                eng._watchdog_fire(1.0)
+        assert eng._current_phase is None
+        assert "stuck in drain" in caplog.text
+        assert _phase_series(eng, "drain")["count"] == 1
+        assert _phase_series(eng, "fetch")["count"] == 1
+        # nothing was in flight: the loop clears the flag and serves on
+        eng._queue.put(eng._WAKE)
+        deadline = time.time() + 30
+        while eng._watchdog_abort.is_set() and time.time() < deadline:
+            time.sleep(0.01)
+        assert len(eng.submit([1, 2, 3], 3, eos_id=-1)) == 3
+    finally:
+        eng.close()
+
+
+def test_warmup_is_one_span_and_one_observation(tiny):
+    model, params = tiny
+    eng = ContinuousBatcher(
+        model, params, slots=2, prompt_widths=(4, 8), decode_block=4
+    )
+    try:
+        assert "" not in eng.metrics.window()["engine_warmup_seconds"]["series"]
+        t0 = time.perf_counter()
+        eng.warmup()
+        wall = time.perf_counter() - t0
+        s = eng.metrics.window()["engine_warmup_seconds"]["series"][""]
+        spans = [x for x in eng._tracer.spans() if x.name == "engine.warmup"]
+        assert s["count"] == 1 == len(spans)
+        assert s["sum"] == spans[0].dur and 0 < s["sum"] <= wall
+        # warm-up's own requests are accounted like any others
+        assert _counter(eng, "engine_prefill_positions_total") == 4 + 8 + 4
+    finally:
+        eng.close()
