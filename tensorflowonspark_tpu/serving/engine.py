@@ -2188,6 +2188,26 @@ class ContinuousBatcher:
 
         return body
 
+    def _block_compiler_options(self):
+        """What the decode block asks of the TPU compiler, None
+        elsewhere (the option is the TPU backend's; the platform is that
+        of the devices the weights are on, so a compile for a described
+        chip takes it too).
+
+        With the cache aliased from input to output, the compiler's
+        memory-space assignment stages about half of the K/V planes
+        through on-chip memory in every step: it prefetches a whole
+        plane, scatters the step's 16 rows into the copy and writes the
+        whole plane back, 84 MB a plane at the benchmark's size, on a
+        step that is bound by HBM as it is. The ratio tells it to leave
+        a buffer in HBM unless its uses there read nearly as many bytes
+        as the copies in and out move; the planes are then updated in
+        place, as they were when the loop's carry was a private copy."""
+        leaf = jax.tree_util.tree_leaves(self._params)[0]
+        if next(iter(leaf.sharding.device_set)).platform != "tpu":
+            return None
+        return {"xla_tpu_msa_inefficient_use_to_copy_ratio": 0.9}
+
     def _block_fn(self, k: int):
         """Jitted k-step decode block. Per-instance memo like
         :meth:`_prefill_fn` (a class-level cache would pin closed
@@ -2204,7 +2224,18 @@ class ContinuousBatcher:
             return cached
         body = self._decode_body()
 
-        @jax.jit  # lint: layout-ok: params/cache arrive pre-committed to the engine TP layout at construction (layout.tp_only + serve_cache_sharding); donation would free the persistent slot buffers the scheduler reuses
+        # The carried batch state is donated: the scan updates the one
+        # batch cache in place (no copy into the loop's carry, no
+        # second copy alive per block in flight), and the caller's
+        # passed-in cache/tok/pos/counts are DELETED by the call — the
+        # scheduler rebinds them from the results and never reads the
+        # old values. The per-row knobs are read by the next block
+        # again and not returned; params is shared (hot swap, replicas).
+        @functools.partial(
+            jax.jit,
+            donate_argnames=("cache", "tok", "pos", "counts"),
+            compiler_options=self._block_compiler_options(),
+        )
         def block(
             params, cache, tok, pos, temps, ads, kps, seeds, pens,
             counts, bias_ids, bias_vals, gates,
@@ -2238,7 +2269,7 @@ class ContinuousBatcher:
         model = self._model
         constrain = self._constrain_cache
 
-        @jax.jit  # lint: layout-ok: params/cache arrive pre-committed to the engine TP layout at construction (layout.tp_only + serve_cache_sharding); donation would free the persistent slot buffers the scheduler reuses
+        @jax.jit  # lint: layout-ok: params arrive pre-committed to the engine TP layout at construction (layout.tp_only) and are shared (hot swap, replicas); the program takes no cache and builds its single-row one, so there is nothing to donate
         def prefill(
             params, prompt, length, temps, ads, kps, seed_1, bid_1,
             bval_1,
@@ -2271,7 +2302,19 @@ class ContinuousBatcher:
     def _admit_fn(self):
         constrain = self._constrain_cache
 
-        @jax.jit
+        # Every batch-state argument (*_b) is donated and returned
+        # updated: the scatter writes one row into the batch cache in
+        # place instead of copying all of it. Never the single-row
+        # side: cache_1 may be held by _PrefixStore and the L2 filler
+        # thread, tok_1 waits in _pending_first.
+        @functools.partial(
+            jax.jit,
+            donate_argnames=(
+                "cache_b", "tok_b", "pos_b", "temps_b", "ads_b",
+                "kps_b", "seeds_b", "pens_b", "counts_b", "bids_b",
+                "bvals_b",
+            ),
+        )
         def admit(
             cache_b, cache_1, row, tok_b, tok_1, pos_b, pos_1,
             temps_b, temp_1, ads_b, ad_1, kps_b, kp_1, seeds_b, seed_1,
@@ -2321,7 +2364,7 @@ class ContinuousBatcher:
         model = self._model
         constrain = self._constrain_cache
 
-        @jax.jit  # lint: layout-ok: params/cache arrive pre-committed to the engine TP layout at construction (layout.tp_only + serve_cache_sharding); donation would free the persistent slot buffers the scheduler reuses
+        @jax.jit  # lint: layout-ok: params/cache arrive pre-committed to the engine TP layout at construction (layout.tp_only + serve_cache_sharding); the single-row cache is NOT donated: it can be a _PrefixStore entry (or an L2 offer in flight) that other requests resume from
         def chunk(params, cache, tokens, positions, ads):
             logits, updated = model.apply(
                 {"params": params, "cache": cache},
@@ -2662,10 +2705,22 @@ class ContinuousBatcher:
         counts = jnp.zeros((b, self._model.cfg.vocab_size), jnp.float32)
         bids = jnp.full((b, _BIAS_SLOTS), -1, jnp.int32)
         bvals = jnp.zeros((b, _BIAS_SLOTS), jnp.float32)
-        return (
+        state = (
             cache, tok, pos, temps, ads, kps, seeds, pens, counts,
             bids, bvals,
         )
+        if self._mesh is None:
+            return state
+        # Under a mesh, commit the state to the layout the programs keep
+        # it in (cache heads on 'model', the rest replicated), so that
+        # the first admit writes in place like every later one.
+        from tensorflowonspark_tpu.compute import layout
+
+        cache_sh = jax.tree.map(
+            lambda x: layout.serve_cache_sharding(self._mesh, x), cache
+        )
+        rest = (layout.replicated(self._mesh),) * (len(state) - 1)
+        return jax.device_put(state, (cache_sh,) + rest)
 
     def _effective_knobs(self, p: _Pending):
         """Resolved (top_k, top_p, min_p) for one request — the request

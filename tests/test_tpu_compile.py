@@ -97,3 +97,63 @@ def test_bn_stats_kernels_compile_for_v5e(one_chip, kernel, rows, channels):
     else:
         text = _compiled_text(bn_kernels.cross_stats, one_chip, x, x)
     assert "tpu_custom_call" in text
+
+
+def test_engine_decode_block_compiles_for_v5e_with_its_option(one_chip):
+    """The decode block of the serving engine, one layer at Mistral-7B
+    widths with the benchmark's 16 x 2560 cache, compiled for the chip
+    as the engine compiles it there: with the TPU compiler option it
+    asks for (an option the installed compiler did not know would fail
+    here, not at a replica's start-up) and with the whole batch cache
+    aliased from input to output. Whether memory-space assignment then
+    leaves the planes in HBM shows only at the full depth (PERF.md §6,
+    PR 26); that compile takes minutes and stays with
+    ``perfbench/tools/compile_rehearsal.py``."""
+    from tensorflowonspark_tpu.models.llama import Llama, LlamaConfig
+    from tensorflowonspark_tpu.serving.engine import (
+        _BIAS_SLOTS,
+        ContinuousBatcher,
+    )
+
+    cfg = LlamaConfig(
+        vocab_size=2048, hidden_size=4096, intermediate_size=14336,
+        num_layers=1, num_heads=32, num_kv_heads=8, max_seq_len=2560,
+        dtype=BF16, remat=False,
+    )
+    model = Llama(cfg)
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        spec,
+        jax.eval_shape(
+            lambda: model.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+            )["params"]
+        ),
+    )
+    # programs only: no scheduler thread, no state on a device
+    eng = ContinuousBatcher.__new__(ContinuousBatcher)
+    eng._model, eng._mesh, eng._slots, eng._params = model, None, 16, params
+    eng._block_cache = {}
+    assert eng._block_compiler_options()  # the weights are on a TPU
+    slots, vocab = 16, cfg.vocab_size
+    cache = jax.tree.map(spec, eng._cache_shapes(slots))
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = eng._block_fn(2).lower(
+        params, cache, arr(i32, slots), arr(i32, slots), arr(f32, slots),
+        arr(i32, slots), arr(f32, slots, 3), arr(jnp.uint32, slots),
+        arr(f32, slots, 2), arr(f32, slots, vocab),
+        arr(i32, slots, _BIAS_SLOTS), arr(f32, slots, _BIAS_SLOTS),
+        arr(jnp.bool_, 4),
+    ).compile()
+    cache_bytes = sum(
+        x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(cache)
+    )
+    # the cache whole (tok, pos and counts ride along, padded to tiles)
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
