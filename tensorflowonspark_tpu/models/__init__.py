@@ -15,6 +15,11 @@ from tensorflowonspark_tpu.models.bert import (  # noqa: F401
     BertForMLM,
     bert_param_shardings,
 )
+from tensorflowonspark_tpu.models.falcon_h1 import (  # noqa: F401
+    FalconH1,
+    FalconH1Config,
+    falcon_h1_param_shardings,
+)
 from tensorflowonspark_tpu.models.inception import (  # noqa: F401
     InceptionConfig,
     InceptionV3,

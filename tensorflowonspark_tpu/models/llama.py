@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
 from tensorflowonspark_tpu.compute import layout
+from tensorflowonspark_tpu.models.decode_cache import init_cache  # noqa: F401
 
 from tensorflowonspark_tpu.ops.attention import dot_product_attention
 from tensorflowonspark_tpu.ops.lora import (
@@ -107,6 +108,10 @@ class LlamaConfig:
     # of the cache ever exists). Decode-side only; training is
     # unaffected (no cache).
     kv_cache_dtype: str = "model"
+    # Keys are multiplied by this before the rotation (a muP factor of
+    # models/falcon_h1.py's published config). 1.0 multiplies nothing: a
+    # Python branch, not a traced multiply by one.
+    key_multiplier: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -322,12 +327,18 @@ class QDense(nn.Module):
 
 
 class Attention(nn.Module):
+    """Grouped-query attention with rotary positions and, in
+    ``decode=True``, the static-shape KV cache. ``cfg`` is a
+    :class:`LlamaConfig` or any config that carries the attributes read
+    here (``models/falcon_h1.py`` passes its own, whose ``head_dim`` is
+    not ``hidden_size / num_heads``)."""
+
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(
         self, x, positions, segment_ids=None, decode=False, padded=False,
-        adapter_ids=None,
+        adapter_ids=None, valid=None,
     ):
         cfg = self.cfg
         dense = lambda feats, name, b=False: QDense(  # noqa: E731
@@ -345,6 +356,8 @@ class Attention(nn.Module):
         q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
         k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
         v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.key_multiplier != 1.0:
+            k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
         q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
         if decode:
@@ -356,7 +369,7 @@ class Attention(nn.Module):
                     "with packed rows' global slot indexing"
                 )
             out = self._cached_attention(q, k, v, positions, padded,
-                                         segment_ids)
+                                         segment_ids, valid)
         else:
             out = dot_product_attention(
                 q, k, v, causal=True, segment_ids=segment_ids,
@@ -366,7 +379,8 @@ class Attention(nn.Module):
         return dense(cfg.hidden_size, "o_proj")(out, adapter_ids)
 
     def _cached_attention(
-        self, q, k, v, positions, padded=False, segment_ids=None
+        self, q, k, v, positions, padded=False, segment_ids=None,
+        valid=None,
     ):
         """Autoregressive attention against a static-shape KV cache.
 
@@ -393,6 +407,15 @@ class Attention(nn.Module):
         row (``packed_loss_mask`` canonicalizes). Unpacked callers
         store zeros everywhere, making the id-equality term vacuous —
         one code path, one compiled program.
+
+        ``valid`` (b, s) bool, on the ``padded=True`` path: a position
+        marked false writes nothing (its scatter index is moved out of
+        range and dropped) and its output is don't-care. ``Llama`` never
+        passes it: the K/V it writes for padding are masked by slot, and
+        an overlap it recomputes is recomputed identically. A block whose
+        hidden states are wrong at invalid positions (a recurrence that
+        skipped them: ``models/falcon_h1.py``) must not let them
+        overwrite rows that are already right.
 
         Decode is HBM-bandwidth-bound; plain einsum is the right shape
         for it (flash targets the O(S^2) training pass).
@@ -458,7 +481,8 @@ class Attention(nn.Module):
             # indistinguishable from never-written for early queries.
             # NOTE for cache consumers that build fresh rows outside
             # flax (the serving engine): this is the ONE cache leaf
-            # whose init is non-zero under rolling — see init_cache().
+            # whose init is non-zero under rolling — see
+            # decode_cache.init_cache().
             cp = self.variable(
                 "cache", "pos",
                 lambda: jnp.full((b, C), -1 if rolling else 0, jnp.int32),
@@ -506,13 +530,16 @@ class Attention(nn.Module):
             slot_q = None  # unused: rolling masks by position only
         elif padded:
             rows = jnp.arange(b)[:, None]
-            ck.value = ck.value.at[rows, positions].set(k_new)
-            cv.value = cv.value.at[rows, positions].set(v_new)
+            at = positions
+            if valid is not None:
+                at = jnp.where(valid, positions, C)  # out of range: dropped
+            ck.value = ck.value.at[rows, at].set(k_new, mode="drop")
+            cv.value = cv.value.at[rows, at].set(v_new, mode="drop")
             if int8_kv:
-                cks.value = cks.value.at[rows, positions].set(ks_new)
-                cvs.value = cvs.value.at[rows, positions].set(vs_new)
+                cks.value = cks.value.at[rows, at].set(ks_new, mode="drop")
+                cvs.value = cvs.value.at[rows, at].set(vs_new, mode="drop")
             if cfg.sliding_window is not None:
-                cp.value = cp.value.at[rows, positions].set(positions)
+                cp.value = cp.value.at[rows, at].set(positions, mode="drop")
             # positions ARE the slots here (unpacked rows only; the
             # packed+padded combination is rejected in __call__)
             slot_q = positions
@@ -677,6 +704,7 @@ class Llama(nn.Module):
         return_hidden=False,
         padded=False,
         adapter_ids=None,
+        valid=None,
     ):
         """tokens (B, S) int32 -> logits (B, S, vocab).
 
@@ -709,7 +737,13 @@ class Llama(nn.Module):
         head weight — so callers can run the vocab projection in chunks
         (:func:`llama_loss_fn` with ``logit_chunk``) without ever
         materializing the (B, S, vocab) fp32 logits.
+
+        ``valid`` (B, S) bool marks the positions that are real tokens
+        of this call. Accepted and ignored: K/V written for padding are
+        masked by slot when they are read. The serving engine passes it
+        to every model; one that carries recurrent state needs it.
         """
+        del valid
         cfg = self.cfg
         if positions is None:
             idx = jnp.broadcast_to(
@@ -737,15 +771,7 @@ class Llama(nn.Module):
             nn.initializers.normal(0.02),
             (cfg.vocab_size, cfg.hidden_size),
         )
-        if isinstance(embed, QuantTensor):
-            # gather int8 rows, then scale: the table stays int8 in HBM.
-            # Per-row (axis=0) scales — quantize_tree's default for the
-            # embedding — gather alongside the rows; axis=-1 broadcasts.
-            rows = embed.q[tokens].astype(jnp.float32)
-            scale = embed.scale[tokens] if embed.axis == 0 else embed.scale
-            x = (rows * scale).astype(cfg.dtype)
-        else:
-            x = embed[tokens].astype(cfg.dtype)
+        x = embed_rows(embed, tokens).astype(cfg.dtype)
         if cfg.remat and not decode:
             # Rematerialize each layer's activations in backward: trades
             # FLOPs for HBM, the standard long-sequence TPU memory lever.
@@ -782,9 +808,37 @@ class Llama(nn.Module):
         )
         if return_hidden:
             return x, head
-        if isinstance(head, QuantTensor):
-            return quantized_dot(x, head).astype(jnp.float32)
-        return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
+        return head_logits(x, head, cfg.dtype)
+
+    def head(self, hidden):
+        """Logits of final-norm hidden states (..., H) from the bound
+        ``lm_head`` (``model.apply({"params": p}, hidden,
+        method="head")``): how a caller that took ``return_hidden``
+        applies the head to the rows it kept."""
+        return head_logits(
+            hidden, self.get_variable("params", "lm_head"), self.cfg.dtype
+        )
+
+
+def embed_rows(embed, tokens):
+    """The embedding table's rows for ``tokens``, an int8 ``QuantTensor``
+    table staying int8 in HBM: its rows are gathered, then scaled.
+    Per-row (axis=0) scales — quantize_tree's default for the embedding
+    — gather alongside the rows; axis=-1 broadcasts."""
+    if isinstance(embed, QuantTensor):
+        rows = embed.q[tokens].astype(jnp.float32)
+        return rows * (embed.scale[tokens] if embed.axis == 0 else embed.scale)
+    return embed[tokens]
+
+
+def head_logits(x, head, dtype, multiplier: float = 1.0):
+    """float32 logits of hidden states ``x`` (..., H) under an untied head
+    (H, vocab), an int8 ``QuantTensor`` head staying int8 in HBM."""
+    if isinstance(head, QuantTensor):
+        out = quantized_dot(x, head).astype(jnp.float32)
+    else:
+        out = (x @ head.astype(dtype)).astype(jnp.float32)
+    return out if multiplier == 1.0 else out * multiplier
 
 
 def llama_param_shardings(params, mesh: Mesh):
@@ -798,26 +852,6 @@ def llama_param_shardings(params, mesh: Mesh):
     specs cannot diverge.
     """
     return layout.param_shardings(params, mesh, "llama")
-
-
-def init_cache(shapes):
-    """Fresh cache values for a tree of ShapeDtypeStructs (the serving
-    engine builds per-row caches from ``jax.eval_shape`` rather than a
-    real ``model.init`` — an init-valued apply would also WRITE its
-    dummy token into the cache). This is the single source of truth for
-    cache-leaf init values outside flax: everything zero-fills EXCEPT
-    the position plane, which is -1 ("never written") so a rolling
-    cache cannot mistake a stale slot for a valid position 0. Keep in
-    lockstep with the ``self.variable`` inits in ``_cached_attention``.
-    """
-
-    def init(path, s):
-        name = str(getattr(path[-1], "key", path[-1]))
-        if name == "pos":
-            return jnp.full(s.shape, -1, s.dtype)
-        return jnp.zeros(s.shape, s.dtype)
-
-    return jax.tree_util.tree_map_with_path(init, shapes)
 
 
 def decode_cache_spec(x: jax.Array) -> PartitionSpec:
@@ -1075,6 +1109,9 @@ def _build_generate(
             positions=positions,
             decode=True,
             padded=padded,
+            # right-padding is no token: a model that carries recurrent
+            # state (models/falcon_h1.py) must not run over it
+            valid=positions < lengths[:, None] if padded else None,
             mutable=["cache"],
         )
         keys = jax.random.split(rng, max_new_tokens)

@@ -240,6 +240,36 @@ def _build_llama(variant, tiny):
     )
 
 
+def _build_falcon_h1(tiny):
+    from tensorflowonspark_tpu.models import falcon_h1 as F
+
+    # full size: the defaults are Falcon-H1-34B's published config
+    cfg = F.FalconH1Config.tiny() if tiny else F.FalconH1Config()
+    model = F.FalconH1(cfg)
+
+    def make_input(b):
+        rng = np.random.default_rng(0)
+        s = min(cfg.max_seq_len, 32 if tiny else 1024)
+        return {
+            "tokens": rng.integers(0, cfg.vocab_size, size=(b, s + 1)).astype(
+                np.int32
+            )
+        }
+
+    def make_loss():
+        token_loss = F.falcon_h1_loss_fn(model)
+        return lambda p, batch: token_loss(p, batch["tokens"])
+
+    return ZooEntry(
+        name="falcon_h1_34b",
+        kind="tokens",
+        model=model,
+        make_input=make_input,
+        param_shardings=F.falcon_h1_param_shardings,
+        make_loss=make_loss,
+    )
+
+
 _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "resnet18": lambda tiny, nc: _build_resnet("resnet18", tiny, nc),
     "resnet34": lambda tiny, nc: _build_resnet("resnet34", tiny, nc),
@@ -256,6 +286,7 @@ _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "llama3_8b": lambda tiny, nc: _build_llama("llama3_8b", tiny),
     "mistral_7b": lambda tiny, nc: _build_llama("mistral_7b", tiny),
     "qwen2_7b": lambda tiny, nc: _build_llama("qwen2_7b", tiny),
+    "falcon_h1_34b": lambda tiny, nc: _build_falcon_h1(tiny),
 }
 
 

@@ -78,7 +78,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tensorflowonspark_tpu.models.llama import Llama
+from tensorflowonspark_tpu.models.decode_cache import init_cache, leaf_kind
 from tensorflowonspark_tpu.obs import registry as obs_registry
 from tensorflowonspark_tpu.obs import reqtrace
 from tensorflowonspark_tpu.obs import spans as obs_spans
@@ -602,7 +602,14 @@ class _PrefixStore:
 
 
 class ContinuousBatcher:
-    """Persistent B-slot decode engine over one Llama checkpoint.
+    """Persistent B-slot decode engine over one decoder checkpoint
+    (``models.llama.Llama``, ``models.falcon_h1.FalconH1``: any module
+    with their call signature, a ``head`` method and a ``cache``
+    collection as ``models/decode_cache.py`` describes it). The engine
+    behaves by what the model's cache tree holds: a model that carries
+    recurrent state beside K/V is served through the same programs, and
+    refuses ``prefix_cache``, ``prefix_l2`` and a ``mesh`` whose
+    ``model`` extent is over 1 (see ``docs/SERVING.md``).
 
     ``submit(tokens, max_new_tokens)`` blocks the calling thread until
     that request's completion is ready (server handler threads call it
@@ -646,7 +653,7 @@ class ContinuousBatcher:
 
     def __init__(
         self,
-        model: Llama,
+        model,
         params,
         *,
         slots: int = 8,
@@ -670,11 +677,43 @@ class ContinuousBatcher:
         cfg = model.cfg
         self._model = model
         self._mesh = mesh
+        self._slots = int(slots)
+        if self._slots < 1:
+            # slots=0 would construct fine, then the scheduler thread
+            # busy-spins and every submit() waits forever on a free slot.
+            raise ValueError(f"slots must be >= 1, got {slots}")
+        # The batch cache's tree, by one eval_shape (no compile, no
+        # device work): what kinds of per-request state the model keeps
+        # decides what the engine may do with a row.
+        self._params = params
+        self._batch_cache_shapes = self._cache_shapes(self._slots)
+        cache_bytes = dict.fromkeys(("kv", "recurrent", "other"), 0)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+            self._batch_cache_shapes
+        ):
+            cache_bytes[leaf_kind(path)] += (
+                math.prod(leaf.shape) * leaf.dtype.itemsize
+            )
+        if cache_bytes["recurrent"]:
+            # Recurrent state is valid at one position only: the one
+            # after the last token the row consumed.
+            if prefix_cache is not None or prefix_l2 is not None:
+                raise ValueError(
+                    "prefix_cache / prefix_l2 are unsupported with a "
+                    "model that carries recurrent state: a stored row "
+                    "resumes at any depth inside it, which needs a "
+                    "state snapshot at the resume position, and none "
+                    "is kept"
+                )
+            if mesh is not None and mesh.shape.get("model", 1) > 1:
+                raise ValueError(
+                    "a mesh 'model' extent over 1 is unsupported with a "
+                    "model that carries recurrent state: there is no "
+                    "cache sharding for its recurrent leaves (nor a "
+                    "parameter table for its mixer)"
+                )
         if mesh is not None:
             from tensorflowonspark_tpu.compute import layout
-            from tensorflowonspark_tpu.models.llama import (
-                llama_param_shardings,
-            )
 
             tp = mesh.shape.get("model", 1)
             if cfg.num_heads % tp or cfg.num_kv_heads % tp:
@@ -707,7 +746,7 @@ class ContinuousBatcher:
                 params,
                 jax.tree.map(
                     lambda sh: layout.tp_only(mesh, sh),
-                    llama_param_shardings(params, mesh),
+                    layout.param_shardings(params, mesh, "llama"),
                 ),
             )
         self._params = params
@@ -716,11 +755,6 @@ class ContinuousBatcher:
         # MultiLoraTensor banks in the params enable per-request adapter
         # routing; 0 slots means "no bank" (adapter must be 0/None).
         self._n_adapters = bank_size(params)
-        self._slots = int(slots)
-        if self._slots < 1:
-            # slots=0 would construct fine, then the scheduler thread
-            # busy-spins and every submit() waits forever on a free slot.
-            raise ValueError(f"slots must be >= 1, got {slots}")
         self._widths = tuple(sorted(int(w) for w in prompt_widths))
         if not self._widths or self._widths[-1] > cfg.max_seq_len:
             raise ValueError(
@@ -1019,6 +1053,17 @@ class ContinuousBatcher:
             "engine_inflight_depth",
             "decode blocks dispatched but not yet fetched",
         )
+        # set once: the batch cache is allocated whole at the first
+        # admission and never resized
+        self._cache_bytes = cache_bytes
+        g_cache = self.metrics.gauge(
+            "engine_cache_bytes",
+            "bytes of the batch cache by kind of leaf: kv (a plane of "
+            "positions a row), recurrent (one state a row), other "
+            "(segment ids, positions, write indices)",
+        )
+        for kind, n in cache_bytes.items():
+            g_cache.set(n, kind=kind)
 
         def _collect(
             busy=g_busy, depth=g_depth, slots=g_slots,
@@ -1995,6 +2040,8 @@ class ContinuousBatcher:
             # fleet supervisors read it off /stats
             "unresolved": self.unresolved(),
             "tokens_emitted": self.tokens_emitted,
+            # the batch cache by kind of leaf (engine_cache_bytes)
+            "cache_bytes": dict(self._cache_bytes),
             # degradation surface: terminal deadline expiries, watchdog
             # fires, and (after close()) whether the scheduler actually
             # wound down inside its join timeout — None while running
@@ -2275,18 +2322,29 @@ class ContinuousBatcher:
             bval_1,
         ):
             positions = jnp.arange(width, dtype=jnp.int32)[None, :]
-            logits, state = model.apply(
+            # The padding after the prompt is marked: K/V written for
+            # it are masked when read, a recurrence must not run over
+            # it at all.
+            (hidden, _), state = model.apply(
                 {"params": params},
                 prompt,
                 positions=positions,
                 decode=True,
                 padded=True,
                 adapter_ids=ads,
+                valid=positions < length[:, None],
+                return_hidden=True,
                 mutable=["cache"],
             )
-            last = jnp.take_along_axis(
-                logits, (length - 1)[:, None, None], axis=1
-            )[:, 0]
+            # the head on the prompt's last position only: no
+            # (width, vocab) array exists
+            last = model.apply(
+                {"params": params},
+                jnp.take_along_axis(
+                    hidden, (length - 1)[:, None, None], axis=1
+                )[:, 0],
+                method="head",
+            )
             # the first sampled token occupies position `length`;
             # logit_bias shapes it too (penalties don't - zero counts)
             tok, lp = _sample_rows(
@@ -2365,30 +2423,43 @@ class ContinuousBatcher:
         constrain = self._constrain_cache
 
         @jax.jit  # lint: layout-ok: params/cache arrive pre-committed to the engine TP layout at construction (layout.tp_only + serve_cache_sharding); the single-row cache is NOT donated: it can be a _PrefixStore entry (or an L2 offer in flight) that other requests resume from
-        def chunk(params, cache, tokens, positions, ads):
-            logits, updated = model.apply(
+        def chunk(params, cache, tokens, positions, ads, lo, hi):
+            # Real tokens of this call: inside the prompt (< hi) and
+            # not yet consumed (>= lo: a window shifted back recomputes
+            # its overlap, which is harmless for K/V and would be
+            # applied twice by a recurrence).
+            (hidden, _), updated = model.apply(
                 {"params": params, "cache": cache},
                 tokens,
                 positions=positions,
                 decode=True,
                 padded=True,
                 adapter_ids=ads,
+                valid=(positions >= lo) & (positions < hi),
+                return_hidden=True,
                 mutable=["cache"],
             )
-            return constrain(updated["cache"]), logits
+            return constrain(updated["cache"]), hidden
 
         return chunk
 
     @functools.cached_property
     def _sample1_fn(self):
-        @jax.jit
+        model = self._model
+
+        @jax.jit  # lint: layout-ok: params arrive pre-committed to the engine TP layout at construction (layout.tp_only) and are shared (hot swap, replicas); the chunk's hidden states are read once and nothing here is carried, so there is nothing to donate
         def sample1(
-            logits_chunk, idx, temps, kps, seed_1, length_1, bid_1,
-            bval_1,
+            params, hidden_chunk, idx, temps, kps, seed_1, length_1,
+            bid_1, bval_1,
         ):
-            last = jax.lax.dynamic_index_in_dim(
-                logits_chunk, idx, axis=1, keepdims=False
-            )  # (1, vocab): the prompt's true last position
+            # the head on the prompt's true last position only
+            last = model.apply(
+                {"params": params},
+                jax.lax.dynamic_index_in_dim(
+                    hidden_chunk, idx, axis=1, keepdims=False
+                ),
+                method="head",
+            )  # (1, vocab)
             # the first sampled token occupies position `length`;
             # logit_bias shapes it too (penalties don't - zero counts)
             return _sample_rows(
@@ -2400,9 +2471,10 @@ class ContinuousBatcher:
 
     def _cache_shapes(self, batch: int):
         """Cache-tree ShapeDtypeStructs for a ``batch``-row decode —
-        one eval_shape (traces the whole model, no compile/device work)
-        shared by the per-row and engine-batch cache builders so the
-        two can never drift structurally."""
+        one eval_shape (traces the whole model, no compile/device
+        work), made once at construction for the engine's batch; the
+        per-row tree is cut from it by shape, so the two can never
+        drift structurally."""
         _, shapes = jax.eval_shape(
             lambda p, t, pos: self._model.apply(
                 {"params": p},
@@ -2422,14 +2494,19 @@ class ContinuousBatcher:
     def _single_row_cache_shapes(self):
         # A constant, NOT per-admission work on the scheduler thread (a
         # per-request trace would stall live rows' step dispatch,
-        # exactly the latency chunked prefill exists to remove).
-        return self._cache_shapes(1)
+        # exactly the latency chunked prefill exists to remove). By
+        # shape from the batch's tree, whatever the model: every leaf
+        # but the scalar write index has the row first.
+        return jax.tree.map(
+            lambda s: s if not s.shape else jax.ShapeDtypeStruct(
+                (1, *s.shape[1:]), s.dtype
+            ),
+            self._batch_cache_shapes,
+        )
 
     def _single_row_cache(self):
-        from tensorflowonspark_tpu.models.llama import init_cache
-
-        # the model owns its cache-leaf init values (rolling caches
-        # init the position plane to -1, not 0)
+        # decode_cache.init_cache owns the leaves' init values (zeros;
+        # the position plane -1, not 0)
         return init_cache(self._single_row_cache_shapes)
 
     def _l2_offer(self, tokens: list[int], cache_1, adapter) -> None:
@@ -2544,6 +2621,24 @@ class ContinuousBatcher:
             next_insert_depth=self._prefill_chunk or 0,
         )
 
+    def _chunk_window(self, job: _PrefillJob) -> tuple[int, int]:
+        """Where the job's next chunk starts, and how many of its
+        positions are new prompt tokens.
+
+        The window is shifted back rather than letting positions run
+        past max_seq_len: a final chunk starting at `next_pos` would
+        scatter rows at next_pos+c-1 >= max_seq_len, which only works
+        by JAX's silent out-of-bounds-scatter drop. The overlap
+        start..next_pos is already in the cache: its K/V rows are
+        recomputed identically (chunked prefill is causal-consistent),
+        and the chunk program marks it invalid, so that a recurrent
+        state does not consume those tokens twice. Every position stays
+        in [0, max_seq_len) and distinct. __init__ guarantees
+        c <= max_seq_len, so start >= 0."""
+        c = self._prefill_chunk
+        start = min(job.next_pos, self._model.cfg.max_seq_len - c)
+        return start, max(0, min(job.length, start + c) - job.next_pos)
+
     def _advance_job(
         self, cache, tok, pos, temps, ads, kps, seeds, pens, counts,
         bids, bvals,
@@ -2561,31 +2656,23 @@ class ContinuousBatcher:
                 bids, bvals,
             )
         c = self._prefill_chunk
-        # Shift the window back rather than letting positions run past
-        # max_seq_len: a final chunk starting at `start` would scatter
-        # rows at start+c-1 >= max_seq_len, which only works by JAX's
-        # silent out-of-bounds-scatter drop. Chunked prefill is
-        # causal-consistent, so re-processing the overlap start_w..start
-        # (already in the cache) recomputes identical K/V rows; every
-        # position stays in [0, max_seq_len) and distinct. __init__
-        # guarantees c <= max_seq_len, so start_w >= 0.
-        start_w = min(job.next_pos, self._model.cfg.max_seq_len - c)
+        start_w, n_new = self._chunk_window(job)
         toks = np.zeros((1, c), np.int32)
         piece = job.p.tokens[start_w : start_w + c]
         toks[0, : len(piece)] = piece
         positions = np.arange(start_w, start_w + c, dtype=np.int32)[None, :]
         # new prompt tokens only: a window shifted back recomputes
         # start_w..next_pos, which is padding like the tail's
-        self._m_prefill_tokens.inc(
-            min(job.length, start_w + c) - job.next_pos
-        )
+        self._m_prefill_tokens.inc(n_new)
         self._m_prefill_positions.inc(c)
-        job.cache_1, logits = self._chunk_fn(
+        job.cache_1, hidden = self._chunk_fn(
             self._params,
             job.cache_1,
             jnp.asarray(toks),
             jnp.asarray(positions),
             job.ad_1,
+            jnp.int32(job.next_pos),
+            jnp.int32(job.length),
         )
         job.next_pos = start_w + c
         if job.next_pos < job.length:
@@ -2628,7 +2715,8 @@ class ContinuousBatcher:
             self._l2_offer(job.p.tokens, job.cache_1, job.p.adapter)
         # final chunk: it contains the prompt's last true position
         tok_1, lp_1 = self._sample1_fn(
-            logits,
+            self._params,
+            hidden,
             jnp.int32(job.length - 1 - start_w),
             job.temp_1,
             job.kp_1,
@@ -2681,9 +2769,7 @@ class ContinuousBatcher:
 
     def _empty_state(self):
         b = self._slots
-        from tensorflowonspark_tpu.models.llama import init_cache
-
-        cache = init_cache(self._cache_shapes(b))
+        cache = init_cache(self._batch_cache_shapes)
         tok = jnp.zeros((b,), jnp.int32)
         # Parked rows decode at position 0 against their own slot only;
         # their K/V writes stay inside their row and are overwritten on
@@ -3425,7 +3511,11 @@ class ContinuousBatcher:
                             pens, counts, bids, bvals,
                         ) = self._empty_state()
                     if self._prefill_chunk is None:
-                        with self._phase("prefill"):
+                        with self._phase(
+                            "prefill",
+                            width=self._bucket(len(item.tokens)),
+                            valid=len(item.tokens),
+                        ):
                             (
                                 cache, tok, pos, temps, ads, kps, seeds,
                                 pens, counts, bids, bvals,
@@ -3452,7 +3542,10 @@ class ContinuousBatcher:
                         # job's private single-row cache and overlap
                         # freely with in-flight decode blocks.
                         self._drain_window("prefill_admit")
-                    with self._phase("prefill"):
+                    with self._phase(
+                        "prefill", width=c,
+                        valid=self._chunk_window(self._job)[1],
+                    ):
                         (
                             cache, tok, pos, temps, ads, kps, seeds,
                             pens, counts, bids, bvals,

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from tensorflowonspark_tpu.models.falcon_h1 import FalconH1, FalconH1Config
 from tensorflowonspark_tpu.models.llama import Llama, LlamaConfig
 from tensorflowonspark_tpu.serving import ContinuousBatcher
 
@@ -21,6 +22,15 @@ from tensorflowonspark_tpu.serving import ContinuousBatcher
 def tiny():
     cfg = LlamaConfig.tiny(dtype=jnp.float32, remat=False)
     model = Llama(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    return model, params
+
+
+@pytest.fixture(scope="module")
+def tiny_hybrid():
+    model = FalconH1(FalconH1Config.tiny(dtype=jnp.float32))
     params = model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )["params"]
@@ -73,9 +83,17 @@ _PREFILL_CASES = {
 }
 
 
-@pytest.mark.parametrize("kind", sorted(_PREFILL_CASES))
-def test_prefill_tokens_and_positions_are_exact(tiny, kind):
-    model, params = tiny
+@pytest.mark.parametrize(
+    "kind,family",
+    [(k, "llama") for k in sorted(_PREFILL_CASES)]
+    # the same counts whatever the model; a model that carries recurrent
+    # state refuses the prefix store
+    + [(k, "hybrid") for k in sorted(_PREFILL_CASES) if k != "prefix_hit"],
+)
+def test_prefill_tokens_and_positions_are_exact(
+    tiny, tiny_hybrid, kind, family
+):
+    model, params = tiny if family == "llama" else tiny_hybrid
     options, prompts, tokens, positions = _PREFILL_CASES[kind]
     eng = ContinuousBatcher(model, params, slots=2, **options)
     try:
@@ -85,6 +103,11 @@ def test_prefill_tokens_and_positions_are_exact(tiny, kind):
         assert _counter(eng, "engine_prefill_positions_total") == positions
         saved = eng.stats().get("prefix_tokens_saved", 0)
         assert tokens == sum(map(len, prompts)) - saved
+        # the engine.prefill spans say the same: the width computed and
+        # how much of it was the prompt's own tokens
+        spans = [s for s in eng._tracer.spans() if s.name == "engine.prefill"]
+        assert sum(s.args["valid"] for s in spans) == tokens
+        assert sum(s.args["width"] for s in spans) == positions
     finally:
         eng.close()
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tensorflowonspark_tpu.models.falcon_h1 import FalconH1, FalconH1Config
 from tensorflowonspark_tpu.models.llama import Llama, LlamaConfig, generate
 from tensorflowonspark_tpu.serving import ContinuousBatcher
 from tensorflowonspark_tpu.serving.engine import _BIAS_SLOTS
@@ -30,6 +31,9 @@ _KINDS = {
     "rolling": (dict(sliding_window=8, kv_cache_len=16), False),
     # constrain returns the sharding it was given: input and output alias
     "tp_mesh": ({}, True),
+    # recurrent state and the convolution's window beside K/V: leaves of
+    # the same tree, row first like the planes (cache/ssm, cache/conv)
+    "hybrid": (None, False),
 }
 
 
@@ -38,8 +42,12 @@ def built(request):
     """An engine for one kind of cache. The tests drive its compiled
     programs directly; its loop stays idle."""
     overrides, tp = _KINDS[request.param]
-    cfg = LlamaConfig.tiny(dtype=jnp.float32, remat=False, **overrides)
-    model = Llama(cfg)
+    if overrides is None:
+        model = FalconH1(FalconH1Config.tiny(dtype=jnp.float32))
+    else:
+        model = Llama(
+            LlamaConfig.tiny(dtype=jnp.float32, remat=False, **overrides)
+        )
     params = model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )["params"]
@@ -232,12 +240,16 @@ def test_calls_consume_the_batch_state_and_nothing_shared(built):
             row=0,
         )
     )
-    plane = next(
-        np.asarray(x)
-        for x in jax.tree_util.tree_leaves(again[0])
-        if x.ndim == 4
-    )
-    assert plane[0].any() and plane[1].any()
+    planes = {
+        jax.tree_util.keystr(path): np.asarray(x)
+        for path, x in jax.tree_util.tree_leaves_with_path(again[0])
+        if x.ndim >= 3  # K/V and scale planes, recurrent state, window
+    }
+    for name, plane in planes.items():
+        assert plane[0].any() and plane[1].any(), name
+    if isinstance(eng._model, FalconH1):
+        assert any(n.endswith("['ssm']") for n in planes)
+        assert any(n.endswith("['conv']") for n in planes)
 
 
 def _reference(model, params, tokens, n):
