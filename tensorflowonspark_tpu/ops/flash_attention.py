@@ -17,14 +17,27 @@ log-sum-exp residuals: a dQ kernel (grid over Q blocks, streaming K), and
 a dK/dV kernel (grid over K blocks, streaming Q). dK/dV are computed per
 *query* head and group-summed outside the kernel — inside, multiple grid
 rows would otherwise race on one KV head's output block.
+
+Packed rows (``segment_ids``) skip the tiles their documents mask whole.
+The ids are reduced once, outside the kernels, to each block's smallest
+and largest id and, from those, to the first and last live block of every
+row of tiles (:func:`_tile_tables`); the kernels get them as one
+scalar-prefetched int32 table. A tile whose q range and k range are
+disjoint holds no equal pair whatever the order of the ids, so leaving it
+out drops only terms that were exactly zero (ids that are not monotone
+merely skip less). A dead step at either end of a row computes nothing and
+its index maps name the block its live neighbour fetched, so the pipeline
+issues no DMA for it.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -127,17 +140,142 @@ def _segment_masked(s, qseg_ref, kseg_ref, block_k: int):
 
 
 # --------------------------------------------------------------------------
+# tiles that segment ids mask whole
+# --------------------------------------------------------------------------
+
+
+def _ranges_disjoint(qmin, qmax, kmin, kmax):
+    """No id of the q block equals any id of the k block: the tile is
+    masked whole. Safe for any order of ids (a tile of disjoint ranges
+    cannot hold an equal pair); overlapping ranges decide nothing."""
+    return (qmax < kmin) | (qmin > kmax)
+
+
+def _span(xp, live, axis: int):
+    """First and last True along ``axis``; the whole axis where none is."""
+    n = live.shape[axis]
+    return live.argmax(axis), n - 1 - xp.flip(live, axis).argmax(axis)
+
+
+def _tile_tables(xp, segment_ids, block_q, block_k, causal, window):
+    """What ``segment_ids`` (B, S) decide about the (S/block_q, S/block_k)
+    tiles of each batch row, over ``xp`` (``jnp`` inside the jitted
+    wrappers, ``numpy`` for :func:`segment_tile_counts`): each block's
+    smallest and largest id, ``live`` (B, nq, nk) by the kernels' own
+    predicate, ``in_window`` (nq, nk) by the causal edge and the window
+    alone, and each row of tiles' first and last live block."""
+    b, s = segment_ids.shape
+    nq, nk = s // block_q, s // block_k
+    qb = segment_ids.reshape(b, nq, block_q)
+    kb = segment_ids.reshape(b, nk, block_k)
+    t = dict(
+        qmin=qb.min(-1), qmax=qb.max(-1), kmin=kb.min(-1), kmax=kb.max(-1)
+    )
+    qi, ki = xp.arange(nq)[:, None], xp.arange(nk)[None, :]
+    in_window = xp.ones((nq, nk), bool)
+    if causal:  # segment ids need sq == sk: the offset is 0
+        in_window = in_window & _causal_live(qi, ki, block_q, block_k, 0)
+    if window is not None:
+        in_window = in_window & _window_live(
+            qi, ki, block_q, block_k, 0, window
+        )
+    live = in_window[None] & ~_ranges_disjoint(
+        t["qmin"][:, :, None], t["qmax"][:, :, None],
+        t["kmin"][:, None, :], t["kmax"][:, None, :],
+    )
+    t["kfirst"], t["klast"] = _span(xp, live, 2)  # (B, nq)
+    t["qfirst"], t["qlast"] = _span(xp, live, 1)  # (B, nk)
+    return dict(t, live=live, in_window=in_window)
+
+
+@dataclasses.dataclass(frozen=True)
+class _TileTable:
+    """Layout of the one int32 table the kernels prefetch into SMEM: four
+    sections of B * nq entries (per q block: smallest id, largest id,
+    first and last live k block), then four of B * nk (per k block: the
+    same, with its first and last live q block). One dimension, so SMEM
+    pads it once."""
+
+    batch: int
+    nq: int
+    nk: int
+
+    Q_SECTIONS = ("qmin", "qmax", "kfirst", "klast")
+    K_SECTIONS = ("kmin", "kmax", "qfirst", "qlast")
+
+    def pack(self, tables) -> jax.Array:
+        return jnp.concatenate(
+            [
+                tables[name].reshape(-1).astype(jnp.int32)
+                for name in self.Q_SECTIONS + self.K_SECTIONS
+            ]
+        )
+
+    def _at(self, tab, name: str, b, i):
+        """Entry of block ``i`` of batch row ``b`` in section ``name``."""
+        if name in self.Q_SECTIONS:
+            section, n, base = self.Q_SECTIONS.index(name), self.nq, 0
+        else:
+            section, n = self.K_SECTIONS.index(name), self.nk
+            base = len(self.Q_SECTIONS) * self.batch * self.nq
+        return tab[base + (section * self.batch + b) * n + i]
+
+    def dead(self, tab, b, qi, ki):
+        """Tile (qi, ki) of batch row b is masked whole by segment ids."""
+        return _ranges_disjoint(
+            self._at(tab, "qmin", b, qi), self._at(tab, "qmax", b, qi),
+            self._at(tab, "kmin", b, ki), self._at(tab, "kmax", b, ki),
+        )
+
+    def clamp_k(self, tab, b, qi, ki):
+        """``ki`` pulled into q block qi's live span."""
+        return jnp.clip(
+            ki, self._at(tab, "kfirst", b, qi), self._at(tab, "klast", b, qi)
+        )
+
+    def clamp_q(self, tab, b, ki, qi):
+        """``qi`` pulled into k block ki's live span."""
+        return jnp.clip(
+            qi, self._at(tab, "qfirst", b, ki), self._at(tab, "qlast", b, ki)
+        )
+
+
+def _segment_tile_table(segment_ids, block_q, block_k, causal, window):
+    """The layout and the packed table of these ids, built inside the
+    caller's jit (a few reductions over (B, S) int32)."""
+    b, s = segment_ids.shape
+    layout = _TileTable(b, s // block_q, s // block_k)
+    return layout, layout.pack(
+        _tile_tables(jnp, segment_ids, block_q, block_k, causal, window)
+    )
+
+
+def segment_tile_counts(
+    segment_ids, *, window, block_q=None, block_k=None
+) -> tuple[int, int]:
+    """How often the skip engages on host ``segment_ids`` (B, S) under
+    causal attention with ``window``: ``(tiles_in_window, tiles_run)``
+    summed over the batch rows, one head's worth — the tiles the causal
+    edge and the window leave, and those of them the kernels compute. The
+    same tables and predicate the kernels use, over numpy."""
+    ids = np.asarray(segment_ids)
+    bq, bk = _default_blocks(ids.shape[1], ids.shape[1], True)
+    t = _tile_tables(np, ids, block_q or bq, block_k or bk, True, window)
+    return ids.shape[0] * int(t["in_window"].sum()), int(t["live"].sum())
+
+
+# --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
 
 
 def _fwd_kernel(
     *refs, block_q: int, block_k: int, seq_q: int, seq_k: int,
-    causal: bool, scale: float, num_k_blocks: int, has_segments: bool,
-    window: int | None = None,
+    causal: bool, scale: float, num_k_blocks: int,
+    tiles: _TileTable | None, heads_q: int, window: int | None = None,
 ):
-    if has_segments:
-        (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
+    if tiles is not None:
+        (tab_ref, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
          o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
@@ -166,6 +304,8 @@ def _fwd_kernel(
     )
     if window is not None:
         live = live & _window_live(qi, ki, block_q, block_k, offset, window)
+    if tiles is not None:
+        live = live & ~tiles.dead(tab_ref, pl.program_id(0) // heads_q, qi, ki)
 
     @pl.when(live)
     def _compute():
@@ -219,6 +359,73 @@ def _check_segment_ids(segment_ids, b: int, sq: int, sk: int) -> None:
         )
 
 
+def _block_maps(sq, sk, block_q, block_k, window, heads_q, tiles):
+    """The index maps' streamed block: ``k_block(h, qi, kr, *tab)`` for a
+    grid over q blocks (forward, dq), ``q_block(h, ki, qr, *tab)`` for one
+    over k blocks (dkv). The restricted index of a windowed grid becomes
+    the actual block (windowed kernels DMA only the ~window-span blocks);
+    ``tab`` is the prefetched tile table, there only with segment ids: a
+    dead step before or after the row's live span then names the block
+    its neighbour fetched, so the pipeline issues no DMA for it."""
+    num_q_blocks, num_k_blocks = sq // block_q, sk // block_k
+    nk_w = _window_grid_k(window, block_q, block_k, num_k_blocks)
+    nq_w = _window_grid_q(window, block_q, block_k, num_q_blocks)
+
+    def k_block(h, qi, kr, *tab):
+        ki = kr
+        if window is not None:
+            ki = kr + _first_k_block(
+                qi, sk - sq, window, block_q, block_k, nk_w, num_k_blocks
+            )
+        if tab:
+            ki = tiles.clamp_k(tab[0], h // heads_q, qi, ki)
+        return ki
+
+    def q_block(h, ki, qr, *tab):
+        qi = qr
+        if window is not None:
+            qi = qr + _first_q_block(
+                ki, sk - sq, window, block_q, block_k, nq_w, num_q_blocks
+            )
+        if tab:
+            qi = tiles.clamp_q(tab[0], h // heads_q, ki, qi)
+        return qi
+
+    return k_block, q_block
+
+
+def _pallas(
+    kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
+    prefetch: bool,
+):
+    """``pl.pallas_call`` over ``grid``. With ``prefetch`` the first
+    operand is the tile table: prefetched into SMEM, handed to every
+    index map after the grid indices and to the kernel ahead of its
+    other refs. Without it the call is the plain one."""
+    if not prefetch:
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch_shapes,
+            interpret=INTERPRET,
+        )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        interpret=INTERPRET,
+    )
+
+
 def _flash_forward(
     q: jax.Array,
     k: jax.Array,
@@ -257,19 +464,22 @@ def _flash_forward(
     num_k_blocks = sk // block_k
     nk_w = _window_grid_k(window, block_q, block_k, num_k_blocks)
     grid = (b * hq, sq // block_q, nk_w)
-
-    def k_block(qi, kr):
-        # restricted ki grid -> actual k block (windowed kernels DMA
-        # only the ~window-span K/V blocks per q block)
-        if window is None:
-            return kr
-        return kr + _first_k_block(
-            qi, sk - sq, window, block_q, block_k, nk_w, num_k_blocks
+    tiles = None
+    operands = [qt, kt, vt]
+    if segment_ids is not None:
+        tiles, table = _segment_tile_table(
+            segment_ids, block_q, block_k, causal, window
         )
+        operands = [table, *operands, *_segment_operands(segment_ids, sq, sk)]
 
-    def kv_row(h, qi, kr):
+    k_block, _ = _block_maps(sq, sk, block_q, block_k, window, hq, tiles)
+
+    def kv_row(h, qi, kr, *tab):
         # grid row h = batch * hq + q_head; its KV row in the (b*hk) array
-        return (h // hq) * hk + (h % hq) // group, k_block(qi, kr), 0
+        return (h // hq) * hk + (h % hq) // group, k_block(h, qi, kr, *tab), 0
+
+    def q_row(h, qi, kr, *tab):
+        return h, qi, 0
 
     kernel = functools.partial(
         _fwd_kernel,
@@ -280,33 +490,35 @@ def _flash_forward(
         causal=causal,
         scale=scale,
         num_k_blocks=num_k_blocks,
-        has_segments=segment_ids is not None,
+        tiles=tiles,
+        heads_q=hq,
         window=window,
     )
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda h, qi, ki: (h, qi, 0)),
+        pl.BlockSpec((1, block_q, d), q_row),
         pl.BlockSpec((1, block_k, d), kv_row),
         pl.BlockSpec((1, block_k, d), kv_row),
     ]
-    operands = [qt, kt, vt]
-    if segment_ids is not None:
+    if tiles is not None:
         in_specs += [
             pl.BlockSpec(
-                (1, block_q, NUM_LANES), lambda h, qi, kr: (h // hq, qi, 0)
+                (1, block_q, NUM_LANES),
+                lambda h, qi, kr, *tab: (h // hq, qi, 0),
             ),
             pl.BlockSpec(
                 (1, NUM_SUBLANES, block_k),
-                lambda h, qi, kr: (h // hq, 0, k_block(qi, kr)),
+                lambda h, qi, kr, *tab: (
+                    h // hq, 0, k_block(h, qi, kr, *tab)
+                ),
             ),
         ]
-        operands += list(_segment_operands(segment_ids, sq, sk))
-    out, lse = pl.pallas_call(
+    out, lse = _pallas(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
+        grid,
+        in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda h, qi, ki: (h, qi, 0)),
-            pl.BlockSpec((1, block_q, NUM_LANES), lambda h, qi, ki: (h, qi, 0)),
+            pl.BlockSpec((1, block_q, d), q_row),
+            pl.BlockSpec((1, block_q, NUM_LANES), q_row),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
@@ -317,7 +529,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max
             pltpu.VMEM((block_q, 1), jnp.float32),  # running denominator
         ],
-        interpret=INTERPRET,
+        prefetch=tiles is not None,
     )(*operands)
     out = out.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)
     if return_lse:
@@ -342,11 +554,11 @@ def _probs(s, lse_col):
 
 def _dq_kernel(
     *refs, block_q: int, block_k: int, seq_q: int, seq_k: int,
-    causal: bool, scale: float, num_k_blocks: int, has_segments: bool,
-    window: int | None = None,
+    causal: bool, scale: float, num_k_blocks: int,
+    tiles: _TileTable | None, heads_q: int, window: int | None = None,
 ):
-    if has_segments:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    if tiles is not None:
+        (tab_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          qseg_ref, kseg_ref, dq_ref, dq_acc) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -372,6 +584,8 @@ def _dq_kernel(
     )
     if window is not None:
         live = live & _window_live(qi, ki, block_q, block_k, offset, window)
+    if tiles is not None:
+        live = live & ~tiles.dead(tab_ref, pl.program_id(0) // heads_q, qi, ki)
 
     @pl.when(live)
     def _compute():
@@ -399,11 +613,11 @@ def _dq_kernel(
 
 def _dkv_kernel(
     *refs, block_q: int, block_k: int, seq_q: int, seq_k: int,
-    causal: bool, scale: float, num_q_blocks: int, has_segments: bool,
-    window: int | None = None,
+    causal: bool, scale: float, num_q_blocks: int,
+    tiles: _TileTable | None, heads_q: int, window: int | None = None,
 ):
-    if has_segments:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    if tiles is not None:
+        (tab_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          qseg_ref, kseg_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -430,6 +644,8 @@ def _dkv_kernel(
     )
     if window is not None:
         live = live & _window_live(qi, ki, block_q, block_k, offset, window)
+    if tiles is not None:
+        live = live & ~tiles.dead(tab_ref, pl.program_id(0) // heads_q, qi, ki)
 
     @pl.when(live)
     def _compute():
@@ -486,31 +702,24 @@ def _flash_backward(
     # NUM_LANES above).
     lse_l = jnp.broadcast_to(lse[:, :, None], (b * hq, sq, NUM_LANES))
     delta_l = jnp.broadcast_to(delta[:, :, None], (b * hq, sq, NUM_LANES))
-    seg_operands: list = []
-    if segment_ids is not None:
-        seg_operands = list(_segment_operands(segment_ids, sq, sk))
-
     num_q_blocks = sq // block_q
     num_k_blocks = sk // block_k
     nk_w = _window_grid_k(window, block_q, block_k, num_k_blocks)
     nq_w = _window_grid_q(window, block_q, block_k, num_q_blocks)
+    tiles = None
+    operands = [qt, kt, vt, gt, lse_l, delta_l]
+    if segment_ids is not None:
+        tiles, table = _segment_tile_table(
+            segment_ids, block_q, block_k, causal, window
+        )
+        operands = [table, *operands, *_segment_operands(segment_ids, sq, sk)]
 
-    def kv_row3(h, a, c):
+    def kv_row(h):
         return (h // hq) * hk + (h % hq) // group
 
-    def k_block(qi, kr):
-        if window is None:
-            return kr
-        return kr + _first_k_block(
-            qi, sk - sq, window, block_q, block_k, nk_w, num_k_blocks
-        )
-
-    def q_block(ki, qr):
-        if window is None:
-            return qr
-        return qr + _first_q_block(
-            ki, sk - sq, window, block_q, block_k, nq_w, num_q_blocks
-        )
+    k_block, q_block = _block_maps(
+        sq, sk, block_q, block_k, window, hq, tiles
+    )
 
     common = dict(
         block_q=block_q,
@@ -519,96 +728,88 @@ def _flash_backward(
         seq_k=sk,
         causal=causal,
         scale=scale,
+        tiles=tiles,
+        heads_q=hq,
         window=window,
     )
 
-    has_segments = segment_ids is not None
+    def dq_q_row(h, qi, kr, *tab):
+        return h, qi, 0
+
+    def dq_kv_row(h, qi, kr, *tab):
+        return kv_row(h), k_block(h, qi, kr, *tab), 0
+
     dq_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda h, qi, kr: (h, qi, 0)),
-        pl.BlockSpec(
-            (1, block_k, d),
-            lambda h, qi, kr: (kv_row3(h, qi, kr), k_block(qi, kr), 0),
-        ),
-        pl.BlockSpec(
-            (1, block_k, d),
-            lambda h, qi, kr: (kv_row3(h, qi, kr), k_block(qi, kr), 0),
-        ),
-        pl.BlockSpec((1, block_q, d), lambda h, qi, kr: (h, qi, 0)),
-        pl.BlockSpec((1, block_q, NUM_LANES), lambda h, qi, kr: (h, qi, 0)),
-        pl.BlockSpec((1, block_q, NUM_LANES), lambda h, qi, kr: (h, qi, 0)),
+        pl.BlockSpec((1, block_q, d), dq_q_row),
+        pl.BlockSpec((1, block_k, d), dq_kv_row),
+        pl.BlockSpec((1, block_k, d), dq_kv_row),
+        pl.BlockSpec((1, block_q, d), dq_q_row),
+        pl.BlockSpec((1, block_q, NUM_LANES), dq_q_row),
+        pl.BlockSpec((1, block_q, NUM_LANES), dq_q_row),
     ]
-    if has_segments:
+    if tiles is not None:
         dq_in_specs += [
             pl.BlockSpec(
-                (1, block_q, NUM_LANES), lambda h, qi, kr: (h // hq, qi, 0)
+                (1, block_q, NUM_LANES),
+                lambda h, qi, kr, *tab: (h // hq, qi, 0),
             ),
             pl.BlockSpec(
                 (1, NUM_SUBLANES, block_k),
-                lambda h, qi, kr: (h // hq, 0, k_block(qi, kr)),
+                lambda h, qi, kr, *tab: (
+                    h // hq, 0, k_block(h, qi, kr, *tab)
+                ),
             ),
         ]
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel,
-            num_k_blocks=num_k_blocks,
-            has_segments=has_segments,
-            **common,
-        ),
-        grid=(b * hq, num_q_blocks, nk_w),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda h, qi, kr: (h, qi, 0)),
+    dq = _pallas(
+        functools.partial(_dq_kernel, num_k_blocks=num_k_blocks, **common),
+        (b * hq, num_q_blocks, nk_w),
+        dq_in_specs,
+        out_specs=pl.BlockSpec((1, block_q, d), dq_q_row),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=INTERPRET,
-    )(qt, kt, vt, gt, lse_l, delta_l, *seg_operands)
+        prefetch=tiles is not None,
+    )(*operands)
 
     # dK/dV per *query* head (b*hq rows): several q heads share one KV head,
     # and revisiting an output block from non-consecutive grid rows is not
     # allowed — group-sum afterwards instead.
+    def dkv_q_row(h, ki, qr, *tab):
+        return h, q_block(h, ki, qr, *tab), 0
+
+    def dkv_kv_row(h, ki, qr, *tab):
+        return kv_row(h), ki, 0
+
+    def dkv_out_row(h, ki, qr, *tab):
+        return h, ki, 0
+
     dkv_in_specs = [
-        pl.BlockSpec(
-            (1, block_q, d), lambda h, ki, qr: (h, q_block(ki, qr), 0)
-        ),
-        pl.BlockSpec(
-            (1, block_k, d), lambda h, ki, qr: (kv_row3(h, ki, qr), ki, 0)
-        ),
-        pl.BlockSpec(
-            (1, block_k, d), lambda h, ki, qr: (kv_row3(h, ki, qr), ki, 0)
-        ),
-        pl.BlockSpec(
-            (1, block_q, d), lambda h, ki, qr: (h, q_block(ki, qr), 0)
-        ),
-        pl.BlockSpec(
-            (1, block_q, NUM_LANES),
-            lambda h, ki, qr: (h, q_block(ki, qr), 0),
-        ),
-        pl.BlockSpec(
-            (1, block_q, NUM_LANES),
-            lambda h, ki, qr: (h, q_block(ki, qr), 0),
-        ),
+        pl.BlockSpec((1, block_q, d), dkv_q_row),
+        pl.BlockSpec((1, block_k, d), dkv_kv_row),
+        pl.BlockSpec((1, block_k, d), dkv_kv_row),
+        pl.BlockSpec((1, block_q, d), dkv_q_row),
+        pl.BlockSpec((1, block_q, NUM_LANES), dkv_q_row),
+        pl.BlockSpec((1, block_q, NUM_LANES), dkv_q_row),
     ]
-    if has_segments:
+    if tiles is not None:
         dkv_in_specs += [
             pl.BlockSpec(
                 (1, block_q, NUM_LANES),
-                lambda h, ki, qr: (h // hq, q_block(ki, qr), 0),
+                lambda h, ki, qr, *tab: (
+                    h // hq, q_block(h, ki, qr, *tab), 0
+                ),
             ),
             pl.BlockSpec(
-                (1, NUM_SUBLANES, block_k), lambda h, ki, qr: (h // hq, 0, ki)
+                (1, NUM_SUBLANES, block_k),
+                lambda h, ki, qr, *tab: (h // hq, 0, ki),
             ),
         ]
-    dk_q, dv_q = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel,
-            num_q_blocks=num_q_blocks,
-            has_segments=has_segments,
-            **common,
-        ),
-        grid=(b * hq, num_k_blocks, nq_w),
-        in_specs=dkv_in_specs,
+    dk_q, dv_q = _pallas(
+        functools.partial(_dkv_kernel, num_q_blocks=num_q_blocks, **common),
+        (b * hq, num_k_blocks, nq_w),
+        dkv_in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda h, ki, qr: (h, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda h, ki, qr: (h, ki, 0)),
+            pl.BlockSpec((1, block_k, d), dkv_out_row),
+            pl.BlockSpec((1, block_k, d), dkv_out_row),
         ],
         out_shape=[
             # f32: the group-sum below must accumulate in full precision —
@@ -620,8 +821,8 @@ def _flash_backward(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=INTERPRET,
-    )(qt, kt, vt, gt, lse_l, delta_l, *seg_operands)
+        prefetch=tiles is not None,
+    )(*operands)
 
     dq = dq.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)
     dk = (
@@ -638,10 +839,16 @@ def _flash_backward(
 # --------------------------------------------------------------------------
 
 
-def _default_blocks(sq: int, sk: int) -> tuple[int, int]:
+def _default_blocks(
+    sq: int, sk: int, segments: bool = False
+) -> tuple[int, int]:
     """Block sizes by sequence length, measured on v5e: bigger blocks
     amortize grid overhead once the sequence is long enough (512 wins at
-    >=4k, 256 at >=1k, 128 below)."""
+    >=4k, 256 at >=1k, 128 below). Packed rows (``segments``) of 8192
+    and more take 1024-wide k blocks: at (2, 8192, 32/8, 128) under a
+    4096 window, 512 x 1024 skips fewer tiles than 512 x 512 (30 %
+    against 35 %) and still takes a tenth less time, in 0.6 of the grid
+    steps; smaller blocks skip more and lose more (PERF.md §6, PR 28)."""
 
     def pick(s):
         for cand in (512, 256, 128):
@@ -651,7 +858,10 @@ def _default_blocks(sq: int, sk: int) -> tuple[int, int]:
                 return cand
         return 128
 
-    return pick(sq), pick(sk)
+    bq, bk = pick(sq), pick(sk)
+    if segments and sk >= 8192 and sk % 1024 == 0:
+        bk = 1024
+    return bq, bk
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -675,7 +885,9 @@ def flash_attention(
         raise ValueError(
             f"window={window} requires causal=True and window >= 1"
         )
-    bq, bk = _default_blocks(q.shape[1], k.shape[1])
+    bq, bk = _default_blocks(
+        q.shape[1], k.shape[1], segment_ids is not None
+    )
     return _flash_forward(
         q, k, v, causal, scale, block_q or bq, block_k or bk,
         segment_ids=segment_ids, window=window,
@@ -687,7 +899,9 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, window, segment_ids):
         raise ValueError(
             f"window={window} requires causal=True and window >= 1"
         )
-    bq, bk = _default_blocks(q.shape[1], k.shape[1])
+    bq, bk = _default_blocks(
+        q.shape[1], k.shape[1], segment_ids is not None
+    )
     out, lse = _flash_forward(
         q, k, v, causal, scale, block_q or bq, block_k or bk,
         return_lse=True, segment_ids=segment_ids, window=window,
@@ -697,7 +911,9 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, window, segment_ids):
 
 def _bwd(causal, scale, block_q, block_k, window, res, g):
     q, k, v, out, lse, segment_ids = res
-    bq, bk = _default_blocks(q.shape[1], k.shape[1])
+    bq, bk = _default_blocks(
+        q.shape[1], k.shape[1], segment_ids is not None
+    )
     dq, dk, dv = _flash_backward(
         q, k, v, out, lse, g, causal, scale, block_q or bq, block_k or bk,
         segment_ids=segment_ids, window=window,

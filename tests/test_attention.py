@@ -241,6 +241,173 @@ def test_dispatcher_flash_segments_matches_xla(monkeypatch):
     )
 
 
+# -- tiles that segment ids mask whole are skipped ----------------------
+
+
+def _ids(*runs):
+    """A row of ids from (id, length) runs."""
+    return np.concatenate([np.full(n, i, np.int32) for i, n in runs])
+
+
+# one row of 512 positions each; the kernels run 128-wide blocks on them
+_SKIP_ROWS = {
+    "on_block_edges": _ids((1, 128), (2, 256), (3, 128)),
+    "off_block_edges": _ids((1, 100), (2, 190), (3, 222)),
+    "one_document": _ids((1, 512)),
+    "several_in_one_block": _ids((1, 40), (2, 30), (3, 50), (4, 136), (5, 256)),
+    "trailing_padding": _ids((1, 200), (2, 150), (0, 162)),
+    "not_monotone": _ids((1, 128), (2, 128), (1, 256)),
+}
+
+
+def _assert_flash_matches_xla_with_segments(
+    q, k, v, seg, window, block_q, block_k
+):
+    """Forward, dq, dk and dv of the kernels against the XLA path."""
+    seg = jnp.asarray(seg)
+
+    def flash(q, k, v):
+        return fa.flash_attention(
+            q, k, v, True, None, block_q, block_k, window, seg
+        )
+
+    def ref(q, k, v):
+        return _xla_attention(
+            q, k, v, causal=True, segment_ids=seg, window=window
+        )
+
+    g = jax.random.normal(jax.random.PRNGKey(7), q.shape, q.dtype)
+    out_f, vjp_f = jax.vjp(flash, q, k, v)
+    out_r, vjp_r = jax.vjp(ref, q, k, v)
+    for name, got, want in zip(
+        ("out", "dq", "dk", "dv"), (out_f, *vjp_f(g)), (out_r, *vjp_r(g))
+    ):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=5e-3, atol=5e-3,
+            err_msg=name,
+        )
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("layout", sorted(_SKIP_ROWS))
+def test_flash_skips_segment_tiles_and_matches_xla(
+    layout, window, monkeypatch
+):
+    """Each layout of documents over the blocks, with and without the
+    window: the kernels leave out the tiles the ids mask whole and still
+    give the XLA path's forward, dq, dk and dv. The second batch row is
+    laid out otherwise, so a table read for the wrong row would show."""
+    monkeypatch.setattr(fa, "INTERPRET", True)
+    other = "on_block_edges" if layout == "off_block_edges" else "off_block_edges"
+    seg = np.stack([_SKIP_ROWS[layout], _SKIP_ROWS[other]])
+    in_window, run = fa.segment_tile_counts(
+        seg[:1], window=window, block_q=128, block_k=128
+    )
+    if layout == "one_document":
+        assert run == in_window
+    else:
+        assert run < in_window
+    q, k, v = _qkv(b=2, sq=512, sk=512, hq=2, hk=2, d=32)
+    _assert_flash_matches_xla_with_segments(q, k, v, seg, window, 128, 128)
+
+
+@pytest.mark.parametrize("blocks", [(128, 64), (64, 128)])
+@pytest.mark.parametrize("layout", ["off_block_edges", "trailing_padding"])
+def test_flash_skips_segment_tiles_gqa_uneven_blocks(
+    layout, blocks, monkeypatch
+):
+    """GQA 4/2 with block_q != block_k, either the larger (one of them
+    under one lane tile), under the window."""
+    monkeypatch.setattr(fa, "INTERPRET", True)
+    seg = np.stack([_SKIP_ROWS[layout], _SKIP_ROWS["not_monotone"]])
+    q, k, v = _qkv(b=2, sq=512, sk=512, hq=4, hk=2, d=32)
+    _assert_flash_matches_xla_with_segments(q, k, v, seg, 200, *blocks)
+
+
+def _random_packing(rng, rows, s):
+    """Rows of documents with log-uniform lengths; some rows end in id 0
+    padding, some reuse an id further on (ids that are not monotone)."""
+    seg = np.zeros((rows, s), np.int32)
+    for r in range(rows):
+        pos, i = 0, 1
+        fill = s if rng.random() < 0.5 else int(s * rng.uniform(0.6, 1.0))
+        while pos < fill:
+            n = int(np.exp(rng.uniform(np.log(4), np.log(s))))
+            seg[r, pos:min(pos + n, fill)] = i if rng.random() < 0.8 else 1
+            pos, i = pos + n, i + 1
+    return seg
+
+
+@pytest.mark.parametrize("window", [None, 96, 300])
+@pytest.mark.parametrize("blocks", [(64, 64), (128, 64), (64, 128)])
+def test_segment_tile_counts_against_the_dense_mask(blocks, window):
+    """Over random packings: no tile that holds an unmasked pair is ever
+    skipped; ``tiles_run`` is what the kernels' own predicate counts over
+    the packed table they prefetch; jnp and numpy build the same tables."""
+    bq, bk = blocks
+    rng = np.random.default_rng([bq, bk, window or 0])
+    b, s = 6, 512
+    seg = _random_packing(rng, b, s)
+    pos = np.arange(s)
+    dense = (seg[:, :, None] == seg[:, None, :]) & (pos[:, None] >= pos[None, :])
+    if window is not None:
+        dense &= pos[:, None] - pos[None, :] < window
+    holds_pair = dense.reshape(b, s // bq, bq, s // bk, bk).any(axis=(2, 4))
+
+    tables = fa._tile_tables(np, seg, bq, bk, True, window)
+    assert not (holds_pair & ~tables["live"]).any()
+    in_window, run = fa.segment_tile_counts(
+        seg, window=window, block_q=bq, block_k=bk
+    )
+    assert in_window == b * int(tables["in_window"].sum())
+    assert holds_pair.sum() <= run <= in_window
+
+    layout, table = fa._segment_tile_table(jnp.asarray(seg), bq, bk, True, window)
+    table = np.asarray(table)
+    kernel_run = 0
+    for row in range(b):
+        for qi in range(s // bq):
+            for ki in range(s // bk):
+                live = fa._causal_live(qi, ki, bq, bk, 0)
+                if window is not None:
+                    live &= fa._window_live(qi, ki, bq, bk, 0, window)
+                live &= not layout.dead(table, row, qi, ki)
+                kernel_run += bool(live)
+                # a live tile is fetched under its own index
+                if live:
+                    assert layout.clamp_k(table, row, qi, ki) == ki
+                    assert layout.clamp_q(table, row, ki, qi) == qi
+    assert kernel_run == run
+
+
+def test_segment_tile_counts_on_the_train_cells_pool():
+    """The figure ISSUE 28 rests on: of the tiles the window leaves on
+    the rows of ``perfbench/traffic/packed8k.json``, packed as the train
+    cell packs them, 30-40 % are masked whole at 512 x 512."""
+    from perfbench import harness, traffic
+    from tensorflowonspark_tpu.data.packing import pack_batches
+
+    spec = harness.load_json("traffic", "packed8k.json")
+    seg = []
+    for lengths in traffic.document_rows(spec):
+        (row,) = pack_batches(
+            [[1] * n for n in lengths], 1, spec["seq_len"], drop_remainder=False
+        )
+        seg.append(row["segment_ids"][0, :-1])
+    seg = np.stack(seg)
+    assert seg.shape == (32, 8192)
+    in_window, run = fa.segment_tile_counts(
+        seg, window=4096, block_q=512, block_k=512
+    )
+    assert in_window == 32 * 108
+    assert 0.30 <= 1 - run / in_window <= 0.40
+    # the blocks the kernels take on such rows skip a little less
+    assert fa._default_blocks(8192, 8192, True) == (512, 1024)
+    in_window, run = fa.segment_tile_counts(seg, window=4096)
+    assert in_window == 32 * 60
+    assert 0.27 <= 1 - run / in_window <= 0.33
+
+
 # -- sliding-window (Mistral-style local) attention --------------------
 
 

@@ -13,6 +13,10 @@ call must not happen while any module is imported (every xdist worker
 imports every test file) nor in a child process.
 """
 
+import json
+import pathlib
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -85,6 +89,86 @@ def test_flash_attention_compiles_for_v5e(one_chip, case):
         shapes.append(((b, s), jnp.int32))
     text = _compiled_text(_attention(grad, window), one_chip, *shapes)
     assert "tpu_custom_call" in text
+
+
+# -- the train cell's packed attention: (2, 8192, 32/8, 128), window 4096 --
+
+
+@pytest.fixture(scope="module")
+def cell_attention_calls(one_chip):
+    """The ``tpu_custom_call`` lines of the train cell's attention, forward
+    and backward, compiled with and without segment ids. Called from
+    inside a flax module, as the model calls it: that is where the
+    kernels get the instruction names the benchmark's patterns look for."""
+    import flax.linen as nn
+
+    from tensorflowonspark_tpu.ops.attention import _jitted_attention
+
+    class Attend(nn.Module):
+        @nn.compact
+        def __call__(self, q, k, v, seg=None):
+            return _jitted_attention(
+                q, k, v, causal=True, impl="flash", window=4096,
+                segment_ids=seg,
+            )
+
+    def step(q, k, v, seg=None):
+        def loss(q, k, v):
+            return Attend().apply({}, q, k, v, seg).astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    shapes = [((2, 8192, 32, 128), BF16)] + [((2, 8192, 8, 128), BF16)] * 2
+
+    def calls(*more):
+        text = _compiled_text(step, one_chip, *shapes, *more)
+        return [
+            line.strip() for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+        ]
+
+    return {"packed": calls(((2, 8192), jnp.int32)), "unpacked": calls()}
+
+
+# the tile table: one int32 vector, the first operand of the call
+_PREFETCH_OPERAND = r"operand_layout_constraints=\{s32\[\d+\]\{0\}"
+
+
+def test_packed_cell_attention_compiles_with_scalar_prefetch(
+    cell_attention_calls,
+):
+    packed = cell_attention_calls["packed"]
+    assert len(packed) == 3  # forward, dq, dkv
+    assert all(re.search(_PREFETCH_OPERAND, line) for line in packed)
+
+
+def test_unpacked_cell_attention_has_no_prefetch_operand(cell_attention_calls):
+    unpacked = cell_attention_calls["unpacked"]
+    assert len(unpacked) == 3
+    assert not any("s32[" in line for line in unpacked)
+
+
+@pytest.mark.parametrize("which", ["fwd", "dq", "dkv"])
+def test_flash_roofline_patterns_still_find_the_packed_kernels(
+    cell_attention_calls, which
+):
+    """``flash_roofline_pct.train`` finds the three kernels by instruction
+    name and result dtypes (its file is read here, not edited): each
+    pattern matches exactly one of the packed calls, as the trace's
+    reduction shortens them."""
+    from perfbench.trace_reduce import short
+
+    metric = json.loads(
+        (
+            pathlib.Path(__file__).parents[1]
+            / "perfbench/metrics/flash_roofline_pct.train.json"
+        ).read_text()
+    )
+    (pattern,) = [
+        k["pattern"] for k in metric["params"]["kernels"] if k["which"] == which
+    ]
+    names = [short(line) for line in cell_attention_calls["packed"]]
+    assert sum(bool(re.search(pattern, n)) for n in names) == 1, names
 
 
 # ResNet-50 b=256 activations viewed as (rows, C): the stem and the widest
