@@ -48,7 +48,7 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# `train` holds the bench's memory setting (bench.py _bench_llama): fp32
+# `train` holds the memory setting of a training job at this size: fp32
 # params, bf16 Adam moments, no remat. `logit_tol` bounds, for every
 # emitted token, max(logits) - logits[token] under the plain forward, and
 # |engine logprob - forward logprob|; `loss_rtol` bounds the fsdp=4 run's
@@ -154,8 +154,8 @@ def _llama_config(c: dict, **kw):
 
 
 def _train_setup(c: dict, mesh, seed: int):
-    """Model, sharded state and step — wired exactly as
-    ``benchmarks/real_chip.py:bench_llama1b`` wires them."""
+    """Model, sharded state and step, wired as a training job wires
+    them (``examples/llama/llama_fsdp.py``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -7,7 +7,7 @@ scales from a tiny CPU smoke run to the real thing by flags: mesh axes,
 model size, remat, and checkpoint/resume are all config.
 
 MFU accounting: 6*P*T model flops per token (fwd+bwd) over the measured
-step time, against the per-chip peak ``benchmarks/peaks.py`` publishes for
+step time, against the per-chip peak ``perfbench/peaks.py`` publishes for
 the device's ``device_kind`` (a TPU kind missing there is an error; on the
 CPU no utilisation is computed).
 
@@ -266,11 +266,11 @@ def main_fun(args, ctx):
         f"({tokens_per_step / step_time / jax.device_count():.0f} /chip)"
     )
     if device.platform == "tpu":
-        from benchmarks.peaks import peak_for
+        from perfbench.peaks import peak_for
 
         model_flops = 6 * n_params * tokens_per_step  # fwd+bwd, no attn term
         mfu = model_flops / step_time / jax.device_count() / (
-            peak_for(device).bf16_tflops * 1e12
+            peak_for(device.device_kind)["flops"]
         )
         line += f", MFU {mfu * 100:.1f}%"
     print(line)

@@ -1,7 +1,7 @@
 """MNIST training with MANIFEST feeding — node-side feeders in SPARK mode.
 
-The push plane routes every byte through the driver (its ceiling on a
-CPU host: ``benchmarks/feed_plane.py``); the reference never hit this because
+The push plane routes every byte through the driver, one host's
+ceiling; the reference never hit this because
 its feed tasks ran on the executors with HDFS locality. This example
 restores that property: the driver feeds ``FileManifest`` records (one
 per TFRecord shard — O(files) driver bytes) and every node expands its

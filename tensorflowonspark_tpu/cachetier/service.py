@@ -380,6 +380,10 @@ class CacheServer:
         self.host, self.port = self._lsock.getsockname()[:2]
         self._stop = threading.Event()
         self._accept_thread: threading.Thread | None = None
+        # accepted connections, so close() can end them: a handler
+        # thread blocked in recv would otherwise answer one more request
+        self._lock = threading.Lock()
+        self._conns: set[socket.socket] = set()
 
     @property
     def address(self) -> str:
@@ -402,6 +406,15 @@ class CacheServer:
                 continue
             except OSError:
                 break  # closed under us
+            with self._lock:
+                # close() sets _stop before it takes the lock: either
+                # it sees this connection in the set, or we see _stop
+                stopped = self._stop.is_set()
+                if not stopped:
+                    self._conns.add(conn)
+            if stopped:
+                conn.close()
+                break
             threading.Thread(
                 target=self._serve_conn, args=(conn,),
                 name="cachetier-conn", daemon=True,
@@ -426,6 +439,8 @@ class CacheServer:
         except OSError:
             pass  # client vanished mid-reply; nothing to clean up
         finally:
+            with self._lock:
+                self._conns.discard(conn)
             try:
                 conn.close()
             except OSError:
@@ -482,6 +497,16 @@ class CacheServer:
             pass
         if self._accept_thread is not None:
             self._accept_thread.join(timeout=2.0)
+        with self._lock:
+            conns = list(self._conns)
+        for conn in conns:
+            # shutdown wakes the handler's recv and fails its send; the
+            # handler thread then leaves the set on its own
+            try:
+                conn.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass  # the client went first
+            conn.close()
 
 
 # ---------------------------------------------------------------------------

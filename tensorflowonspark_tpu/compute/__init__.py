@@ -34,7 +34,6 @@ from tensorflowonspark_tpu.compute.train import (
     TrainState,
     build_train_step,
     build_eval_step,
-    build_update_step,
     fsdp_shardings,
     shard_state,
     state_shardings,
@@ -58,7 +57,6 @@ __all__ = [
     "TrainState",
     "build_train_step",
     "build_eval_step",
-    "build_update_step",
     "fsdp_shardings",
     "shard_state",
     "state_shardings",

@@ -26,7 +26,6 @@ already carries ``model``/``seq`` axes).
 from __future__ import annotations
 
 import re
-import time
 from typing import Any, Callable
 
 import jax
@@ -46,8 +45,6 @@ from tensorflowonspark_tpu.obs import spans as obs_spans
 _PER_PARAM_STATE_RE = re.compile(_layout.OPTIMIZER_PARAM_STATE_PATTERN)
 
 # The named scope grouping the optimizer's device ops in traces.
-# obs/trace_report.py's 'weight_update' classifier keys on this literal
-# (lockstep-pinned by tests/test_obs.py).
 WEIGHT_UPDATE_SCOPE = "train.weight_update"
 
 
@@ -245,8 +242,8 @@ def build_train_step(
     all-gather back to their table shardings. ``zero_sharding=False``
     is the replicated-optimizer escape hatch for A/B: the weight-update
     decomposition itself is elementwise, hence byte-identical across
-    knobs on identical gradients (``bench.py --zero``'s smoke gate pins
-    this); the full train paths agree to reduction-order tolerance
+    knobs on identical gradients; the full train paths agree to
+    reduction-order tolerance
     (reduce-scatter vs all-reduce summation grouping, ~1 ulp). State
     committed with :func:`shard_state` should use the SAME knob value
     (a mismatched state is re-committed once at the first call).
@@ -357,8 +354,7 @@ def make_step_fn(
     the updated params all-gather back to their own shardings — the
     arXiv 2004.13336 dataflow. The optimizer arithmetic itself is
     grouped under a ``train.weight_update`` ``jax.named_scope`` so
-    device traces attribute its ops (``obs.trace_report``'s
-    ``weight_update`` category).
+    device traces can attribute its ops.
     """
     if accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
@@ -466,79 +462,14 @@ def make_step_fn(
 def _apply_weight_update(
     tx: optax.GradientTransformation, state: TrainState, grads
 ) -> TrainState:
-    """The optimizer apply shared by :func:`make_step_fn` and
-    :func:`build_update_step` — ONE implementation, so the isolated
-    A/B span (bench.py --zero) measures exactly what the train step
-    runs, under the named scope device traces attribute
-    (obs.trace_report's ``weight_update`` category — the before/after
-    evidence for the ZeRO A/B)."""
+    """The optimizer apply of :func:`make_step_fn`, under the named
+    scope device traces attribute."""
     with jax.named_scope(WEIGHT_UPDATE_SCOPE):
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
     return TrainState(
         step=state.step + 1, params=new_params, opt_state=new_opt
     )
-
-
-def build_update_step(
-    tx: optax.GradientTransformation,
-    mesh: Mesh,
-    param_shardings: Any | None = None,
-    zero_sharding: bool = True,
-    donate: bool = True,
-) -> Callable[[TrainState, Any], TrainState]:
-    """Compile ``(state, grads) -> state`` — the weight update ALONE.
-
-    Same shardings/donation discipline as :func:`build_train_step`
-    (gradients arrive in the ZeRO update layout when the knob is on),
-    so the optimizer fraction of step time is measurable in isolation:
-    the ``bench.py --zero`` A/B leg times this against fixed gradients.
-    Every call runs under a ``train.weight_update`` span and is
-    observed into the ``train_weight_update_seconds`` histogram; like
-    ``train.step`` the span measures DISPATCH — callers timing the
-    device must barrier on a fetched leaf.
-    """
-    from tensorflowonspark_tpu.obs.registry import default_registry
-
-    hist = default_registry().histogram(
-        "train_weight_update_seconds",
-        "wall seconds per optimizer weight-update dispatch",
-    )
-
-    def update(state: TrainState, grads) -> TrainState:
-        return _apply_weight_update(tx, state, grads)
-
-    compiled: dict[str, Any] = {}
-
-    def wrapped(state: TrainState, grads) -> TrainState:
-        if "fn" not in compiled:
-            psh = (
-                param_shardings
-                if param_shardings is not None
-                else jax.tree.map(lambda _: replicated(mesh), state.params)
-            )
-            state_sh = state_shardings(state, mesh, psh, zero_sharding)
-            grad_sh = (
-                zero_update_shardings(state.params, mesh, psh)
-                if zero_sharding
-                else psh
-            )
-            compiled["fn"] = jax.jit(
-                update,
-                in_shardings=(state_sh, grad_sh),
-                out_shardings=state_sh,
-                donate_argnums=(0,) if donate else (),
-            )
-            # same first-call commit as build_train_step: accept states
-            # built without shard_state
-            state = jax.tree.map(jax.device_put, state, state_sh)
-        t0 = time.perf_counter()
-        with obs_spans.span(WEIGHT_UPDATE_SCOPE):
-            out = compiled["fn"](state, grads)
-        hist.observe(time.perf_counter() - t0)
-        return out
-
-    return wrapped
 
 
 def build_eval_step(

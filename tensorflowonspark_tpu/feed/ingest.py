@@ -1,10 +1,8 @@
 """Driverless pull ingestion — executor-local sharded columnar readers.
 
 The push plane's ceiling shows why this module exists: every byte of
-``InputMode.SPARK`` crosses the single driver process, and the aggregate
-*collapses* as the cluster grows (CPU-host rows of
-``benchmarks/feed_plane.py`` in
-``benchmarks/results/feed_plane_scaling.jsonl``). The reference never
+``InputMode.SPARK`` crosses the single driver process, so the aggregate
+is bounded by one host however many nodes consume. The reference never
 had the problem because its feed
 tasks ran on the executors with HDFS locality — the driver shipped
 closures, not bytes (SURVEY.md §3.2); tf.data (arXiv:2101.12127) makes

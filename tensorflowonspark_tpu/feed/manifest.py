@@ -1,7 +1,6 @@
 """Manifest feeding — node-side feeders over the push control plane.
 
-The push plane has a ceiling per driver host (`benchmarks/feed_plane.py`
-measures it on a CPU host; rows in `benchmarks/results/`): every byte of
+The push plane has a ceiling per driver host: every byte of
 ``InputMode.SPARK`` crosses the driver. The
 reference never had this problem because its feed tasks ran *on the
 executors* with HDFS data locality — the driver shipped closures, not

@@ -1,6 +1,6 @@
-"""Unified observability: spans, metrics, trace attribution.
+"""Unified observability: spans and metrics.
 
-Three pillars, one package (round-5 verdict: the stack could build fast
+Two pillars, one package (round-5 verdict: the stack could build fast
 paths but not *see* them):
 
 - :mod:`~tensorflowonspark_tpu.obs.spans` — host-side span tracer
@@ -13,11 +13,6 @@ paths but not *see* them):
   by the HTTP server and each node runtime;
   ``utils.metrics.MetricsWriter`` is a sink of the registry
   (``Registry.publish``), not a parallel system.
-- :mod:`~tensorflowonspark_tpu.obs.trace_report` — nesting-aware
-  self-time over captured profiler traces plus an op classifier
-  (MXU / vector / copy / infeed / collective / host), emitted as a
-  JSON artifact by ``bench.py --trace`` and readable via
-  ``python -m tensorflowonspark_tpu.tools.trace_report``.
 
 Plus the cluster-wide plane (docs/OBSERVABILITY.md):
 

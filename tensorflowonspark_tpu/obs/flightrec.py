@@ -17,8 +17,8 @@ tracer's ring IS the bound), a metrics snapshot, and a small event log
   ``map_fun`` ferries an exception.
 
 Dumps embed the tracer's Chrome-trace export (with its
-``trace_context`` metadata), so ``tools/trace_report.py`` and
-``tools/trace_merge.py`` read them directly — a postmortem is one
+``trace_context`` metadata), so ``tools/trace_merge.py`` reads them
+directly — a postmortem is one
 ``trace_merge logs/flightrec-*.json`` away from a cluster timeline.
 
 Module-level :func:`install` / :func:`note` / :func:`dump_now` keep
@@ -172,7 +172,7 @@ class FlightRecorder:
             "events": events,
             "metrics": metrics_text,
             # full Chrome-trace export (with trace_context metadata):
-            # trace_report/trace_merge read dumps as trace files
+            # trace_merge reads dumps as trace files
             "spans": self.tracer.export(process_name=self.process),
         }
 

@@ -17,7 +17,7 @@ window". This module is that read substrate:
   wall-clock window;
 - every appended point optionally spills to JSONL
   (``spill_path``), so a run leaves its full telemetry history on
-  disk, and :meth:`to_artifact` packages the rings for bench
+  disk, and :meth:`to_artifact` packages the rings for run
   artifacts (windowed history instead of a point snapshot).
 
 Per-series rings are ``deque(maxlen=capacity)`` — memory is bounded by

@@ -1,10 +1,9 @@
 """Declarative SLOs + multi-window burn-rate evaluation over History.
 
-ROADMAP items 1/4/5 each restated "p99 within budget under X" as a
-hand-rolled bench assert; this module makes the objective declarative
-and the evaluation uniform, so serve_model ``/statusz``, the router's
-shed annotations, and ``bench.py --serve-fleet/--rollout/--serve-slo``
-all gate on the SAME evaluator.
+"p99 within budget under X" used to be restated as a hand-rolled
+assert at each site; this module makes the objective declarative and
+the evaluation uniform, so serve_model ``/statusz`` and the router's
+shed annotations gate on the SAME evaluator.
 
 An :class:`SLO` names an objective over metrics that ``obs.history``
 already retains:
@@ -130,8 +129,7 @@ def router_slos(
     fast_burn: float = 14.0,
     slow_burn: float = 6.0,
 ) -> tuple[SLO, ...]:
-    """Fleet-level objectives over the router's registry — the single
-    budget gate bench.py's fleet/rollout legs adopt."""
+    """Fleet-level objectives over the router's registry."""
     return (
         SLO(
             name="fleet_latency",

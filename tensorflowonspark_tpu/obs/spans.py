@@ -1,9 +1,8 @@
 """Host-side span tracing: where does the host's time go, per phase.
 
-The device side already has a first-class story (``jax.profiler.trace``
-→ ``obs.trace_report``); what the stack lacked was the HOST side — queue
-waits, batch formation, dispatch, fetches — the glue the round-5 verdict
-could only hand-wave about ("57× latency tax ≈ host RPCs"). A
+The device side already has a first-class story (``jax.profiler.trace``);
+what the stack lacked was the HOST side — queue waits, batch formation,
+dispatch, fetches — the glue between device programs. A
 :class:`SpanTracer` records named intervals into a thread-safe ring
 buffer with microsecond timestamps, cheap enough to leave on in
 production hot paths (one ``perf_counter`` pair + a deque append per
@@ -17,8 +16,7 @@ Three consumption surfaces, one recording API:
 - **Chrome trace export**: :meth:`SpanTracer.export` /
   :meth:`write_chrome_trace` emit standard ``traceEvents`` JSON
   (``ph: "X"`` complete events, per-thread lanes) that
-  ``obs.trace_report`` — and chrome://tracing / Perfetto — read
-  directly.
+  ``obs.trace_merge``, chrome://tracing and Perfetto read directly.
 - **XLA timeline bridge**: every span body also runs under
   ``jax.profiler.TraceAnnotation`` (and :meth:`step_span` under
   ``StepTraceAnnotation``), so when a device trace is active the host
@@ -192,9 +190,10 @@ class SpanTracer:
         (``tid="interval:<name>"``), NOT the calling thread's lane: a
         backdated interval (a ~1s queue wait recorded at admission time)
         would otherwise span real call-stack spans the same thread
-        recorded in the meantime without properly nesting them, and
-        nesting-aware consumers (``obs.trace_report.self_times``) would
-        subtract those spans from it — producing negative self time.
+        recorded in the meantime without properly nesting them, and a
+        nesting-aware reader (self time = duration minus children)
+        would subtract those spans from it — producing negative self
+        time.
         ``summary()`` percentiles key on name only and are identical
         either way.
         """
@@ -271,7 +270,7 @@ class SpanTracer:
     def export(self, process_name: str | None = None) -> dict:
         """The buffer as a Chrome-trace dict (``{"traceEvents": [...]}``,
         ``ts``/``dur`` in microseconds relative to the tracer epoch) —
-        the format ``obs.trace_report`` and chrome://tracing read."""
+        the format ``obs.trace_merge`` and chrome://tracing read."""
         pid = os.getpid()
         # Cross-process alignment metadata: the wall-clock time of this
         # tracer's epoch (event ts are relative to it), plus the run's

@@ -65,9 +65,7 @@ def _choose_blocks(rows: int, cols: int) -> tuple[int, int]:
     Inception-v3 BN sits at C=32/48/80/96 and the ResNet stem at C=64
     (models/inception.py, models/resnet.py) — so ``min(cols, 512)``
     passes sub-128-lane and non-128-aligned column blocks straight to
-    Mosaic, which pads the lane dimension internally; those shapes are in
-    ``benchmarks/pallas_bn_smoke.py``'s TPU list so a real-chip lowering
-    failure shows up in the cheap smoke, not the conv-net compile. Rows
+    Mosaic, which pads the lane dimension internally. Rows
     default to 1024 (so a (1024, 512) bf16 tile is 1 MB — big enough to
     hit DMA streaming rate, small enough to double-buffer in VMEM).
     """

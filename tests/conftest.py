@@ -21,7 +21,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 # starts, through the switch JAX itself reads. The programs' entry points
 # turn the cache on (utils.util.enable_compile_cache: the directory in
 # JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache), so without this
-# every bench and node subprocess a test starts would write there.
+# every node and smoke subprocess a test starts would write there.
 #
 # History of the pin: under jaxlib 0.4.36 (found in PR 15's tier-1) a
 # MULTI-DEVICE/sharded CPU executable restored from the persistent cache

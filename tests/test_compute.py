@@ -218,40 +218,6 @@ def test_zero_train_step_matches_replicated(mesh_dp):
     assert s_off.opt_state[0].mu["w1"].sharding.spec == P()
 
 
-def test_build_update_step_matches_inline_update(mesh_dp):
-    """The isolated weight-update step (the bench's optimizer-span
-    probe) must produce exactly tx.update + apply_updates, ZeRO-sharded
-    or not, and feed the train_weight_update_seconds histogram."""
-    from tensorflowonspark_tpu.compute import build_update_step
-    from tensorflowonspark_tpu.obs.registry import default_registry
-
-    rng = np.random.default_rng(11)
-    params = {"w": jnp.asarray(rng.normal(size=(16, 4)).astype(np.float32))}
-    grads = {"w": jnp.asarray(rng.normal(size=(16, 4)).astype(np.float32))}
-    tx = optax.adamw(1e-2)
-
-    # eager single-device reference (jit fusion may differ by ~1 ulp,
-    # so the reference check is allclose; the on-vs-off check is exact)
-    ref_state = TrainState.create(jax.tree.map(jnp.array, params), tx)
-    upd, new_opt = tx.update(grads, ref_state.opt_state, ref_state.params)
-    ref_params = optax.apply_updates(ref_state.params, upd)
-
-    results = {}
-    for zero in (True, False):
-        state = TrainState.create(jax.tree.map(jnp.array, params), tx)
-        step = build_update_step(tx, mesh_dp, zero_sharding=zero)
-        out = step(state, jax.tree.map(jnp.array, grads))
-        np.testing.assert_allclose(
-            np.asarray(out.params["w"]), np.asarray(ref_params["w"]),
-            rtol=1e-6,
-        )
-        assert int(out.step) == 1
-        results[zero] = np.asarray(out.params["w"]).tobytes()
-    # the sharded decomposition is elementwise: byte-exact across knobs
-    assert results[True] == results[False]
-    assert "train_weight_update_seconds" in default_registry().render()
-
-
 def test_checkpoint_roundtrip(tmp_path, mesh_dp):
     from tensorflowonspark_tpu.compute.checkpoint import (
         restore_checkpoint,

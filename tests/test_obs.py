@@ -1,4 +1,4 @@
-"""obs/: span tracer, metrics registry, trace attribution — and their
+"""obs/: span tracer and metrics registry — and their
 wiring into the serving engine, the HTTP server, and the node runtime."""
 
 import gzip
@@ -6,7 +6,6 @@ import json
 import threading
 import time
 import urllib.request
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -14,15 +13,29 @@ import pytest
 
 from tensorflowonspark_tpu.obs import registry as obs_registry
 from tensorflowonspark_tpu.obs import spans as obs_spans
-from tensorflowonspark_tpu.obs import trace_report
 
 
 # -- spans -------------------------------------------------------------
 
 
+def _read_chrome_trace(path):
+    """(complete events, {tid: thread name}, process name) of one
+    exported Chrome-trace file."""
+    with gzip.open(path, "rt") as f:
+        events = json.load(f)["traceEvents"]
+    meta = [e for e in events if e["ph"] == "M"]
+    threads = {
+        e["tid"]: e["args"]["name"] for e in meta if e["name"] == "thread_name"
+    }
+    (process,) = [
+        e["args"]["name"] for e in meta if e["name"] == "process_name"
+    ]
+    return [e for e in events if e["ph"] == "X"], threads, process
+
+
 def test_span_nesting_chrome_export_roundtrip(tmp_path):
-    """Nested spans export as Chrome-trace complete events that
-    obs.trace_report's nesting-aware self-time reads back correctly."""
+    """Nested spans export as Chrome-trace complete events on one lane,
+    the inner event's interval inside the outer's."""
     tr = obs_spans.SpanTracer(capacity=64)
     with tr.span("outer", phase="x"):
         with tr.span("inner"):
@@ -34,31 +47,25 @@ def test_span_nesting_chrome_export_roundtrip(tmp_path):
     assert outer.ts <= inner.ts  # outer opened first
     assert outer.args == {"phase": "x"}
 
-    run = tmp_path / "plugins" / "profile" / "run0"
-    run.mkdir(parents=True)
-    tr.write_chrome_trace(
-        str(run / "host.trace.json.gz"), process_name="python host"
+    path = tr.write_chrome_trace(
+        str(tmp_path / "host.trace.json.gz"), process_name="python host"
     )
-    report = trace_report.build_report(str(tmp_path))
-    att = report["attribution"]
-    # a host-lane-only trace: everything lands in the host bucket
-    assert att["device_total_us"] == 0
-    assert att["host_total_us"] > 0
-    assert att["categories"]["host"]["pct"] == 100.0
-    # self-time semantics survive the round trip: outer's self time
-    # excludes inner's interval
-    events = trace_report.load_events(
-        str(run / "host.trace.json.gz")
-    )["traceEvents"]
-    self_us = trace_report.self_times(events)
-    by_name = {n: us for (_pid, n), us in self_us.items()}
-    total_us = outer.dur * 1e6
-    assert by_name["inner"] + by_name["outer"] == pytest.approx(
-        total_us, rel=0.01
-    )
-    assert by_name["outer"] == pytest.approx(
-        total_us - inner.dur * 1e6, rel=0.05, abs=50
-    )
+    events, threads, process = _read_chrome_trace(path)
+    assert process == "python host"
+    by_name = {e["name"]: e for e in events}
+    assert set(by_name) == {"inner", "outer"} and len(events) == 2
+    ev_in, ev_out = by_name["inner"], by_name["outer"]
+    # one call stack, one lane, named after the recording thread
+    assert (ev_in["pid"], ev_in["tid"]) == (ev_out["pid"], ev_out["tid"])
+    assert ev_out["tid"] == threading.get_ident()
+    assert threads[ev_out["tid"]] == threading.current_thread().name
+    assert ev_out["args"] == {"phase": "x"} and "args" not in ev_in
+    # microseconds, and the nesting survives the round trip: a reader
+    # that subtracts children from parents gets outer's self time
+    assert ev_in["dur"] == pytest.approx(inner.dur * 1e6, abs=0.01)
+    assert ev_out["dur"] == pytest.approx(outer.dur * 1e6, abs=0.01)
+    assert ev_out["ts"] <= ev_in["ts"]
+    assert ev_in["ts"] + ev_in["dur"] <= ev_out["ts"] + ev_out["dur"] + 0.01
 
 
 def test_span_tracer_thread_safety_and_capacity():
@@ -107,9 +114,9 @@ def test_record_interval_lands_on_synthetic_lane(tmp_path):
     """A backdated record() interval must never interleave with the
     recording thread's call-stack spans: a queue wait recorded at
     admission time covers the prefill/dispatch spans the scheduler
-    thread recorded DURING the wait without nesting them, which used to
-    drive trace_report self-times negative in the committed serve
-    artifact."""
+    thread recorded DURING the wait without nesting them, so on the
+    thread's own lane a nesting-aware reader would subtract them from
+    it and report negative self time."""
     tr = obs_spans.SpanTracer(capacity=64)
     t_wait0 = time.perf_counter()
     # real call-stack work on this thread during the "wait"
@@ -126,25 +133,28 @@ def test_record_interval_lands_on_synthetic_lane(tmp_path):
     assert by_span["engine.queue"].tid == "interval:engine.queue"
     assert by_span["engine.queue"].thread_name == "intervals: engine.queue"
 
-    run = tmp_path / "plugins" / "profile" / "run0"
-    run.mkdir(parents=True)
-    tr.write_chrome_trace(str(run / "host.trace.json.gz"), "host")
-    events = trace_report.load_events(
-        str(run / "host.trace.json.gz")
-    )["traceEvents"]
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # separate lanes: nothing overlaps
-        self_us = trace_report.self_times(events)
-    assert all(us >= 0 for us in self_us.values())
-    by = {n: us for (_pid, n), us in self_us.items()}
-    # the interval keeps its FULL duration (nothing nests inside it on
-    # its synthetic lane) and the call-stack spans keep theirs
-    assert by["engine.queue"] == pytest.approx(
-        by_span["engine.queue"].dur * 1e6, rel=0.01
+    path = tr.write_chrome_trace(str(tmp_path / "host.trace.json.gz"), "host")
+    events, threads, _process = _read_chrome_trace(path)
+    by = {e["name"]: e for e in events}
+    assert len(events) == 3
+    queue, prefill, dispatch = (
+        by["engine.queue"], by["engine.prefill"], by["engine.dispatch"]
     )
-    assert by["engine.prefill"] == pytest.approx(
-        by_span["engine.prefill"].dur * 1e6, rel=0.01
-    )
+    assert queue["tid"] == "interval:engine.queue"
+    assert threads[queue["tid"]] == "intervals: engine.queue"
+    assert prefill["tid"] == dispatch["tid"] == threading.get_ident()
+    # in time the interval covers both call-stack spans ...
+    assert queue["ts"] <= prefill["ts"]
+    assert dispatch["ts"] + dispatch["dur"] <= queue["ts"] + queue["dur"] + 1
+    # ... and on its own lane it overlaps none of them: nothing else
+    # shares the lane, so every event keeps its FULL duration
+    assert [e["name"] for e in events if e["tid"] == queue["tid"]] == [
+        "engine.queue"
+    ]
+    for name, ev in by.items():
+        assert ev["dur"] == pytest.approx(by_span[name].dur * 1e6, abs=0.01)
+    # the call-stack lane itself stays properly nested (here: disjoint)
+    assert prefill["ts"] + prefill["dur"] <= dispatch["ts"] + 0.01
 
 
 # -- registry ----------------------------------------------------------
@@ -224,193 +234,6 @@ def test_metrics_writer_is_registry_sink(tmp_path):
     # publish used mirror=False: no gauge echo of registry-born series
     names = [m.name for m in reg.metrics()]
     assert names == ["lat_seconds", "loss_train", "tokens_total"]
-
-
-# -- trace attribution -------------------------------------------------
-
-
-def _synthetic_events():
-    """One device lane (module > dot/fusion/copy/infeed children) and
-    one host lane. Device self times: module 35, dot.1 30, fusion.2 20,
-    copy.3 10, infeed.4 5 (total 100); host: 50."""
-    return [
-        {"ph": "M", "name": "process_name", "pid": 7,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "name": "process_name", "pid": 9,
-         "args": {"name": "python main thread"}},
-        {"ph": "X", "pid": 7, "tid": 1, "name": "module",
-         "ts": 0, "dur": 100},
-        {"ph": "X", "pid": 7, "tid": 1, "name": "dot.1",
-         "ts": 10, "dur": 30},
-        {"ph": "X", "pid": 7, "tid": 1, "name": "fusion.2",
-         "ts": 50, "dur": 20},
-        {"ph": "X", "pid": 7, "tid": 1, "name": "copy.3",
-         "ts": 70, "dur": 10},
-        {"ph": "X", "pid": 7, "tid": 1, "name": "infeed.4",
-         "ts": 80, "dur": 5},
-        {"ph": "X", "pid": 9, "tid": 2, "name": "engine.dispatch",
-         "ts": 0, "dur": 50},
-    ]
-
-
-def test_classify_op():
-    assert trace_report.classify_op("dot.12") == "mxu"
-    assert trace_report.classify_op("convolution.3") == "mxu"
-    assert trace_report.classify_op("copy-start.1") == "copy"
-    assert trace_report.classify_op("transpose.9") == "copy"
-    assert trace_report.classify_op("all-reduce.2") == "collective"
-    assert trace_report.classify_op("infeed") == "infeed"
-    assert trace_report.classify_op("exp.7") == "vector"
-    assert trace_report.classify_op("fusion.88") == "vector"
-    assert trace_report.classify_op("dot.1", device=False) == "host"
-    # the train step's optimizer scope wins over every other category —
-    # a weight-update matmul/collective counts as optimizer time; the
-    # scope literal is pinned against compute/train.py's constant so a
-    # rename in one site cannot silently kill the category
-    from tensorflowonspark_tpu.compute.train import WEIGHT_UPDATE_SCOPE
-
-    assert (
-        trace_report.classify_op(f"{WEIGHT_UPDATE_SCOPE}/fusion.3")
-        == "weight_update"
-    )
-    assert (
-        trace_report.classify_op("jit(step)/train.weight_update/all-gather.2")
-        == "weight_update"
-    )
-    assert (
-        trace_report.classify_op("train.weight_update/dot.1", device=False)
-        == "host"
-    )
-    assert trace_report.is_device_lane("/device:TPU:0")
-    assert not trace_report.is_device_lane("python main thread")
-
-
-def test_attribution_table_from_synthetic_trace():
-    events = _synthetic_events()
-    att = trace_report.attribution(
-        trace_report.self_times(events), trace_report.lane_names(events)
-    )
-    cats = att["categories"]
-    assert cats["mxu"] == {"us": 30, "pct": 30.0}
-    assert cats["vector"] == {"us": 55, "pct": 55.0}  # module + fusion
-    assert cats["copy"] == {"us": 10, "pct": 10.0}
-    assert cats["infeed"] == {"us": 5, "pct": 5.0}
-    assert cats["collective"] == {"us": 0, "pct": 0.0}
-    # host pct is of (device + host): 50 / 150
-    assert cats["host"]["us"] == 50
-    assert cats["host"]["pct"] == pytest.approx(33.33, abs=0.01)
-    assert att["device_total_us"] == 100
-    assert att["host_total_us"] == 50
-    assert att["mxu_fraction"] == 0.3
-    # no scoped optimizer ops in this trace: fraction present and zero
-    assert cats["weight_update"] == {"us": 0, "pct": 0.0}
-    assert att["weight_update_fraction"] == 0.0
-
-
-def test_attribution_weight_update_fraction():
-    """Device ops under the train.weight_update named scope land in
-    their own category and the optimizer fraction of device time is
-    reported — the number the ZeRO A/B (bench.py --zero) reads."""
-    events = [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "X", "pid": 1, "tid": 1, "name": "dot.1", "ts": 0,
-         "dur": 60},
-        {"ph": "X", "pid": 1, "tid": 1,
-         "name": "jit(step)/train.weight_update/fusion.9", "ts": 60,
-         "dur": 40},
-    ]
-    att = trace_report.attribution(
-        trace_report.self_times(events), trace_report.lane_names(events)
-    )
-    assert att["categories"]["weight_update"] == {"us": 40, "pct": 40.0}
-    assert att["weight_update_fraction"] == 0.4
-    assert att["mxu_fraction"] == 0.6
-
-
-def test_self_times_partial_overlap_clamps_and_warns():
-    """Non-nested overlap on one lane (the corrupt-trace shape) must
-    clamp at zero and warn instead of silently reporting negative
-    self time: only the portion of an event that falls INSIDE the
-    enclosing event charges it."""
-    events = [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "host"}},
-        # prefill overlaps queue and extends past its end; the old code
-        # charged queue prefill's FULL 150us: self = 100 - 150 = -50
-        {"ph": "X", "pid": 1, "tid": 1, "name": "queue",
-         "ts": 0, "dur": 100},
-        {"ph": "X", "pid": 1, "tid": 1, "name": "prefill",
-         "ts": 10, "dur": 150},
-    ]
-    with pytest.warns(RuntimeWarning, match="without nesting"):
-        self_us = trace_report.self_times(events)
-    by = {n: us for (_pid, n), us in self_us.items()}
-    assert by["queue"] == 10  # 100 minus prefill's in-queue 90us
-    assert by["prefill"] == 150
-    assert all(us >= 0 for us in self_us.values())
-
-    # strictly nested events stay warning-free and exact
-    nested = [
-        {"ph": "X", "pid": 1, "tid": 1, "name": "outer",
-         "ts": 0, "dur": 100},
-        {"ph": "X", "pid": 1, "tid": 1, "name": "inner",
-         "ts": 10, "dur": 50},
-    ]
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        clean = trace_report.self_times(nested)
-    assert {n: us for (_p, n), us in clean.items()} == {
-        "outer": 50, "inner": 50,
-    }
-
-    # interval lanes (SpanTracer.record) are NOT call stacks:
-    # concurrent requests' queue waits overlap freely, each keeps its
-    # full duration, and no malformed-trace warning fires
-    iv = [
-        {"ph": "X", "pid": 1, "tid": "interval:queue", "name": "queue",
-         "ts": 0, "dur": 100},
-        {"ph": "X", "pid": 1, "tid": "interval:queue", "name": "queue",
-         "ts": 50, "dur": 100},
-    ]
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        ivs = trace_report.self_times(iv)
-    assert ivs[(1, "queue")] == 200
-
-
-def test_build_report_and_cli(tmp_path, capsys):
-    run = tmp_path / "plugins" / "profile" / "run1"
-    run.mkdir(parents=True)
-    with gzip.open(run / "host.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": _synthetic_events()}, f)
-
-    report = trace_report.build_report(str(tmp_path), top=3)
-    lanes = report["files"][0]["lanes"]
-    dev = next(ln for ln in lanes if ln["device"])
-    assert dev["name"] == "/device:TPU:0" and dev["total_us"] == 100
-    top = dev["top_ops"][0]
-    assert top["name"] == "module" and top["category"] == "vector"
-    assert any(
-        op["name"] == "dot.1" and op["category"] == "mxu"
-        for op in dev["top_ops"]
-    )
-
-    out_json = tmp_path / "report.json"
-    rc = trace_report.main(
-        [str(tmp_path), "--top", "5", "--json", str(out_json)]
-    )
-    assert rc == 0
-    printed = capsys.readouterr().out
-    assert "/device:TPU:0" in printed
-    assert "attribution" in printed and "mxu" in printed
-    on_disk_text = out_json.read_text()
-    assert on_disk_text.endswith("\n")  # clean diffs on regeneration
-    on_disk = json.loads(on_disk_text)
-    assert on_disk["attribution"]["mxu_fraction"] == 0.3
-
-    with pytest.raises(FileNotFoundError):
-        trace_report.build_report(str(tmp_path / "empty"))
 
 
 # -- engine + HTTP wiring ---------------------------------------------
