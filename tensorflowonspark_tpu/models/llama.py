@@ -29,6 +29,10 @@ from tensorflowonspark_tpu.compute import layout
 from tensorflowonspark_tpu.models.decode_cache import init_cache  # noqa: F401
 
 from tensorflowonspark_tpu.ops.attention import dot_product_attention
+from tensorflowonspark_tpu.ops.decode_attention import (
+    cache_block_k,
+    decode_attention,
+)
 from tensorflowonspark_tpu.ops.lora import (
     LoraTensor,
     MultiLoraTensor,
@@ -36,6 +40,7 @@ from tensorflowonspark_tpu.ops.lora import (
     multi_lora_apply,
 )
 from tensorflowonspark_tpu.ops.quant import QuantTensor, quantized_dot
+from tensorflowonspark_tpu.parallel.context import use_mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -417,8 +422,12 @@ class Attention(nn.Module):
         skipped them: ``models/falcon_h1.py``) must not let them
         overwrite rows that are already right.
 
-        Decode is HBM-bandwidth-bound; plain einsum is the right shape
-        for it (flash targets the O(S^2) training pass).
+        Decode is HBM-bandwidth-bound. A padded step of one position
+        against the dense model-dtype cache on a single TPU goes to
+        ``ops/decode_attention.py``, which reads only the blocks a row
+        has written; every other caller (prefill and chunks, uniform
+        and packed rows, the rolling and int8 caches, a mesh, the CPU)
+        takes the einsum over the whole cache below.
         """
         cfg = self.cfg
         b, s = q.shape[:2]
@@ -566,6 +575,17 @@ class Attention(nn.Module):
                 (cur + jnp.arange(s, dtype=jnp.int32))[None, :], (b, s)
             )
         ci.value = cur + s
+        if padded and s == 1 and cache_block_k(cfg) is not None:
+            # One new position a row against the dense cache, on one
+            # TPU: the kernel is told each row's written length (a slot
+            # is its position here) and fetches no block past it, nor
+            # before the window. The id plane is not read: ``padded``
+            # with ids was refused in __call__.
+            out = decode_attention(
+                q[:, 0], ck.value, cv.value, positions[:, 0] + 1,
+                window=cfg.sliding_window,
+            )
+            return out[:, None]
         # Grouped einsum against the un-repeated cache: materializing a
         # jnp.repeat of (b, max_seq_len, heads, d) K/V — plus an fp32 copy
         # — per layer per step would multiply exactly the HBM traffic that
@@ -1098,7 +1118,10 @@ def _build_generate(
             logits, key, temperature, top_k, top_p, min_p
         )
 
+    # the mesh is ambient while the body is traced: under one, the
+    # cached attention of a padded step keeps the einsum GSPMD partitions
     @jax.jit
+    @use_mesh(mesh)
     def run(params, prompt, rng, lengths=None):
         positions = jnp.broadcast_to(
             jnp.arange(s, dtype=jnp.int32), (b, s)

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from tensorflowonspark_tpu.compute import layout
+from tensorflowonspark_tpu.parallel.context import use_mesh
 
 __all__ = ["speculative_generate", "speculative_accept"]
 
@@ -243,7 +244,10 @@ def _build_speculative(
             cache,
         )
 
+    # the mesh is ambient while the body is traced: under one, the
+    # draft's one-position steps keep the einsum GSPMD partitions
     @jax.jit
+    @use_mesh(mesh)
     def run(params, draft_params, prompt, rng, lengths=None):
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         # Prefill BOTH caches on the prompt. padded=True everywhere:

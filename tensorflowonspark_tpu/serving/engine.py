@@ -82,6 +82,8 @@ from tensorflowonspark_tpu.models.decode_cache import init_cache, leaf_kind
 from tensorflowonspark_tpu.obs import registry as obs_registry
 from tensorflowonspark_tpu.obs import reqtrace
 from tensorflowonspark_tpu.obs import spans as obs_spans
+from tensorflowonspark_tpu.ops import decode_attention
+from tensorflowonspark_tpu.parallel.context import use_mesh
 from tensorflowonspark_tpu.utils.failpoints import failpoint
 
 logger = logging.getLogger(__name__)
@@ -1000,12 +1002,38 @@ class ContinuousBatcher:
             "(bucket or chunk width; the excess over "
             "engine_prefill_tokens_total is padding)",
         )
+        self._m_kv_read = self.metrics.counter(
+            "engine_decode_kv_positions_read_total",
+            "cache positions the dispatched decode steps fetch, a "
+            "layer and plane: per step and slot the blocks the "
+            "decode-attention kernel reads at the slot's position "
+            "(ceil(length / block_k) x block_k, clipped to the "
+            "window), or the whole cache row where the einsum runs",
+        )
+        self._m_kv_span = self.metrics.counter(
+            "engine_decode_kv_positions_span_total",
+            "cache positions the dispatched decode steps span: "
+            "steps x slots x cache length",
+        )
         # a window in which nothing was counted reads 0, not absent
         for c in (
             self._m_live_steps, self._m_fallback_steps,
             self._m_prefill_tokens, self._m_prefill_positions,
+            self._m_kv_read, self._m_kv_span,
         ):
             c.inc(0)
+        # The granule in which a decode step reads a cache row: the
+        # kernel's block, asked as the step's trace will ask (under this
+        # engine's mesh), or the whole row where the einsum runs. And
+        # the host's copy of every slot's device position: set at
+        # admission, advanced with each dispatched step, so counting
+        # fetches no device value.
+        self._kv_len = cfg.kv_cache_len or cfg.max_seq_len
+        with use_mesh(mesh):
+            self._kv_block = (
+                decode_attention.cache_block_k(cfg) or self._kv_len
+            )
+        self._row_pos = np.zeros((self._slots,), np.int64)
         self._m_phase = self.metrics.histogram(
             "engine_request_phase_seconds",
             "scheduler phase latency (queue/prefill per request; "
@@ -2193,15 +2221,18 @@ class ContinuousBatcher:
             params, cache, tok, pos, temps, ads, kps, seeds, pens,
             counts, bias_ids, bias_vals, gates,
         ):
-            logits, updated = model.apply(
-                {"params": params, "cache": cache},
-                tok[:, None],
-                positions=pos[:, None],
-                decode=True,
-                padded=True,
-                adapter_ids=ads,
-                mutable=["cache"],
-            )
+            # the mesh is ambient while the step is traced: under one
+            # the cached attention keeps the einsum GSPMD can partition
+            with use_mesh(self._mesh):
+                logits, updated = model.apply(
+                    {"params": params, "cache": cache},
+                    tok[:, None],
+                    positions=pos[:, None],
+                    decode=True,
+                    padded=True,
+                    adapter_ids=ads,
+                    mutable=["cache"],
+                )
             # The per-step logprob costs one (slots, vocab) fp32
             # log_softmax (~1 MB at 8x32k ≈ a few µs of HBM time vs the
             # ~GB of weight reads bounding the step) and a (slots,)
@@ -2234,6 +2265,28 @@ class ContinuousBatcher:
             return constrain(updated["cache"]), nxt, nxt_pos, lp, counts
 
         return body
+
+    def _count_kv_positions(self, k: int) -> None:
+        """Count what the k steps just dispatched read of the cache
+        and what they span, and advance the host's copy of the slots'
+        positions as :meth:`_decode_body` advances the device's (every
+        slot steps, live or not, and stops at the cache's edge)."""
+        cfg = self._model.cfg
+        at = np.minimum(
+            self._row_pos[:, None] + np.arange(k), cfg.max_seq_len - 1
+        )
+        self._row_pos = np.minimum(
+            self._row_pos + k, cfg.max_seq_len - 1
+        )
+        self._m_kv_span.inc(at.size * self._kv_len)
+        self._m_kv_read.inc(
+            int(
+                decode_attention.positions_read(
+                    at + 1, self._kv_len, cfg.sliding_window,
+                    self._kv_block,
+                ).sum()
+            )
+        )
 
     def _block_compiler_options(self):
         """What the decode block asks of the TPU compiler, None
@@ -2754,6 +2807,7 @@ class ContinuousBatcher:
         # Deferred first-token fetch, same as _admit_one: the sample and
         # admit are dispatched; the host value resolves on the fetch path.
         self._live[job.row] = (job.p, [], [])
+        self._row_pos[job.row] = job.length
         self._gates_arr = None
         self.admitted += 1
         self._pending_first.append((job.row, tok_1, lp_1))
@@ -2954,6 +3008,7 @@ class ContinuousBatcher:
         # admissions batches into back-to-back dispatches instead of
         # paying two scalar round-trips each.
         self._live[row] = (p, [], [])
+        self._row_pos[row] = len(p.tokens)
         self._gates_arr = None
         self.admitted += 1
         self._pending_first.append((row, tok_1, lp_1))
@@ -3610,6 +3665,7 @@ class ContinuousBatcher:
                         )
                         if k < self._decode_block:
                             self._m_fallback_steps.inc(k)
+                        self._count_kv_positions(k)
                         self._window.append((k, packed))
                         self._progress_ts = time.monotonic()
                 # Deferred admission first tokens resolve AFTER the

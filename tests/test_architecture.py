@@ -36,7 +36,9 @@ ALLOWED = {
     "feed": {"compute", "data", "native", "obs", "utils"},
     # tokens out
     "cachetier": {"obs", "utils"},
-    "serving": {"cachetier", "compute", "models", "obs", "ops", "utils"},
+    "serving": {
+        "cachetier", "compute", "models", "obs", "ops", "parallel", "utils",
+    },
     "autotune": {"obs", "utils"},
     # the cluster, and what drives it
     "cluster": {"compute", "feed", "native", "obs", "streaming", "utils"},
@@ -87,6 +89,7 @@ UPWARD = {
     ("ops/attention.py", "parallel.context"): "dispatch_mesh, sp_specs_and_args",
     ("ops/attention.py", "compute.layout"): "activation specs of the table",
     ("ops/bn_kernels.py", "parallel.context"): "dispatch_mesh",
+    ("ops/decode_attention.py", "parallel.context"): "current_mesh",
     ("ops/bn_kernels.py", "compute.layout"): "activation specs of the table",
     ("parallel/moe.py", "compute.layout"): "expert specs of the table",
     # readers hand their records over in the feed's frame format

@@ -22,7 +22,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from tensorflowonspark_tpu.ops import attention as attn_mod
 from tensorflowonspark_tpu.ops import bn_kernels
+from tensorflowonspark_tpu.ops.decode_attention import decode_attention
 from tensorflowonspark_tpu.ops.flash_attention import flash_attention
 
 
@@ -183,16 +185,50 @@ def test_bn_stats_kernels_compile_for_v5e(one_chip, kernel, rows, channels):
     assert "tpu_custom_call" in text
 
 
-def test_engine_decode_block_compiles_for_v5e_with_its_option(one_chip):
+# (rows, heads, kv_heads, d, C, window) of the two serve cells' decode steps
+DECODE_CASES = {
+    "mistral7b_16x2560": (16, 32, 8, 128, 2560, 4096),
+    "falconh1_48x2048": (48, 20, 4, 128, 2048, None),
+}
+
+
+def _plane_copies(text: str, rows, C, kv_heads, d) -> list[str]:
+    """Instructions whose result is a whole K/V plane made by a copy or a
+    transpose: what a layout the kernel and XLA disagree on would cost,
+    84 MB a plane and step."""
+    plane = rf"= bf16\[{rows},{C},{kv_heads},{d}\]\S* (copy|transpose)\("
+    return [line.strip() for line in text.splitlines() if re.search(plane, line)]
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_decode_attention_compiles_for_v5e(one_chip, case):
+    """The kernel at both serve cells' shapes, on the cache as stored:
+    Mosaic takes the 4-D planes in the layout XLA keeps them in."""
+    rows, heads, kv_heads, d, C, window = DECODE_CASES[case]
+    plane = ((rows, C, kv_heads, d), BF16)
+    text = _compiled_text(
+        lambda q, k, v, n: decode_attention(q, k, v, n, window=window),
+        one_chip, ((rows, heads, d), BF16), plane, plane, ((rows,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+    assert not _plane_copies(text, rows, C, kv_heads, d)
+
+
+def test_engine_decode_block_compiles_for_v5e_with_its_option(
+    one_chip, monkeypatch
+):
     """The decode block of the serving engine, one layer at Mistral-7B
     widths with the benchmark's 16 x 2560 cache, compiled for the chip
     as the engine compiles it there: with the TPU compiler option it
     asks for (an option the installed compiler did not know would fail
-    here, not at a replica's start-up) and with the whole batch cache
-    aliased from input to output. Whether memory-space assignment then
-    leaves the planes in HBM shows only at the full depth (PERF.md §6,
-    PR 26); that compile takes minutes and stays with
-    ``perfbench/tools/compile_rehearsal.py``."""
+    here, not at a replica's start-up), with the whole batch cache
+    aliased from input to output, and with the decode-attention kernel
+    reading the planes where the scatter left them, no copy between.
+    Whether memory-space assignment then leaves the planes in HBM shows
+    only at the full depth (PERF.md §6, PR 26); that compile takes
+    minutes and stays with ``perfbench/tools/compile_rehearsal.py``."""
+    # the branch asks JAX for its backend, which is the CPU here
+    monkeypatch.setattr(attn_mod, "TREAT_AS_TPU", True)
     from tensorflowonspark_tpu.models.llama import Llama, LlamaConfig
     from tensorflowonspark_tpu.serving.engine import (
         _BIAS_SLOTS,
@@ -241,3 +277,6 @@ def test_engine_decode_block_compiles_for_v5e_with_its_option(one_chip):
     )
     # the cache whole (tok, pos and counts ride along, padded to tiles)
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _plane_copies(text, slots, cfg.max_seq_len, 8, 128)
