@@ -34,6 +34,11 @@ from tensorflowonspark_tpu.models.speculative import (  # noqa: F401
     speculative_accept,
     speculative_generate,
 )
+from tensorflowonspark_tpu.models.pangu_moe import (  # noqa: F401
+    PanguMoE,
+    PanguMoEConfig,
+    pangu_moe_param_shardings,
+)
 from tensorflowonspark_tpu.models.resnet import (  # noqa: F401
     ResNet,
     ResNetConfig,

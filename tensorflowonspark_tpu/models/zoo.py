@@ -270,6 +270,37 @@ def _build_falcon_h1(tiny):
     )
 
 
+def _build_pangu_moe(tiny):
+    from tensorflowonspark_tpu.models import pangu_moe as P
+
+    # full size: the defaults are openPangu-Ultra-MoE-718B's published
+    # config, every routed expert held (no one chip holds that)
+    cfg = P.PanguMoEConfig.tiny() if tiny else P.PanguMoEConfig()
+    model = P.PanguMoE(cfg)
+
+    def make_input(b):
+        rng = np.random.default_rng(0)
+        s = min(cfg.max_seq_len, 32 if tiny else 1024)
+        return {
+            "tokens": rng.integers(0, cfg.vocab_size, size=(b, s + 1)).astype(
+                np.int32
+            )
+        }
+
+    def make_loss():
+        token_loss = P.pangu_moe_loss_fn(model)
+        return lambda p, batch: token_loss(p, batch["tokens"])
+
+    return ZooEntry(
+        name="pangu_ultra_moe_718b",
+        kind="tokens",
+        model=model,
+        make_input=make_input,
+        param_shardings=P.pangu_moe_param_shardings,
+        make_loss=make_loss,
+    )
+
+
 _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "resnet18": lambda tiny, nc: _build_resnet("resnet18", tiny, nc),
     "resnet34": lambda tiny, nc: _build_resnet("resnet34", tiny, nc),
@@ -287,6 +318,7 @@ _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "mistral_7b": lambda tiny, nc: _build_llama("mistral_7b", tiny),
     "qwen2_7b": lambda tiny, nc: _build_llama("qwen2_7b", tiny),
     "falcon_h1_34b": lambda tiny, nc: _build_falcon_h1(tiny),
+    "pangu_ultra_moe_718b": lambda tiny, nc: _build_pangu_moe(tiny),
 }
 
 

@@ -34,6 +34,13 @@ def _on_tpu() -> bool:
     return TREAT_AS_TPU or jax.default_backend() == "tpu"
 
 
+def _one_head_width(q, v) -> bool:
+    """The flash kernel takes one head width, from q: latent attention's
+    prefill (query-key width 192, value width 128) keeps the einsum.
+    ``v`` None: a caller that has none to show."""
+    return v is None or v.shape[3] == q.shape[3]
+
+
 def _flash_shapes_ok(q, k, segment_ids) -> bool:
     """Shapes the Pallas flash kernel accepts (whole-array view)."""
     return (
@@ -154,13 +161,13 @@ def dot_product_attention(
             segment_ids=segment_ids, window=window,
         )
     if impl == "auto":
-        mesh = _flash_mesh(q, k, segment_ids)
+        mesh = _flash_mesh(q, k, segment_ids, v)
         if mesh is not None:
             return mesh_flash_attention(
                 q, k, v, mesh, causal=causal, scale=scale,
                 segment_ids=segment_ids, window=window,
             )
-        impl = _local_auto_impl(q, k, segment_ids)
+        impl = _local_auto_impl(q, k, segment_ids, v)
     return _jitted_attention(
         q, k, v, causal=causal, scale=scale,
         segment_ids=segment_ids, impl=impl, window=window,
@@ -196,6 +203,11 @@ def _jitted_attention(
             flash_attention,
         )
 
+        if v.shape[-1] != q.shape[-1]:
+            raise ValueError(
+                f"impl='flash' takes one head width; q has {q.shape[-1]} "
+                f"and v {v.shape[-1]} (use impl='auto' or 'xla')"
+            )
         # positional: custom_vjp functions reject keyword arguments
         return flash_attention(
             q, k, v, causal, scale, None, None, window, segment_ids
@@ -206,7 +218,7 @@ def _jitted_attention(
     )
 
 
-def _local_auto_impl(q, k, segment_ids) -> str:
+def _local_auto_impl(q, k, segment_ids, v=None) -> str:
     """``auto`` for operands known to be shard-LOCAL: on a single-device
     process trivially, or inside a shard_map body (e.g. a ulysses or
     gpipe stage), where each device holds its own block — the raw flash
@@ -223,12 +235,15 @@ def _local_auto_impl(q, k, segment_ids) -> str:
             local = False
     return (
         "flash"
-        if (_on_tpu() and local and _flash_shapes_ok(q, k, segment_ids))
+        if (
+            _on_tpu() and local and _one_head_width(q, v)
+            and _flash_shapes_ok(q, k, segment_ids)
+        )
         else "xla"
     )
 
 
-def _flash_mesh(q, k, segment_ids):
+def _flash_mesh(q, k, segment_ids, v=None):
     """The ambient mesh, iff ``auto`` should take the shard_map flash
     route: multi-device TPU, a published mesh whose only sharded axes are
     batch/head-like, and shapes the kernel accepts both globally and
@@ -246,7 +261,7 @@ def _flash_mesh(q, k, segment_ids):
     tp = mesh.shape.get("model", 1)
     if q.shape[2] % tp or k.shape[2] % tp:
         return None
-    if not _flash_shapes_ok(q, k, segment_ids):
+    if not (_one_head_width(q, v) and _flash_shapes_ok(q, k, segment_ids)):
         return None
     return mesh
 

@@ -58,15 +58,11 @@ def _default_block_k(
     fetch less past a row's length and pay more grid steps, a step's
     fixed cost showing once a block is under a megabyte; larger ones
     fetch more than they save."""
-    hp = _padded_heads(heads)
-    for cand in (512, 256, 128, 64, 32, 16, 8):
-        if (
-            C % cand == 0
-            and cand * kv_heads * d * itemsize <= 1 << 20
-            and hp * cand * kv_heads * 4 <= 1 << 20
-        ):
-            return cand
-    return None
+    # a K/V plane's block makes kv_heads columns of logits a position
+    # and query row
+    return _largest_block(
+        C, kv_heads * d * itemsize, _padded_heads(heads) * kv_heads
+    )
 
 
 def _padded_heads(heads: int) -> int:
@@ -233,12 +229,174 @@ def decode_attention(
     return out[:, :heads]
 
 
+def _largest_block(C: int, plane_bytes: int, logit_cols: int) -> int | None:
+    """The block rule: the largest power of two up to 512 that divides
+    the cache and keeps a block of a plane of ``plane_bytes`` a position,
+    and its ``logit_cols`` float32 logits a position, within 1 MiB each."""
+    for cand in (512, 256, 128, 64, 32, 16, 8):
+        if (
+            C % cand == 0
+            and cand * plane_bytes <= 1 << 20
+            and cand * logit_cols * 4 <= 1 << 20
+        ):
+            return cand
+    return None
+
+
+def latent_entry_width(rank: int, rope: int) -> int:
+    """Values a position of a latent plane is stored in: the ``rank``
+    compressed values and the ``rope`` rotary ones, then zeros up to
+    whole 128-lane tiles. The TPU's tiled layout pads the minor
+    dimension so in any case, and given a minor dimension that is no
+    multiple of a tile it makes the positions minor instead, which the
+    kernel could take only through a copy of the whole plane."""
+    return -(-(rank + rope) // NUM_LANES) * NUM_LANES
+
+
+def _latent_block_k(C: int, heads: int, width: int, itemsize: int) -> int | None:
+    """The block rule for a latent plane: one entry of ``width`` values a
+    position, ``heads`` query rows of float32 logits."""
+    return _largest_block(C, width * itemsize, _padded_heads(heads))
+
+
+def _latent_kernel(
+    len_ref, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref,
+    *, block_k: int, rank: int, scale: float,
+):
+    j = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+    _, last = live_blocks(jnp, length, None, block_k)
+    # the row's live blocks are its LAST grid steps (see latent_block)
+    blk = j - (pl.num_programs(1) - 1 - last)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(edge: bool):
+        # one fetched block is K (the whole entry) and V (its first
+        # ``rank`` values, a slice at a tile's edge)
+        c = c_ref[0]
+        s = jax.lax.dot_general(
+            q_ref[0], c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        v = c[:, :rank]
+        if edge:
+            hi = length - blk * block_k
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            s = jnp.where(col < hi, s, NEG_INF)
+            # a zero probability times whatever lies there must be zero
+            v = jnp.where(row < hi, v, jnp.zeros_like(v))
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new[:, :1])
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha[:, :1] * acc_ref[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    whole = (blk + 1) * block_k <= length
+    live = blk >= 0
+    pl.when(live & whole)(functools.partial(step, False))
+    pl.when(live & ~whole)(functools.partial(step, True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def latent_decode_attention(
+    q_lat: jax.Array,
+    q_rope: jax.Array,
+    cache: jax.Array,
+    lengths: jax.Array,
+    *,
+    scale: float,
+    block_k: int | None = None,
+) -> jax.Array:
+    """Absorbed latent attention of one query position a row over its
+    written cache.
+
+    ``cache`` (rows, C, width) as stored: per position the compressed
+    key-value ``c_kv`` (rank values), the rotary key shared by every
+    head, and zeros up to ``width`` (:func:`latent_entry_width`).
+    ``q_lat`` (rows, heads, rank) is each head's query carried through
+    its key up-projection, ``q_rope`` (rows, heads, rope) its rotary
+    part; ``lengths`` (rows,) the positions each row has written, the
+    query's own included. Scores are ``(q_lat . c_kv + q_rope . k_r) *
+    scale`` over positions below the length, softmax in float32, and the
+    result (rows, heads, rank) is ``sum p * c_kv``, to be carried through
+    the value up-projection by the caller. A block of the plane is
+    fetched once and is both K and V; nothing past a row's last live
+    block is fetched. ``scale`` is the model's (from the width of the
+    unabsorbed query, not of what is multiplied here).
+    """
+    rows, heads, rank = q_lat.shape
+    _, C, width = cache.shape
+    fill = width - rank - q_rope.shape[-1]
+    if fill < 0:
+        raise ValueError(
+            f"q_lat {rank} + q_rope {q_rope.shape[-1]} do not fit the "
+            f"cache entry's {width}"
+        )
+    block_k = block_k or _latent_block_k(C, heads, width, cache.dtype.itemsize)
+    if block_k is None or C % block_k:
+        raise ValueError(f"block_k={block_k} does not divide the cache's {C}")
+    hp = _padded_heads(heads)
+    # one query as wide as an entry: its zeros meet the entry's
+    q = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros((rows, heads, fill), q_lat.dtype)], axis=-1
+    )
+    if hp != heads:
+        q = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
+    lengths = jnp.clip(lengths.astype(jnp.int32), 1, C)
+
+    def latent_block(r, j, lens):
+        # live blocks on the row's last grid steps, as decode_attention's
+        # kv_block has them and for its reason
+        _, last = live_blocks(jnp, lens[r], None, block_k)
+        return r, jnp.maximum(j - (C // block_k - 1 - last), 0), 0
+
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_kernel, block_k=block_k, rank=rank, scale=scale,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows, C // block_k),
+            in_specs=[
+                pl.BlockSpec((1, hp, width), lambda r, j, lens: (r, 0, 0)),
+                pl.BlockSpec((1, block_k, width), latent_block),
+            ],
+            out_specs=pl.BlockSpec((1, hp, rank), lambda r, j, lens: (r, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((hp, NUM_LANES), jnp.float32),
+                pltpu.VMEM((hp, NUM_LANES), jnp.float32),
+                pltpu.VMEM((hp, rank), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, hp, rank), q_lat.dtype),
+        interpret=INTERPRET,
+        name="latent_decode_attention",
+    )(lengths, q, cache)
+    return out[:, :heads]
+
+
 def cache_block_k(cfg) -> int | None:
     """``block_k`` of the kernel that a padded one-position step against
     ``cfg``'s cache takes in this process, or None where that step keeps
     the einsum: a rolling or an int8 cache, an ambient mesh (GSPMD
     partitions the einsum and cannot partition a ``pallas_call``), no
-    TPU. ``cfg`` is a model config as ``llama.Attention`` reads it."""
+    TPU. ``cfg`` is a model config as ``llama.Attention`` reads it, or
+    one whose cache entry is a latent (it then carries ``kv_lora_rank``
+    and ``qk_rope_head_dim``: ``models/pangu_moe.py``)."""
     from tensorflowonspark_tpu.ops import attention
     from tensorflowonspark_tpu.parallel.context import current_mesh
 
@@ -250,7 +408,13 @@ def cache_block_k(cfg) -> int | None:
         or not attention._on_tpu()
     ):
         return None
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    rank = getattr(cfg, "kv_lora_rank", None)
+    if rank:
+        return _latent_block_k(
+            C, cfg.num_heads,
+            latent_entry_width(rank, cfg.qk_rope_head_dim), itemsize,
+        )
     return _default_block_k(
-        C, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-        jnp.dtype(cfg.dtype).itemsize,
+        C, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, itemsize
     )

@@ -19,6 +19,17 @@ expert × mean router probability per expert, × num_experts.
 
 The reference has no MoE/expert parallelism (SURVEY.md §2.3) — this is
 beyond-parity capability.
+
+Beside it, the dropless layer a served expert model takes
+(:func:`route`, :func:`dropless_experts`, :class:`DroplessMoE`): the
+layer is told which experts of the router's range it holds, routes every
+token over the whole range, sorts the (token, choice) pairs whose expert
+is held by expert into a buffer sized for the worst case and runs one
+grouped matmul a projection over the held banks. No token is dropped
+whatever the imbalance, no ``(T, E, C)`` mask exists, and what the
+absent experts would add is left out: the partial result goes on, as one
+chip's share of an expert-parallel deployment computes it before the
+exchange (which this layer does not make: ROADMAP M1).
 """
 
 from __future__ import annotations
@@ -148,6 +159,208 @@ class MoEMLP(nn.Module):
             "tec,ecd->td", combine.astype(cfg.dtype), ys
         )
         return out.reshape(b, s, d).astype(x.dtype)
+
+
+def route(
+    logits: jax.Array,
+    top_k: int,
+    *,
+    scoring: str = "sigmoid",
+    norm_topk_prob: bool = True,
+    scaling: float = 1.0,
+):
+    """Router logits (T, E) -> weights (T, k) float32 and experts (T, k)
+    int32, the k best by score. ``scoring`` is ``"sigmoid"`` or
+    ``"softmax"`` over all E; ``norm_topk_prob`` divides the chosen
+    scores by their sum (over all k chosen, wherever their experts
+    live); ``scaling`` multiplies what is left."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown scoring {scoring!r}")
+    weights, experts = jax.lax.top_k(scores, top_k)
+    if norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * scaling, experts.astype(jnp.int32)
+
+
+# Test hook: run the Pallas grouped matmul in the interpreter (on a CPU).
+INTERPRET = False
+# (rows, contraction, columns) a grid step of the Pallas grouped matmul
+# takes. From the chip (PERF.md §6, PR 31), 16 banks of (7680, 2048) and
+# (2048, 7680), one SwiGLU's three products, us at a decode step's 1024
+# rows (59 real) / a 1024-token prefill's 8192 (559 real): (128, 512,
+# 2048) 2,125 / 2,753; (128, 1024, 1024) 2,201 / 2,897; (64, 1024, 1024)
+# 2,152 / 3,292; (128, 512, 512) 2,717 / 3,591; (256, 1024, 512) 3,039 /
+# 3,662. ``jax.lax.ragged_dot`` there: 4,886 / 6,091.
+_GMM_TILING = (128, 512, 2048)
+
+
+def _pallas_gmm() -> bool:
+    """Whether ``grouped_matmul`` takes the Pallas kernel in this
+    process: on one TPU without an ambient mesh (GSPMD cannot partition
+    a ``pallas_call``), as ``ops.decode_attention.cache_block_k`` asks."""
+    from tensorflowonspark_tpu.ops import attention
+    from tensorflowonspark_tpu.parallel.context import current_mesh
+
+    return attention._on_tpu() and current_mesh() is None
+
+
+def grouped_matmul(xs: jax.Array, bank: jax.Array, group_sizes: jax.Array):
+    """Rows ``xs`` (M, d), sorted by group, times ``bank`` (G, d, f):
+    row ``i`` of group ``g`` meets ``bank[g]``. What comes back for rows
+    past ``sum(group_sizes)`` is unspecified: the caller masks them.
+    float32 accumulation, the result in ``xs``'s dtype.
+
+    On one TPU: the installed JAX's Pallas grouped matmul
+    (``megablox.gmm``), which visits only the row tiles that hold a
+    group's rows, so its cost follows the real rows and the banks they
+    reach, not the buffer: 87 % of the bandwidth at a decode step's
+    shape where ``jax.lax.ragged_dot`` reaches 38 %, its cost following
+    the buffer. The chip measurement kept this one.
+
+    ``jax.lax.ragged_dot`` stays only for the platforms the kernel
+    cannot run on, the CPU (tier-1) and an ambient mesh (GSPMD cannot
+    partition the Pallas call): no benchmark cell runs it, and it is
+    unmeasured there."""
+    bank = bank.astype(xs.dtype)
+    group_sizes = group_sizes.astype(jnp.int32)
+    if not _pallas_gmm():
+        return jax.lax.ragged_dot(
+            xs, bank, group_sizes, preferred_element_type=jnp.float32,
+        ).astype(xs.dtype)
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    m, (_, d, f) = xs.shape[0], bank.shape
+    tm, tk, tn = _GMM_TILING
+    fit = lambda size, tile: next(  # noqa: E731
+        (t for t in (tile, 512, 256, 128) if t <= tile and size % t == 0),
+        size,
+    )
+    pad = -m % tm  # the kernel takes whole row tiles; none is visited
+    if pad:
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+    out = megablox.gmm(
+        xs, bank, group_sizes, preferred_element_type=xs.dtype,
+        tiling=(tm, fit(d, tk), fit(f, tn)), interpret=INTERPRET,
+    )
+    return out[:m] if pad else out
+
+
+def dropless_experts(
+    x: jax.Array,
+    weights: jax.Array,
+    experts: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    first_held: int = 0,
+):
+    """The held experts' part of a routed SwiGLU layer.
+
+    ``x`` (T, d); ``weights`` / ``experts`` (T, k) from :func:`route`;
+    the banks ``(held, d, f)``, ``(held, d, f)``, ``(held, f, d)`` of
+    experts ``first_held .. first_held + held - 1``. Returns ``y`` (T, d)
+    in ``x``'s dtype, ``sum_k w_k * SwiGLU_{e_k}(x)`` over the chosen
+    experts that are held, and ``group_sizes`` (held,) int32, the pairs
+    each held expert got. The buffer has ``T * k`` rows, the worst case,
+    so nothing is ever dropped; ``group_sizes`` say how many are real.
+    """
+    t, k = experts.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        local = experts.reshape(-1) - first_held
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held)  # pairs of absent experts last
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+        xs = x[order // k]  # (T * k, d): the pair's token
+    with jax.named_scope("moe.experts"):
+        act = nn.silu(grouped_matmul(xs, w_gate, group_sizes)) * grouped_matmul(
+            xs, w_up, group_sizes
+        )
+        ys = grouped_matmul(act, w_down, group_sizes)
+    with jax.named_scope("moe.combine"):
+        # back to (token, choice) order by a gather (the inverse of the
+        # sort), then the weighted sum over a token's choices: no
+        # scatter-add. A pair whose expert is absent reads a row past the
+        # real ones and is masked, not multiplied by zero.
+        keep = is_held.reshape(t, k)
+        back = ys[jnp.argsort(order)].reshape(t, k, -1).astype(jnp.float32)
+        back = jnp.where(keep[..., None], back, 0.0)
+        y = jnp.einsum("tk,tkd->td", jnp.where(keep, weights, 0.0), back)
+    return y.astype(x.dtype), group_sizes
+
+
+class DroplessMoE(nn.Module):
+    """Routed experts without capacity, one shard of them held here, and
+    the shared experts beside them: ``y = sum_{e chosen, e held} w_e *
+    SwiGLU_e(x) + SwiGLU_shared(x)`` on x (B, S, d).
+
+    ``num_experts`` is the router's range; the layer holds experts
+    ``first_held .. first_held + held - 1`` of it. ``shared_size`` is the
+    shared experts' summed intermediate size (0: none). The router's
+    kernel and arithmetic are float32. Returns ``(y, group_sizes)``:
+    the pairs each held expert got, for whoever counts."""
+
+    num_experts: int
+    top_k: int
+    intermediate_size: int
+    shared_size: int = 0
+    first_held: int = 0
+    held: int | None = None
+    scoring: str = "sigmoid"
+    norm_topk_prob: bool = True
+    scaling: float = 1.0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        held = self.num_experts if self.held is None else self.held
+        if not 0 <= self.first_held <= self.num_experts - held:
+            raise ValueError(
+                f"experts {self.first_held}..{self.first_held + held - 1} "
+                f"are not inside the router's {self.num_experts}"
+            )
+        tokens = x.reshape(b * s, d).astype(self.dtype)
+        init = nn.initializers.normal(0.02)
+        with jax.named_scope("moe.route"):
+            router = self.param(
+                "router", init, (d, self.num_experts), jnp.float32
+            )
+            logits = jnp.dot(
+                tokens.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            weights, experts = route(
+                logits, self.top_k, scoring=self.scoring,
+                norm_topk_prob=self.norm_topk_prob, scaling=self.scaling,
+            )
+        f = self.intermediate_size
+        y, group_sizes = dropless_experts(
+            tokens, weights, experts,
+            self.param("w_gate", init, (held, d, f)),
+            self.param("w_up", init, (held, d, f)),
+            self.param("w_down", init, (held, f, d)),
+            self.first_held,
+        )
+        if self.shared_size:
+            with jax.named_scope("moe.shared"):
+                dense = lambda feats, name: nn.Dense(  # noqa: E731
+                    feats, use_bias=False, dtype=self.dtype, name=name,
+                    kernel_init=init,
+                )
+                act = nn.silu(dense(self.shared_size, "shared_gate")(tokens))
+                act = act * dense(self.shared_size, "shared_up")(tokens)
+                y = y + dense(d, "shared_down")(act)
+        return y.reshape(b, s, d).astype(x.dtype), group_sizes
 
 
 def moe_expert_bank_spec(param_name: str) -> P:

@@ -605,13 +605,18 @@ class _PrefixStore:
 
 class ContinuousBatcher:
     """Persistent B-slot decode engine over one decoder checkpoint
-    (``models.llama.Llama``, ``models.falcon_h1.FalconH1``: any module
-    with their call signature, a ``head`` method and a ``cache``
-    collection as ``models/decode_cache.py`` describes it). The engine
-    behaves by what the model's cache tree holds: a model that carries
-    recurrent state beside K/V is served through the same programs, and
-    refuses ``prefix_cache``, ``prefix_l2`` and a ``mesh`` whose
-    ``model`` extent is over 1 (see ``docs/SERVING.md``).
+    (``models.llama.Llama``, ``models.falcon_h1.FalconH1``,
+    ``models.pangu_moe.PanguMoE``: any module with their call signature,
+    a ``head`` method and a ``cache`` collection as
+    ``models/decode_cache.py`` describes it). The engine behaves by what
+    the model's cache tree holds: a model that carries recurrent state
+    beside K/V is served through the same programs, and refuses
+    ``prefix_cache``, ``prefix_l2`` and a ``mesh`` whose ``model`` extent
+    is over 1; one whose cache entry is a latent is served like K/V (one
+    entry a position) and refuses that mesh too; one that counts what it
+    routes (``moe_counts``) has the counts ride the block's packed fetch
+    and names the registry counters they feed (``counter_entries()``;
+    see ``docs/SERVING.md``).
 
     ``submit(tokens, max_new_tokens)`` blocks the calling thread until
     that request's completion is ready (server handler threads call it
@@ -689,12 +694,27 @@ class ContinuousBatcher:
         # decides what the engine may do with a row.
         self._params = params
         self._batch_cache_shapes = self._cache_shapes(self._slots)
-        cache_bytes = dict.fromkeys(("kv", "recurrent", "other"), 0)
+        cache_bytes = dict.fromkeys(("kv", "latent", "recurrent", "other"), 0)
+        # entries of one layer's ``moe_counts`` leaf (0: the model
+        # counts nothing); the layers' leaves are summed
+        self._n_routed = 0
         for path, leaf in jax.tree_util.tree_leaves_with_path(
             self._batch_cache_shapes
         ):
-            cache_bytes[leaf_kind(path)] += (
+            kind = leaf_kind(path)
+            if kind == "counter":
+                self._n_routed = leaf.shape[0]
+            cache_bytes[kind if kind in cache_bytes else "other"] += (
                 math.prod(leaf.shape) * leaf.dtype.itemsize
+            )
+        if cache_bytes["latent"] and (
+            mesh is not None and mesh.shape.get("model", 1) > 1
+        ):
+            raise ValueError(
+                "a mesh 'model' extent over 1 is unsupported with a latent "
+                "cache: one entry a position has no heads to shard, and "
+                "there is no parameter table for the latent projections "
+                "and the expert banks"
             )
         if cache_bytes["recurrent"]:
             # Recurrent state is valid at one position only: the one
@@ -1022,6 +1042,44 @@ class ContinuousBatcher:
             self._m_kv_read, self._m_kv_span,
         ):
             c.inc(0)
+        if self._n_routed:
+            # A model that counts what its expert layers route
+            # (decode_cache's ``moe_counts``): the sums since the state
+            # was made come back with every block's packed fetch, and
+            # the registry takes the difference to the last one seen.
+            # The registry's counters are the engine's; which of them an
+            # entry of the leaf feeds, and under what labels, is the
+            # model's to say (``decode_cache.moe_count_entries``).
+            counters = {c.name: c for c in (
+                self.metrics.counter(
+                    "engine_moe_assignments_total",
+                    "(token, chosen expert) pairs the dispatched decode "
+                    "steps routed: slots x top-k x expert layers a step",
+                ),
+                self.metrics.counter(
+                    "engine_moe_local_assignments_total",
+                    "those of engine_moe_assignments_total whose expert "
+                    "this model holds",
+                ),
+                self.metrics.counter(
+                    "engine_moe_expert_tokens_total",
+                    "pairs routed to each held expert, summed over layers",
+                ),
+                self.metrics.counter(
+                    "engine_moe_experts_reached_total",
+                    "held experts that got at least one pair, summed over "
+                    "expert layers and decode steps (each reads its banks)",
+                ),
+            )}
+            self._routed_entries = [
+                [(counters[name], labels) for name, labels in feeds]
+                for feeds in model.counter_entries()
+            ]
+            assert len(self._routed_entries) == self._n_routed
+            for feeds in self._routed_entries:
+                for counter, labels in feeds:
+                    counter.inc(0, **labels)
+            self._routed_seen = np.zeros((self._n_routed,), np.uint32)
         # The granule in which a decode step reads a cache row: the
         # kernel's block, asked as the step's trace will ask (under this
         # engine's mesh), or the whole row where the einsum runs. And
@@ -1087,8 +1145,9 @@ class ContinuousBatcher:
         g_cache = self.metrics.gauge(
             "engine_cache_bytes",
             "bytes of the batch cache by kind of leaf: kv (a plane of "
-            "positions a row), recurrent (one state a row), other "
-            "(segment ids, positions, write indices)",
+            "positions a row), latent (one headless entry a position), "
+            "recurrent (one state a row), other (segment ids, "
+            "positions, write indices, counters)",
         )
         for kind, n in cache_bytes.items():
             g_cache.set(n, kind=kind)
@@ -2313,7 +2372,9 @@ class ContinuousBatcher:
         :meth:`_prefill_fn` (a class-level cache would pin closed
         engines). Returns ``(cache, tok, pos, packed, counts)`` where
         ``packed`` is ONE (2, k, slots) int32 array — row 0 the sampled
-        tokens, row 1 their fp32 logprobs bitcast to int32 — so the
+        tokens, row 1 their fp32 logprobs bitcast to int32 (and, for a
+        model that counts what it routes, further rows holding the
+        summed ``moe_counts``: :meth:`_count_routed`) — so the
         host retires a whole block with a single device fetch instead
         of 2·k transfers. Packing INTO int32 (not tokens into f32) is
         deliberate: token ids bitcast to f32 land in the denormal
@@ -2354,6 +2415,20 @@ class ContinuousBatcher:
             packed = jnp.stack(
                 [toks, jax.lax.bitcast_convert_type(lps, jnp.int32)]
             )
+            routed = [
+                leaf for path, leaf
+                in jax.tree_util.tree_leaves_with_path(cache)
+                if leaf_kind(path) == "counter"
+            ]
+            if routed:
+                # the layers' routed counts ride the same fetch, as
+                # whole (k, slots) rows after the tokens and logprobs
+                routed = sum(routed)
+                rows = -(-routed.size // toks.size)
+                routed = jnp.pad(routed, (0, rows * toks.size - routed.size))
+                packed = jnp.concatenate(
+                    [packed, routed.reshape(rows, *toks.shape)]
+                )
             return cache, tok, pos, packed, counts
 
         self._block_cache[k] = block
@@ -2431,15 +2506,19 @@ class ContinuousBatcher:
             temps_b, temp_1, ads_b, ad_1, kps_b, kp_1, seeds_b, seed_1,
             pens_b, pen_1, counts_b, bids_b, bid_1, bvals_b, bval_1,
         ):
-            def scatter(leaf_b, leaf_1):
+            def scatter(path, leaf_b, leaf_1):
                 if leaf_b.ndim == 0:  # per-layer scalar write index:
                     return leaf_b  # unused on the padded decode path
+                if leaf_kind(path) == "counter":
+                    return leaf_b  # the batch's own: a row brings none
                 start = (row,) + (0,) * (leaf_b.ndim - 1)
                 return jax.lax.dynamic_update_slice(
                     leaf_b, leaf_1.astype(leaf_b.dtype), start
                 )
 
-            cache = constrain(jax.tree.map(scatter, cache_b, cache_1))
+            cache = constrain(
+                jax.tree_util.tree_map_with_path(scatter, cache_b, cache_1)
+            )
             tok = jax.lax.dynamic_update_slice(tok_b, tok_1, (row,))
             pos = jax.lax.dynamic_update_slice(pos_b, pos_1, (row,))
             temps = jax.lax.dynamic_update_slice(temps_b, temp_1, (row,))
@@ -2549,11 +2628,11 @@ class ContinuousBatcher:
         # per-request trace would stall live rows' step dispatch,
         # exactly the latency chunked prefill exists to remove). By
         # shape from the batch's tree, whatever the model: every leaf
-        # but the scalar write index has the row first.
-        return jax.tree.map(
-            lambda s: s if not s.shape else jax.ShapeDtypeStruct(
-                (1, *s.shape[1:]), s.dtype
-            ),
+        # but the scalar write index and the counters have the row first.
+        return jax.tree_util.tree_map_with_path(
+            lambda path, s: s
+            if not s.shape or leaf_kind(path) == "counter"
+            else jax.ShapeDtypeStruct((1, *s.shape[1:]), s.dtype),
             self._batch_cache_shapes,
         )
 
@@ -2824,6 +2903,8 @@ class ContinuousBatcher:
     def _empty_state(self):
         b = self._slots
         cache = init_cache(self._batch_cache_shapes)
+        if self._n_routed:
+            self._routed_seen[:] = 0  # the device's sums restart with it
         tok = jnp.zeros((b,), jnp.int32)
         # Parked rows decode at position 0 against their own slot only;
         # their K/V writes stay inside their row and are overwritten on
@@ -3083,7 +3164,22 @@ class ContinuousBatcher:
         failpoint("engine.fetch")
         host = np.asarray(jax.device_get(packed))
         self._progress_ts = time.monotonic()
+        if self._n_routed:
+            self._count_routed(host[2:].reshape(-1)[: self._n_routed])
         return host
+
+    def _count_routed(self, sums: np.ndarray) -> None:
+        """``sums``: the expert layers' ``moe_counts`` added up, as the
+        block just fetched left them. They count since the state was
+        made and wrap as int32 does; blocks are fetched in dispatch
+        order, so the difference to the last fetch is this block's, and
+        each entry's goes to the counters the model names for it."""
+        sums = sums.astype(np.uint32)
+        delta = (sums - self._routed_seen).astype(np.int64)  # mod 2**32
+        self._routed_seen = sums
+        for n, feeds in zip(delta.tolist(), self._routed_entries):
+            for counter, labels in feeds:
+                counter.inc(n, **labels)
 
     def _sweep_block(self, k: int, host: np.ndarray) -> None:
         """Host sweep of one fetched block: append tokens/logprobs,

@@ -92,9 +92,17 @@ def test_pipeline_depth_parity_seeded(tiny):
             st = eng.stats()
             assert st["pipeline_depth"] == depth
             if depth > 1:
-                # staggered admissions under a live window must have
-                # forced at least one drain
-                assert st["drain_stalls"] >= 1
+                # An admission under a live window forces a drain. Not
+                # read off the traffic above: its threads stagger by the
+                # clock, and on a busy host they oversleep until every
+                # request meets an idle engine. Here the second request
+                # is sent on an event, the first token of a stream that
+                # has a hundred steps to go.
+                first = eng.stream([5, 6], 100, eos_id=-1)
+                next(first)
+                eng.submit([1, 2], 4)
+                assert sum(1 for _ in first) == 99
+                assert eng.stats()["drain_stalls"] > st["drain_stalls"]
         finally:
             eng.close()
     assert outs[2] == outs[1]

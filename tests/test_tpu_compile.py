@@ -24,7 +24,11 @@ from jax.sharding import SingleDeviceSharding
 
 from tensorflowonspark_tpu.ops import attention as attn_mod
 from tensorflowonspark_tpu.ops import bn_kernels
-from tensorflowonspark_tpu.ops.decode_attention import decode_attention
+from tensorflowonspark_tpu.ops.decode_attention import (
+    decode_attention,
+    latent_decode_attention,
+    latent_entry_width,
+)
 from tensorflowonspark_tpu.ops.flash_attention import flash_attention
 
 
@@ -212,6 +216,30 @@ def test_decode_attention_compiles_for_v5e(one_chip, case):
     )
     assert "tpu_custom_call" in text
     assert not _plane_copies(text, rows, C, kv_heads, d)
+
+
+def _latent_copies(text: str, rows, C, width) -> list[str]:
+    plane = rf"= bf16\[{rows},{C},{width}\]\S* (copy|transpose)\("
+    return [line.strip() for line in text.splitlines() if re.search(plane, line)]
+
+
+@pytest.mark.parametrize("rows", [128, 16])
+def test_latent_decode_attention_compiles_for_v5e(one_chip, rows):
+    """The latent kernel at the expert cell's shape (128 slots of 3072
+    positions, 128 heads over a 576-value entry stored in 640), on the
+    plane as stored: the parameter keeps the row-major layout the kernel
+    reads (a 576-wide plane would be laid out with its positions minor
+    and copied whole in front of every call)."""
+    heads, rank, rope, C = 128, 512, 64, 3072
+    width = latent_entry_width(rank, rope)
+    assert width == 640
+    text = _compiled_text(
+        lambda a, b, c, n: latent_decode_attention(a, b, c, n, scale=192**-0.5),
+        one_chip, ((rows, heads, rank), BF16), ((rows, heads, rope), BF16),
+        ((rows, C, width), BF16), ((rows,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text and "latent_decode_attention" in text
+    assert not _latent_copies(text, rows, C, width)
 
 
 def test_engine_decode_block_compiles_for_v5e_with_its_option(
