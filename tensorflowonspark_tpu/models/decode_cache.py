@@ -63,6 +63,28 @@ def moe_count_entries(first_held: int, held: int) -> tuple:
     )
 
 
+def starts_sequence(module, leaf: str) -> bool:
+    """Whether a ``decode=True`` call of an attention ``module`` was
+    handed no cache, asked of its per-position ``leaf`` BEFORE the call
+    makes its variables. Such a call starts its sequence: it writes the
+    cache for the calls that follow and attends among its own positions
+    (``dot_product_attention`` over the K/V in hand); a call that was
+    handed one reads the cache. The one rule of ``llama.Attention``
+    (Falcon-H1's too) and ``pangu_moe.LatentAttention``."""
+    return not module.has_variable("cache", leaf)
+
+
+def keys_scored(width: int, cache_len: int, handed_cache: bool) -> int:
+    """The keys each query of a ``width``-position prefill call is
+    scored against, as :func:`starts_sequence` decides it in the models:
+    the call's own ``width`` where it creates its cache, every one of
+    the ``cache_len`` slots where it was handed one (a chunk, a prefix
+    resume: the einsum over the cache). What the engine counts per
+    dispatched prefill program, so that the counter follows the models'
+    rule and not a copy of it."""
+    return cache_len if handed_cache else width
+
+
 def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 

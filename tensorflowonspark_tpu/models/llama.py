@@ -26,8 +26,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
 from tensorflowonspark_tpu.compute import layout
-from tensorflowonspark_tpu.models.decode_cache import init_cache  # noqa: F401
-
+from tensorflowonspark_tpu.models.decode_cache import (
+    init_cache,  # noqa: F401
+    starts_sequence,
+)
 from tensorflowonspark_tpu.ops.attention import dot_product_attention
 from tensorflowonspark_tpu.ops.decode_attention import (
     cache_block_k,
@@ -422,15 +424,43 @@ class Attention(nn.Module):
         skipped them: ``models/falcon_h1.py``) must not let them
         overwrite rows that are already right.
 
-        Decode is HBM-bandwidth-bound. A padded step of one position
-        against the dense model-dtype cache on a single TPU goes to
-        ``ops/decode_attention.py``, which reads only the blocks a row
-        has written; every other caller (prefill and chunks, uniform
-        and packed rows, the rolling and int8 caches, a mesh, the CPU)
-        takes the einsum over the whole cache below.
+        What is read depends on whether the call was handed a cache
+        (``decode_cache.starts_sequence``, asked before the variables
+        are made), never on a config field:
+
+        - A call that creates its cache starts its sequence. The cache
+          is written as above, for the steps and chunks that follow, but
+          nothing is read from it: the keys that exist are the ``k``,
+          ``v`` in hand, and the output is ``dot_product_attention``
+          among the call's own positions, causal, under the window and
+          the ids (on one TPU at kernel shapes the flash kernel, per
+          shard under a batch / head mesh, else the plain einsum over
+          ``s`` keys). No (s, max_seq_len) logits exist. Sound because
+          slot order is sequence order there: uniform rows write at
+          ``idx`` = 0 onward, and every padded caller that creates a
+          cache passes ``positions = arange(s)`` (``generate``,
+          ``models/speculative.py``, the engine's prefill program), so
+          "slot <= the query's slot" is "causal by index", and the
+          window's position distance is the index distance (inside one
+          packed document too). Right-padding and ``valid``-dropped
+          positions lie after a row's real tokens, so no real query
+          sees them; their own outputs are don't-care. The int8 and the
+          rolling cache store what later calls read; this call attends
+          the unrounded K/V. A padded caller that creates a cache must
+          keep that order: positions other than ``arange(s)`` belong to
+          a call that continues a cache.
+        - A call that was handed a cache keeps everything below. Decode
+          is HBM-bandwidth-bound: a padded step of one position against
+          the dense model-dtype cache on a single TPU goes to
+          ``ops/decode_attention.py``, which reads only the blocks a
+          row has written; every other continuing caller (chunks,
+          prefix resumes, speculative verification, uniform and packed
+          rows, the rolling and int8 caches, a mesh, the CPU) takes the
+          einsum over the whole cache.
         """
         cfg = self.cfg
         b, s = q.shape[:2]
+        fresh = starts_sequence(self, "k")  # before the variables exist
         C = cfg.kv_cache_len or cfg.max_seq_len
         rolling = C < cfg.max_seq_len
         if rolling:
@@ -575,6 +605,14 @@ class Attention(nn.Module):
                 (cur + jnp.arange(s, dtype=jnp.int32))[None, :], (b, s)
             )
         ci.value = cur + s
+        if fresh:
+            # The cache is new: its only keys are this call's, in slot
+            # order (see the docstring), so attend among them and read
+            # nothing back.
+            return dot_product_attention(
+                q, k, v, causal=True, segment_ids=segment_ids,
+                impl=cfg.attention_impl, window=cfg.sliding_window,
+            )
         if padded and s == 1 and cache_block_k(cfg) is not None:
             # One new position a row against the dense cache, on one
             # TPU: the kernel is told each row's written length (a slot

@@ -55,7 +55,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tensorflowonspark_tpu.models.decode_cache import moe_count_entries, moe_counts
+from tensorflowonspark_tpu.models.decode_cache import (
+    moe_count_entries,
+    moe_counts,
+    starts_sequence,
+)
 from tensorflowonspark_tpu.models.llama import (
     QDense,
     RMSNorm,
@@ -210,7 +214,7 @@ class LatentAttention(nn.Module):
                 "kv_b_proj", nn.initializers.normal(0.02),
                 (rank, heads * (nope + vd)),
             ).astype(cfg.dtype)
-        fresh = not (decode and self.has_variable("cache", "latent"))
+        fresh = not decode or starts_sequence(self, "latent")
         if decode:
             C = cfg.max_seq_len
             width = latent_entry_width(rank, rot)

@@ -21,9 +21,17 @@ FIXED set of compiled programs and admission never recompiles:
   as traced per-row inputs.
 - **prefill** (compiled once per prompt-width bucket): a (1, W) padded
   prefill builds a fresh single-row cache and samples the row's first
-  token from its true last position. In chunked mode the bucket
-  prefills are replaced by ONE (1, C) **chunk** program plus a tiny
-  **sample** program, reused for every prompt length.
+  token from its true last position. The program hands the model no
+  cache, so the model knows the call starts its sequence: it writes
+  the cache and attends among the prompt's own W positions
+  (`ops.attention.dot_product_attention`: on one TPU the flash kernel
+  from W = 128 up), never against the cache's empty slots. In chunked
+  mode the bucket prefills are replaced by ONE (1, C) **chunk** program
+  plus a tiny **sample** program, reused for every prompt length; a
+  chunk is handed the job's cache and scores its queries against every
+  slot of it (the einsum). `engine_prefill_kv_positions_scored_total`
+  over `..._span_total` says which ran, by the models' own rule
+  (`decode_cache.keys_scored`).
 - **admit** (compiled once): scatters the single-row cache into slot
   ``r`` of the engine cache with `lax.dynamic_update_slice` — no
   host-side cache reads, no recompilation.
@@ -31,8 +39,7 @@ FIXED set of compiled programs and admission never recompiles:
 ``warmup()`` pre-compiles all of them before real traffic. The host
 loop owns scheduling only: admit-then-step, retire rows on EOS, budget,
 stop-sequence match, or cancellation, hand tokens to waiters. The
-device work per step is the same einsum the plain `generate` loop
-runs.
+device work per step is what the plain `generate` loop runs.
 
 **Overlapped pipeline** (``pipeline_depth``, default 2): the scheduler
 keeps up to that many k-step decode blocks IN FLIGHT at once. Block
@@ -78,7 +85,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tensorflowonspark_tpu.models.decode_cache import init_cache, leaf_kind
+from tensorflowonspark_tpu.models.decode_cache import (
+    init_cache,
+    keys_scored,
+    leaf_kind,
+)
 from tensorflowonspark_tpu.obs import registry as obs_registry
 from tensorflowonspark_tpu.obs import reqtrace
 from tensorflowonspark_tpu.obs import spans as obs_spans
@@ -1022,6 +1033,20 @@ class ContinuousBatcher:
             "(bucket or chunk width; the excess over "
             "engine_prefill_tokens_total is padding)",
         )
+        self._m_prefill_kv_scored = self.metrics.counter(
+            "engine_prefill_kv_positions_scored_total",
+            "(query, key) pairs the dispatched prefill programs score, "
+            "a layer and head: per call its width x the keys a query "
+            "of it is scored against (decode_cache.keys_scored: the "
+            "width where the call creates its cache and attends among "
+            "its own positions, the cache length where a chunk runs "
+            "the einsum over the cache it was handed)",
+        )
+        self._m_prefill_kv_span = self.metrics.counter(
+            "engine_prefill_kv_positions_span_total",
+            "(query, key) pairs the dispatched prefill programs span: "
+            "width x cache length a call",
+        )
         self._m_kv_read = self.metrics.counter(
             "engine_decode_kv_positions_read_total",
             "cache positions the dispatched decode steps fetch, a "
@@ -1039,6 +1064,7 @@ class ContinuousBatcher:
         for c in (
             self._m_live_steps, self._m_fallback_steps,
             self._m_prefill_tokens, self._m_prefill_positions,
+            self._m_prefill_kv_scored, self._m_prefill_kv_span,
             self._m_kv_read, self._m_kv_span,
         ):
             c.inc(0)
@@ -2325,6 +2351,27 @@ class ContinuousBatcher:
 
         return body
 
+    def _prefill_pairs(self, width: int, handed_cache: bool) -> dict:
+        """What one dispatched prefill program of ``width`` positions
+        scores and spans, in (query, key) pairs a layer and head: the
+        ``engine.prefill`` span's arguments, and what
+        :meth:`_count_prefill` adds to the registry."""
+        return {
+            "kv_scored": width * keys_scored(
+                width, self._kv_len, handed_cache
+            ),
+            "kv_span": width * self._kv_len,
+        }
+
+    def _count_prefill(
+        self, tokens: int, width: int, handed_cache: bool
+    ) -> None:
+        pairs = self._prefill_pairs(width, handed_cache)
+        self._m_prefill_tokens.inc(tokens)
+        self._m_prefill_positions.inc(width)
+        self._m_prefill_kv_scored.inc(pairs["kv_scored"])
+        self._m_prefill_kv_span.inc(pairs["kv_span"])
+
     def _count_kv_positions(self, k: int) -> None:
         """Count what the k steps just dispatched read of the cache
         and what they span, and advance the host's copy of the slots'
@@ -2794,9 +2841,10 @@ class ContinuousBatcher:
         toks[0, : len(piece)] = piece
         positions = np.arange(start_w, start_w + c, dtype=np.int32)[None, :]
         # new prompt tokens only: a window shifted back recomputes
-        # start_w..next_pos, which is padding like the tail's
-        self._m_prefill_tokens.inc(n_new)
-        self._m_prefill_positions.inc(c)
+        # start_w..next_pos, which is padding like the tail's. The
+        # chunk program is handed the job's cache: every query of it
+        # is scored against every slot.
+        self._count_prefill(n_new, c, handed_cache=True)
         job.cache_1, hidden = self._chunk_fn(
             self._params,
             job.cache_1,
@@ -3061,8 +3109,9 @@ class ContinuousBatcher:
         seed_1 = self._resolve_seed(p)
         bid_1, bval_1 = self._resolve_bias(p)
         ad_1 = jnp.asarray([p.adapter], jnp.int32)
-        self._m_prefill_tokens.inc(len(p.tokens))
-        self._m_prefill_positions.inc(w)
+        # the prefill program creates its single-row cache: it attends
+        # among its own w positions
+        self._count_prefill(len(p.tokens), w, handed_cache=False)
         cache_1, tok_1, pos_1, lp_1 = self._prefill_fn(w)(
             self._params,
             jnp.asarray(prompt),
@@ -3662,10 +3711,10 @@ class ContinuousBatcher:
                             pens, counts, bids, bvals,
                         ) = self._empty_state()
                     if self._prefill_chunk is None:
+                        w = self._bucket(len(item.tokens))
                         with self._phase(
-                            "prefill",
-                            width=self._bucket(len(item.tokens)),
-                            valid=len(item.tokens),
+                            "prefill", width=w, valid=len(item.tokens),
+                            **self._prefill_pairs(w, handed_cache=False),
                         ):
                             (
                                 cache, tok, pos, temps, ads, kps, seeds,
@@ -3696,6 +3745,7 @@ class ContinuousBatcher:
                     with self._phase(
                         "prefill", width=c,
                         valid=self._chunk_window(self._job)[1],
+                        **self._prefill_pairs(c, handed_cache=True),
                     ):
                         (
                             cache, tok, pos, temps, ads, kps, seeds,

@@ -308,3 +308,54 @@ def test_engine_decode_block_compiles_for_v5e_with_its_option(
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert not _plane_copies(text, slots, cfg.max_seq_len, 8, 128)
+
+
+@pytest.mark.parametrize("width", [128, 1024, 2048])
+def test_engine_prefill_compiles_for_v5e_with_the_flash_kernel(
+    one_chip, monkeypatch, width
+):
+    """The engine's prefill program, one layer at Mistral-7B widths over
+    the benchmark's 2560-position cache, compiled for the chip: it is
+    handed no cache, so its attention is the flash kernel among the
+    prompt's own positions, and no (width, 2560) score array is left in
+    the program (PERF.md §6, PR 32)."""
+    # the dispatch asks JAX for its backend and its devices: the CPU's
+    # eight here, one TPU in the cell
+    monkeypatch.setattr(attn_mod, "TREAT_AS_TPU", True)
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a: one)
+    from tensorflowonspark_tpu.models.llama import Llama, LlamaConfig
+    from tensorflowonspark_tpu.serving.engine import (
+        _BIAS_SLOTS,
+        ContinuousBatcher,
+    )
+
+    cfg = LlamaConfig(
+        vocab_size=2048, hidden_size=4096, intermediate_size=14336,
+        num_layers=1, num_heads=32, num_kv_heads=8, max_seq_len=2560,
+        sliding_window=4096, dtype=BF16, remat=False,
+    )
+    model = Llama(cfg)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(
+            lambda: model.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+            )["params"]
+        ),
+    )
+    # the program only: no scheduler thread, no state on a device
+    eng = ContinuousBatcher.__new__(ContinuousBatcher)
+    eng._model, eng._mesh, eng._prefill_cache = model, None, {}
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32, i32 = jnp.float32, jnp.int32
+    text = eng._prefill_fn(width).lower(
+        params, arr(i32, 1, width), arr(i32, 1), arr(f32, 1), arr(i32, 1),
+        arr(f32, 1, 3), arr(jnp.uint32, 1), arr(i32, 1, _BIAS_SLOTS),
+        arr(f32, 1, _BIAS_SLOTS),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.findall(rf"\[(?:\d+,)*{width},{cfg.max_seq_len}\]", text)
