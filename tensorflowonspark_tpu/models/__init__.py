@@ -44,6 +44,11 @@ from tensorflowonspark_tpu.models.resnet import (  # noqa: F401
     ResNetConfig,
     resnet_param_shardings,
 )
+from tensorflowonspark_tpu.models.solar_open2 import (  # noqa: F401
+    SolarOpen2,
+    SolarOpen2Config,
+    solar_open2_param_shardings,
+)
 from tensorflowonspark_tpu.models.unet import (  # noqa: F401
     UNet,
     UNetConfig,

@@ -9,10 +9,14 @@ it: a flax ``cache`` collection whose leaves are told apart by name.
   reads as both K and V (``models/pangu_moe.py``; the width is
   ``ops.decode_attention.latent_entry_width``). Masked by position when
   read, as K/V are, so padding and an overlap may be written.
-- Recurrent leaves (``ssm``, ``conv``): one entry a request, (rows, ...),
-  valid at exactly one position: the state after the last token the row
-  has consumed. They cannot be resumed from an earlier position, nor
-  masked after the fact: a token that must not count must not be applied.
+- Recurrent leaves (``ssm``, ``kda``, ``conv``): one entry a request,
+  (rows, ...), valid at exactly one position: the state after the last
+  token the row has consumed. They cannot be resumed from an earlier
+  position, nor masked after the fact: a token that must not count must
+  not be applied. ``ssm`` is the Mamba-2 state (rows, h, p, N) of
+  ``models/falcon_h1.py``, ``kda`` the delta-rule state (rows, h, d_k,
+  d_v) of ``models/solar_open2.py``, ``conv`` either model's last
+  ``k - 1`` inputs of a depthwise convolution, (rows, k - 1, c).
 - Counters (``moe_counts``): what the decode steps have routed, summed
   on the device since the cache was made and wrapping as int32 does. No
   row: the batch's leaf is the engine's, an admitted row brings none.
@@ -23,7 +27,9 @@ it: a flax ``cache`` collection whose leaves are told apart by name.
 
 Every leaf but the scalar write index and the counters has the row first,
 which is all the engine's admission scatter and donation need to know of
-a leaf.
+a leaf. A layer need not hold every kind: a model whose layers differ
+(``models/solar_open2.py``) has K/V in some and recurrent leaves in the
+others, and the engine asks of the tree, never of a layer.
 """
 
 from __future__ import annotations
@@ -33,7 +39,7 @@ import jax.numpy as jnp
 
 KV_LEAVES = ("k", "v", "k_scale", "v_scale")
 LATENT_LEAVES = ("latent",)
-RECURRENT_LEAVES = ("ssm", "conv")
+RECURRENT_LEAVES = ("ssm", "kda", "conv")
 COUNTER_LEAVES = ("moe_counts",)
 
 
@@ -114,7 +120,8 @@ def init_cache(shapes):
     plane, which is -1 ("never written") so a rolling cache cannot
     mistake a stale slot for a valid position 0. Keep in lockstep with
     the ``self.variable`` inits in ``llama.Attention._cached_attention``,
-    ``falcon_h1.Mixer`` and ``pangu_moe``'s ``LatentAttention`` / ``Block``.
+    ``falcon_h1.Mixer``, ``pangu_moe``'s ``LatentAttention`` / ``Block`` and
+    ``solar_open2``'s ``DeltaMixer`` / ``Block``.
     """
 
     def init(path, s):

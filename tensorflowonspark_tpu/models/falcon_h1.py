@@ -99,9 +99,12 @@ class FalconH1Config:
     dtype: jnp.dtype = jnp.bfloat16
     kv_cache_dtype: str = "model"
     # What llama.Attention reads of its config and this architecture does
-    # not vary: full causal attention, unscaled rotary positions, no bias.
+    # not vary: full causal attention, unscaled rotary positions, no
+    # bias, no output gate.
     attention_impl: str = "auto"
     attention_bias: bool = False
+    use_rope: bool = True
+    attention_output_gate: bool = False
     rope_scaling: None = None
     sliding_window: None = None
     kv_cache_len: None = None

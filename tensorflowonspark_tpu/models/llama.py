@@ -119,6 +119,16 @@ class LlamaConfig:
     # models/falcon_h1.py's published config). 1.0 multiplies nothing: a
     # Python branch, not a traced multiply by one.
     key_multiplier: float = 1.0
+    # Rotary positions on queries and keys. False: none (NoPE), the
+    # order of the keys is all the causal mask tells a query
+    # (models/solar_open2.py). A Python branch: true traces what it
+    # always traced.
+    use_rope: bool = True
+    # An elementwise gate on the attention's output before ``o_proj``:
+    # ``sigmoid(g_proj(x))`` from the layer's input, one value an output
+    # channel (models/solar_open2.py). False makes no ``g_proj`` and
+    # traces nothing.
+    attention_output_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -334,8 +344,10 @@ class QDense(nn.Module):
 
 
 class Attention(nn.Module):
-    """Grouped-query attention with rotary positions and, in
-    ``decode=True``, the static-shape KV cache. ``cfg`` is a
+    """Grouped-query attention with rotary positions (``cfg.use_rope``)
+    and, in ``decode=True``, the static-shape KV cache; where
+    ``cfg.attention_output_gate`` is set, gated by a projection of its
+    input before ``o_proj``. ``cfg`` is a
     :class:`LlamaConfig` or any config that carries the attributes read
     here (``models/falcon_h1.py`` passes its own, whose ``head_dim`` is
     not ``hidden_size / num_heads``)."""
@@ -365,8 +377,9 @@ class Attention(nn.Module):
         v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
         if cfg.key_multiplier != 1.0:
             k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        if cfg.use_rope:
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
         if decode:
             if segment_ids is not None and padded:
                 raise ValueError(
@@ -383,6 +396,14 @@ class Attention(nn.Module):
                 impl=cfg.attention_impl, window=cfg.sliding_window,
             )
         out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
+        if cfg.attention_output_gate:
+            with jax.named_scope("attn.gate"):
+                gate = dense(cfg.num_heads * cfg.head_dim, "g_proj")(
+                    x, adapter_ids
+                )
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    out.dtype
+                )
         return dense(cfg.hidden_size, "o_proj")(out, adapter_ids)
 
     def _cached_attention(

@@ -301,6 +301,37 @@ def _build_pangu_moe(tiny):
     )
 
 
+def _build_solar_open2(tiny):
+    from tensorflowonspark_tpu.models import solar_open2 as S
+
+    # full size: the defaults are Solar-Open2-250B's published config,
+    # every routed expert held (no one chip holds that)
+    cfg = S.SolarOpen2Config.tiny() if tiny else S.SolarOpen2Config()
+    model = S.SolarOpen2(cfg)
+
+    def make_input(b):
+        rng = np.random.default_rng(0)
+        s = min(cfg.max_seq_len, 32 if tiny else 1024)
+        return {
+            "tokens": rng.integers(0, cfg.vocab_size, size=(b, s + 1)).astype(
+                np.int32
+            )
+        }
+
+    def make_loss():
+        token_loss = S.solar_open2_loss_fn(model)
+        return lambda p, batch: token_loss(p, batch["tokens"])
+
+    return ZooEntry(
+        name="solar_open2_250b",
+        kind="tokens",
+        model=model,
+        make_input=make_input,
+        param_shardings=S.solar_open2_param_shardings,
+        make_loss=make_loss,
+    )
+
+
 _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "resnet18": lambda tiny, nc: _build_resnet("resnet18", tiny, nc),
     "resnet34": lambda tiny, nc: _build_resnet("resnet34", tiny, nc),
@@ -319,6 +350,7 @@ _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "qwen2_7b": lambda tiny, nc: _build_llama("qwen2_7b", tiny),
     "falcon_h1_34b": lambda tiny, nc: _build_falcon_h1(tiny),
     "pangu_ultra_moe_718b": lambda tiny, nc: _build_pangu_moe(tiny),
+    "solar_open2_250b": lambda tiny, nc: _build_solar_open2(tiny),
 }
 
 

@@ -1060,12 +1060,21 @@ class ContinuousBatcher:
             "cache positions the dispatched decode steps span: "
             "steps x slots x cache length",
         )
+        # recurrent leaves have the row first: a slot's share of them
+        self._recurrent_row_bytes = cache_bytes["recurrent"] // self._slots
+        self._m_recurrent = self.metrics.counter(
+            "engine_recurrent_state_bytes_total",
+            "bytes of the recurrent leaves (engine_cache_bytes's "
+            "recurrent kind) of the live slots, summed over the "
+            "dispatched decode steps: what the steps' state updates "
+            "read, and write again",
+        )
         # a window in which nothing was counted reads 0, not absent
         for c in (
             self._m_live_steps, self._m_fallback_steps,
             self._m_prefill_tokens, self._m_prefill_positions,
             self._m_prefill_kv_scored, self._m_prefill_kv_span,
-            self._m_kv_read, self._m_kv_span,
+            self._m_kv_read, self._m_kv_span, self._m_recurrent,
         ):
             c.inc(0)
         if self._n_routed:
@@ -3806,8 +3815,10 @@ class ContinuousBatcher:
                         )
                         self.steps += k
                         self._m_steps.inc(k)
-                        self._m_live_steps.inc(
-                            k * sum(e is not None for e in self._live)
+                        live = k * sum(e is not None for e in self._live)
+                        self._m_live_steps.inc(live)
+                        self._m_recurrent.inc(
+                            live * self._recurrent_row_bytes
                         )
                         if k < self._decode_block:
                             self._m_fallback_steps.inc(k)

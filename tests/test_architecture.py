@@ -90,6 +90,7 @@ UPWARD = {
     ("ops/attention.py", "compute.layout"): "activation specs of the table",
     ("ops/bn_kernels.py", "parallel.context"): "dispatch_mesh",
     ("ops/decode_attention.py", "parallel.context"): "current_mesh",
+    ("ops/kda.py", "parallel.context"): "current_mesh",
     ("ops/bn_kernels.py", "compute.layout"): "activation specs of the table",
     ("parallel/moe.py", "compute.layout"): "expert specs of the table",
     # readers hand their records over in the feed's frame format

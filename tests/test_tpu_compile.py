@@ -242,6 +242,31 @@ def test_latent_decode_attention_compiles_for_v5e(one_chip, rows):
     assert not _latent_copies(text, rows, C, width)
 
 
+@pytest.mark.parametrize("rows", [128, 16])
+def test_kda_step_compiles_for_v5e(one_chip, rows):
+    """The delta-rule step kernel at the Solar-Open2 cell's shape (128
+    slots, 64 heads of a 128 x 128 float32 state): the state is written
+    over itself (the program holds it once) and is neither copied nor
+    transposed in front of the call."""
+    from tensorflowonspark_tpu.ops.kda import kda_step_pallas
+
+    h, d = 64, 128
+    f32 = jnp.float32
+    shapes = (
+        ((rows, h, d, d), f32), ((rows, h, d), f32), ((rows, h, d), f32),
+        ((rows, h, d), f32), ((rows, h, d), f32), ((rows, h), f32),
+    )
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    compiled = jax.jit(kda_step_pallas, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_step" in text
+    state = rows * h * d * d * 4
+    assert compiled.memory_analysis().alias_size_in_bytes == state
+    assert not re.findall(
+        rf"= f32\[{rows},{h},{d},{d}\]\S* (copy|transpose)\(", text
+    )
+
+
 def test_engine_decode_block_compiles_for_v5e_with_its_option(
     one_chip, monkeypatch
 ):
