@@ -189,14 +189,81 @@ def route(
 
 # Test hook: run the Pallas grouped matmul in the interpreter (on a CPU).
 INTERPRET = False
-# (rows, contraction, columns) a grid step of the Pallas grouped matmul
-# takes. From the chip (PERF.md §6, PR 31), 16 banks of (7680, 2048) and
-# (2048, 7680), one SwiGLU's three products, us at a decode step's 1024
-# rows (59 real) / a 1024-token prefill's 8192 (559 real): (128, 512,
-# 2048) 2,125 / 2,753; (128, 1024, 1024) 2,201 / 2,897; (64, 1024, 1024)
-# 2,152 / 3,292; (128, 512, 512) 2,717 / 3,591; (256, 1024, 512) 3,039 /
-# 3,662. ``jax.lax.ragged_dot`` there: 4,886 / 6,091.
-_GMM_TILING = (128, 512, 2048)
+# Why ``gmm_tiling`` chooses as it does, from the chip (PERF.md §6, PR
+# 34). The kernel's grid is (column tiles, row tiles that hold a group's
+# rows, contraction tiles); a step fetches a (tk, tn) tile of a bank and
+# the (128, tk) tile of the rows. A product's time is the read of the
+# banks it reaches, and on top of it a step's fixed cost, which shows
+# under about a megabyte a tile, and the rows' tile again at every step,
+# 128 / tn of the bank's bytes. So the width of the column tile decides,
+# and at one width the contraction tile moved nothing (512 to 2048 deep:
+# within 1 %).
+# In the cells, ms a call of the kernel alone at a decode step's 1,024
+# rows (device trace; 123 real rows and 38 of 40 banks reached in
+# Solar-Open2's, 64 and 15.6 of 16 in the expert cell's; * marks the tiles
+# taken). Solar-Open2 gate or up (4096, 1280): PR 33's (128, 512, 256)
+# 0.875; (128, 512, 1280)* 0.538; (128, 4096, 256) 0.547. Down (1280,
+# 4096): (128, 256, 2048)* 0.552-0.571, as before; (128, 1280, 512) 0.555.
+# The expert cell's gate or up (7680, 2048): (128, 512, 2048)* 0.655, as
+# PR 31 chose. Down (2048, 7680): PR 31's (128, 512, 512) 0.87; (128, 512,
+# 1920)* 0.659; (128, 2048, 512) 0.736.
+# A product alone, us at a decode step's 1,024 rows / a 1024-wide
+# prefill's 8,192 (host clock over 40 calls, the group metadata's
+# operations included). Solar-Open2, 40 banks, 112 / 1,024 real rows, 36 /
+# 40 banks reached. Gate or up: (128, 512, 256) 1,006 / 1,303; (128, 512,
+# 640) 675 / 876; (128, 512, 1280)* 582 / 754; (128, 2048, 1280) 587 /
+# 757; (128, 4096, 640) 546 / 668; (128, 4096, 256) 536 / 674. Down: (128,
+# 256, 2048)* 572 / 751; (128, 256, 4096) 548 / 721; (128, 1280, 2048) 535
+# / 663; (128, 1280, 512) 533 / 691. The expert cell, 16 banks, 55 / 529
+# real rows, 14 / 16 reached. Gate or up: (128, 512, 2048)* 649 / 921;
+# (128, 256 to 1280, 2048) 647-650 / 919-923; (128, 512, 1024) 717 /
+# 1,021; (128, 512, 512) 843 / 1,198; (128, 7680, 256) 661 / 878. Down:
+# (128, 512, 512) 855 / 1,225; (128, 512, 1920)* 654 / 940; (128, 512,
+# 3840) 637 / 914; (128, 256, 7680) 625 / 894; (128, 2048, 512) 618 / 858.
+# Alone, a whole contraction read best (the rows' tile then stands still
+# and is fetched once); in the cells it did not: a rule that took the
+# contraction whole first read the expert cell's down-projection 12 %
+# slower and Solar-Open2's three products equal, so it was not kept. Judge
+# a tile by the cell's trace.
+# Rows, a product alone at its best tile: 64 read within 1.5 % of 128 at
+# the decode buffer and 2-3 % worse at the prefill's; 256 read 11-18 %
+# worse or was refused for VMEM. So 128 stays, and the buffer's size does
+# not enter: the order of the tiles was the same at both.
+# PR 31's table, one SwiGLU's three products over the expert cell's banks,
+# us at 1,024 rows (59 real) / 8,192 (559 real): (128, 512, 2048) 2,125 /
+# 2,753; (128, 1024, 1024) 2,201 / 2,897; (64, 1024, 1024) 2,152 / 3,292;
+# (128, 512, 512) 2,717 / 3,591; (256, 1024, 512) 3,039 / 3,662;
+# ``jax.lax.ragged_dot`` 4,886 / 6,091. (Under that day's rule, the first
+# of (the tile, 512, 256, 128) that divides, the down-projection of each
+# of these ran 512 columns wide.)
+# The VMEM budget: what a step's bank tile may take, both buffers of its
+# pipeline together (a quarter of the 16 MiB a kernel is given on a v5e;
+# the compiler refuses a tile of 7.5 MiB), and the widest column tile (a
+# float32 accumulator of 1 MiB at 128 rows).
+_GMM_BANK_TILE_BYTES = 4 * 2**20
+_GMM_COLUMNS = 2048
+
+
+def _widest_tile(size: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``size`` and is at most
+    ``cap``; the whole of ``size`` where none does."""
+    return next(
+        (t for t in range(cap - cap % 128, 0, -128) if size % t == 0), size
+    )
+
+
+def gmm_tiling(m: int, d: int, f: int, itemsize: int) -> tuple[int, int, int]:
+    """(rows, contraction, columns) a grid step of the Pallas grouped
+    matmul takes for ``m`` rows against banks of ``(d, f)``: the widest
+    column tile up to ``_GMM_COLUMNS``, then the deepest contraction tile
+    that keeps the bank tile inside ``_GMM_BANK_TILE_BYTES``. A tile is
+    the largest multiple of 128 that divides its dimension, or the
+    dimension whole where none does. A pure function of the shapes
+    (``m`` does not enter: the comment above says why)."""
+    del m
+    tn = _widest_tile(f, _GMM_COLUMNS)
+    tk = _widest_tile(d, _GMM_BANK_TILE_BYTES // (2 * tn * itemsize))
+    return 128, tk, tn
 
 
 def _pallas_gmm() -> bool:
@@ -218,9 +285,10 @@ def grouped_matmul(xs: jax.Array, bank: jax.Array, group_sizes: jax.Array):
     On one TPU: the installed JAX's Pallas grouped matmul
     (``megablox.gmm``), which visits only the row tiles that hold a
     group's rows, so its cost follows the real rows and the banks they
-    reach, not the buffer: 87 % of the bandwidth at a decode step's
-    shape where ``jax.lax.ragged_dot`` reaches 38 %, its cost following
-    the buffer. The chip measurement kept this one.
+    reach, where ``jax.lax.ragged_dot``'s follows the buffer (PR 31's
+    table at ``gmm_tiling``). The tiles a grid step takes follow from
+    ``xs.shape``, ``bank.shape`` and the dtype alone, by
+    :func:`gmm_tiling`.
 
     ``jax.lax.ragged_dot`` stays only for the platforms the kernel
     cannot run on, the CPU (tier-1) and an ambient mesh (GSPMD cannot
@@ -235,17 +303,13 @@ def grouped_matmul(xs: jax.Array, bank: jax.Array, group_sizes: jax.Array):
     from jax.experimental.pallas.ops.tpu import megablox
 
     m, (_, d, f) = xs.shape[0], bank.shape
-    tm, tk, tn = _GMM_TILING
-    fit = lambda size, tile: next(  # noqa: E731
-        (t for t in (tile, 512, 256, 128) if t <= tile and size % t == 0),
-        size,
-    )
+    tm, tk, tn = gmm_tiling(m, d, f, xs.dtype.itemsize)
     pad = -m % tm  # the kernel takes whole row tiles; none is visited
     if pad:
         xs = jnp.pad(xs, ((0, pad), (0, 0)))
     out = megablox.gmm(
         xs, bank, group_sizes, preferred_element_type=xs.dtype,
-        tiling=(tm, fit(d, tk), fit(f, tn)), interpret=INTERPRET,
+        tiling=(tm, tk, tn), interpret=INTERPRET,
     )
     return out[:m] if pad else out
 

@@ -193,16 +193,46 @@ def test_route_refuses_an_unknown_scoring_and_a_shard_outside_the_range(uncut):
         _layer(first_held=14, held=4).apply({"params": _shard(params, 12, 4)}, x)
 
 
-def test_the_pallas_grouped_matmul_equals_ragged_dot(monkeypatch):
+@pytest.mark.parametrize("rows", [1024, 8192])  # a decode step's, a prefill's
+@pytest.mark.parametrize("d,f,want", [
+    (4096, 1280, (512, 1280)),  # Solar-Open2 gate, up: the bank's whole width
+    (1280, 4096, (256, 2048)),  # Solar-Open2 down
+    (7680, 2048, (512, 2048)),  # the expert cell's gate, up: as PR 31 measured
+    (2048, 7680, (512, 1920)),  # the expert cell's down: no power of two
+])
+def test_gmm_tiling_takes_the_widest_tiles_that_divide(rows, d, f, want):
+    from tensorflowonspark_tpu.parallel import moe
+
+    tm, tk, tn = moe.gmm_tiling(rows, d, f, 2)
+    assert (tm, tk, tn) == (128, *want)
+    for tile, size in ((tk, d), (tn, f)):
+        assert tile % 128 == 0 and size % tile == 0
+    # the budget the rule states: the bank tile's two pipeline buffers
+    # and the float32 accumulator
+    assert 2 * tk * tn * 2 <= moe._GMM_BANK_TILE_BYTES
+    assert tm * tn * 4 <= 128 * moe._GMM_COLUMNS * 4
+    # wider elements get a smaller tile; a dimension that no multiple of
+    # 128 divides is taken whole, as before
+    _, tk4, tn4 = moe.gmm_tiling(rows, d, f, 4)
+    assert 2 * tk4 * tn4 * 4 <= moe._GMM_BANK_TILE_BYTES
+    assert moe.gmm_tiling(16, 32, 48, 4) == (128, 32, 48)
+
+
+@pytest.mark.parametrize("d,f,tiles", [
+    (128, 256, (128, 128, 256)),
+    (256, 1280, (128, 256, 1280)),  # a column tile that is no power of two
+])
+def test_the_pallas_grouped_matmul_equals_ragged_dot(monkeypatch, d, f, tiles):
     """On a TPU ``grouped_matmul`` takes the installed Pallas kernel
     (here in the interpreter); its rows of real groups are ragged_dot's,
     whatever lies past them."""
     from tensorflowonspark_tpu.ops import attention as attn_mod
     from tensorflowonspark_tpu.parallel import moe
 
+    assert moe.gmm_tiling(256, d, f, 4) == tiles
     key = jax.random.split(jax.random.PRNGKey(7), 2)
-    xs = jax.random.normal(key[0], (256, 128))
-    bank = jax.random.normal(key[1], (4, 128, 256)) * 0.1
+    xs = jax.random.normal(key[0], (256, d))
+    bank = jax.random.normal(key[1], (4, d, f)) * 0.1
     sizes = jnp.asarray([3, 0, 130, 40], jnp.int32)
     want = moe.grouped_matmul(xs, bank, sizes)
     monkeypatch.setattr(attn_mod, "TREAT_AS_TPU", True)
@@ -215,7 +245,7 @@ def test_the_pallas_grouped_matmul_equals_ragged_dot(monkeypatch):
     # rows that are no whole tile of the kernel's (a batch of two, top-8)
     few = jnp.asarray([5, 0, 9, 2], jnp.int32)
     got = moe.grouped_matmul(xs[:16], bank, few)
-    assert got.shape == (16, 256)
+    assert got.shape == (16, f)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(jax.lax.ragged_dot(xs[:16], bank, few)),
         atol=2e-4,

@@ -267,6 +267,30 @@ def test_kda_step_compiles_for_v5e(one_chip, rows):
     )
 
 
+@pytest.mark.parametrize("rows", [1024, 8192])  # a decode step's, a prefill's
+@pytest.mark.parametrize("banks,d,f", [
+    (40, 4096, 1280), (40, 1280, 4096),  # Solar-Open2: gate / up, down
+    (16, 7680, 2048), (16, 2048, 7680),  # the expert cell's
+])
+def test_grouped_matmul_compiles_for_v5e_at_the_tiles_it_chooses(
+    one_chip, monkeypatch, rows, banks, d, f
+):
+    """``gmm_tiling``'s choice for the two expert cells' banks fits the
+    fast memory the chip's compiler gives the kernel (it refuses a bank
+    tile of 10 MiB, a whole 1280 x 4096 bank, and one of 7.5 MiB)."""
+    from tensorflowonspark_tpu.parallel import moe
+
+    monkeypatch.setattr(attn_mod, "TREAT_AS_TPU", True)
+    assert moe._pallas_gmm()
+    text = _compiled_text(
+        moe.grouped_matmul, one_chip,
+        ((rows, d), BF16), ((banks, d, f), BF16), ((banks,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text and re.search(
+        rf"%gmm\S* = bf16\[{rows},{f}\]", text
+    )
+
+
 def test_engine_decode_block_compiles_for_v5e_with_its_option(
     one_chip, monkeypatch
 ):
