@@ -909,11 +909,13 @@ class ContinuousBatcher:
                 f"pipeline_depth must be >= 1, got {pipeline_depth}"
             )
         # Overlapped pipeline: up to pipeline_depth dispatched-but-not-
-        # fetched decode blocks. Each window entry is (k, packed) — the
-        # block length and its device-resident (2, k, slots) result.
+        # fetched decode blocks. Each window entry is (k, packed, rows)
+        # — the block length, its device-resident (2, k, slots) result
+        # and how many rows were live when it was dispatched (what the
+        # sweep does not append of k x rows was computed and discarded).
         # Scheduler-thread-only, like _live.
         self._pipeline_depth = int(pipeline_depth)
-        self._window: "collections.deque[tuple[int, object]]" = (
+        self._window: "collections.deque[tuple[int, object, int]]" = (
             collections.deque()
         )
         # Async admissions whose first token is still device-resident:
@@ -1069,12 +1071,64 @@ class ContinuousBatcher:
             "dispatched decode steps: what the steps' state updates "
             "read, and write again",
         )
+        self._m_discarded = self.metrics.counter(
+            "engine_slot_steps_discarded_total",
+            "of engine_slot_steps_live_total, the slot-steps whose "
+            "token no request got: computed past a row's end inside a "
+            "block in flight, or in a block dropped unfetched. Once "
+            "idle, without failures or cancels: live = (tokens emitted "
+            "- requests completed) + discarded, exactly",
+        )
+        # The completion clock: the scheduler awaits every block's
+        # result and every admission's first token, programs run on the
+        # chip in the order they were launched, so the moments those
+        # waits return are a clock of the device's completions on the
+        # host's perf_counter (the spans' clock). Each interval between
+        # two of them goes to the program whose completion ends it.
+        self._m_device_decode_s = self.metrics.counter(
+            "engine_device_decode_seconds_total",
+            "completion-clock seconds that ended with a decode block's "
+            "fetch (the block, and whatever ran before it since the "
+            "last awaited completion: the admit program's scatter, the "
+            "intermediate chunks of a chunked prefill, which nobody "
+            "awaits, blocks dropped unfetched)",
+        )
+        self._m_device_prefill_s = self.metrics.counter(
+            "engine_device_prefill_seconds_total",
+            "completion-clock seconds that ended with an admission's "
+            "first token on the host (its prefill program, and what "
+            "ran before it since the last awaited completion)",
+        )
+        self._m_device_starved_s = self.metrics.counter(
+            "engine_device_starved_seconds_total",
+            "completion-clock seconds carved off the front of an "
+            "interval: at the completion before it nothing was in "
+            "flight (no block in the window, no first token pending), "
+            "until the next launch call had returned. The chip had "
+            "nothing to run. An intermediate chunk of a chunked "
+            "prefill is awaited by nobody and is not seen in flight",
+        )
+        self._m_gap = self.metrics.histogram(
+            "engine_completion_gap_seconds",
+            "the completion clock's intervals, whole (decode or "
+            "prefill and the starved part together): the time between "
+            "two awaited device results while the engine holds work",
+            buckets=obs_registry.DEFAULT_BUCKETS + (30.0,),
+        )
+        # the previous completion (None: the engine held nothing since;
+        # the clock starts again at the next launch), and from when in
+        # the current interval the chip has had something to run (None:
+        # nothing yet)
+        self._clock_at: float | None = None
+        self._clock_fed_at: float | None = None
         # a window in which nothing was counted reads 0, not absent
         for c in (
             self._m_live_steps, self._m_fallback_steps,
             self._m_prefill_tokens, self._m_prefill_positions,
             self._m_prefill_kv_scored, self._m_prefill_kv_span,
             self._m_kv_read, self._m_kv_span, self._m_recurrent,
+            self._m_discarded, self._m_device_decode_s,
+            self._m_device_prefill_s, self._m_device_starved_s,
         ):
             c.inc(0)
         if self._n_routed:
@@ -1129,10 +1183,13 @@ class ContinuousBatcher:
         self._row_pos = np.zeros((self._slots,), np.int64)
         self._m_phase = self.metrics.histogram(
             "engine_request_phase_seconds",
-            "scheduler phase latency (queue/prefill per request; "
-            "dispatch/fetch/sweep per k-step decode block shared by "
-            "all live slots; drain per forced drain, its fetches and "
-            "sweeps nested inside)",
+            "scheduler phase latency (queue/prefill per request, "
+            "prefill_stage/prefill_launch nested inside a prefill: "
+            "the host arrays made device arrays, then the program "
+            "calls until they return; first_token per pass that "
+            "awaits admissions' first tokens; dispatch/fetch/sweep "
+            "per k-step decode block shared by all live slots; drain "
+            "per forced drain, its fetches and sweeps nested inside)",
         )
         self._m_warmup = self.metrics.histogram(
             "engine_warmup_seconds",
@@ -2093,6 +2150,41 @@ class ContinuousBatcher:
             self._current_phase = outer
         self._m_phase.observe(span.dur, phase=phase)
 
+    def _clock_launched(self) -> None:
+        """A launch call (a decode block, a prefill and its admit, a
+        chunk) has returned: from here the chip has something to run.
+        Starts the completion clock where the engine had held nothing,
+        and ends the starved front of an interval that began with
+        nothing in flight."""
+        if self._clock_fed_at is None:
+            self._clock_fed_at = time.perf_counter()
+            if self._clock_at is None:
+                self._clock_at = self._clock_fed_at
+
+    def _clock_completed(self, ended_by, in_flight: bool) -> None:
+        """An awaited result is on the host: the interval since the
+        last such moment goes to ``ended_by`` (the seconds counter of
+        the program that completed), less its starved front.
+        ``in_flight``: whether the chip still has launched work, a
+        block in the window or a first token pending."""
+        now = time.perf_counter()
+        if self._clock_at is not None:
+            gap = now - self._clock_at
+            starved = (self._clock_fed_at or self._clock_at) - self._clock_at
+            self._m_device_starved_s.inc(starved)
+            ended_by.inc(gap - starved)
+            self._m_gap.observe(gap)
+        self._clock_at = now
+        self._clock_fed_at = now if in_flight else None
+
+    def _drop_window(self) -> None:
+        """Drop the in-flight blocks unfetched (every row they decode
+        for has gone): all their slot-steps are discards. They end no
+        interval of the completion clock; their time on the chip falls
+        to the next completion."""
+        self._m_discarded.inc(sum(k * rows for k, _, rows in self._window))
+        self._window.clear()
+
     def _observe_queue_wait(self, p: _Pending) -> None:
         now = time.monotonic()
         dur = now - p.submitted_at
@@ -2845,24 +2937,26 @@ class ContinuousBatcher:
             )
         c = self._prefill_chunk
         start_w, n_new = self._chunk_window(job)
-        toks = np.zeros((1, c), np.int32)
-        piece = job.p.tokens[start_w : start_w + c]
-        toks[0, : len(piece)] = piece
-        positions = np.arange(start_w, start_w + c, dtype=np.int32)[None, :]
+        with self._phase("prefill_stage"):
+            toks = np.zeros((1, c), np.int32)
+            piece = job.p.tokens[start_w : start_w + c]
+            toks[0, : len(piece)] = piece
+            positions = np.arange(
+                start_w, start_w + c, dtype=np.int32
+            )[None, :]
+            toks_1, positions_1 = jnp.asarray(toks), jnp.asarray(positions)
+            lo_1, hi_1 = jnp.int32(job.next_pos), jnp.int32(job.length)
         # new prompt tokens only: a window shifted back recomputes
         # start_w..next_pos, which is padding like the tail's. The
         # chunk program is handed the job's cache: every query of it
         # is scored against every slot.
         self._count_prefill(n_new, c, handed_cache=True)
-        job.cache_1, hidden = self._chunk_fn(
-            self._params,
-            job.cache_1,
-            jnp.asarray(toks),
-            jnp.asarray(positions),
-            job.ad_1,
-            jnp.int32(job.next_pos),
-            jnp.int32(job.length),
-        )
+        with self._phase("prefill_launch"):
+            job.cache_1, hidden = self._chunk_fn(
+                self._params, job.cache_1, toks_1, positions_1,
+                job.ad_1, lo_1, hi_1,
+            )
+            self._clock_launched()
         job.next_pos = start_w + c
         if job.next_pos < job.length:
             if (
@@ -2903,43 +2997,44 @@ class ContinuousBatcher:
             )
             self._l2_offer(job.p.tokens, job.cache_1, job.p.adapter)
         # final chunk: it contains the prompt's last true position
-        tok_1, lp_1 = self._sample1_fn(
-            self._params,
-            hidden,
-            jnp.int32(job.length - 1 - start_w),
-            job.temp_1,
-            job.kp_1,
-            job.seed_1,
-            jnp.asarray([job.length], jnp.int32),
-            *job.bias_1,
-        )
-        (
-            cache, tok, pos, temps, ads, kps, seeds, pens, counts,
-            bids, bvals,
-        ) = self._admit_fn(
-            cache,
-            job.cache_1,
-            jnp.int32(job.row),
-            tok,
-            tok_1,
-            pos,
-            jnp.asarray([job.length], jnp.int32),
-            temps,
-            job.temp_1,
-            ads,
-            job.ad_1,
-            kps,
-            job.kp_1,
-            seeds,
-            job.seed_1,
-            pens,
-            job.pen_1,
-            counts,
-            bids,
-            job.bias_1[0],
-            bvals,
-            job.bias_1[1],
-        )
+        with self._phase("prefill_launch"):
+            tok_1, lp_1 = self._sample1_fn(
+                self._params,
+                hidden,
+                jnp.int32(job.length - 1 - start_w),
+                job.temp_1,
+                job.kp_1,
+                job.seed_1,
+                jnp.asarray([job.length], jnp.int32),
+                *job.bias_1,
+            )
+            (
+                cache, tok, pos, temps, ads, kps, seeds, pens, counts,
+                bids, bvals,
+            ) = self._admit_fn(
+                cache,
+                job.cache_1,
+                jnp.int32(job.row),
+                tok,
+                tok_1,
+                pos,
+                jnp.asarray([job.length], jnp.int32),
+                temps,
+                job.temp_1,
+                ads,
+                job.ad_1,
+                kps,
+                job.kp_1,
+                seeds,
+                job.seed_1,
+                pens,
+                job.pen_1,
+                counts,
+                bids,
+                job.bias_1[0],
+                bvals,
+                job.bias_1[1],
+            )
         # Deferred first-token fetch, same as _admit_one: the sample and
         # admit are dispatched; the host value resolves on the fetch path.
         self._live[job.row] = (job.p, [], [])
@@ -3106,41 +3201,50 @@ class ContinuousBatcher:
         seeds, pens, counts, bids, bvals,
     ):
         w = self._bucket(len(p.tokens))
-        prompt = np.zeros((1, w), np.int32)
-        prompt[0, : len(p.tokens)] = p.tokens
-        temp = (
-            self._temperature
-            if p.temperature is None
-            else float(p.temperature)
-        )
-        temp_1 = jnp.asarray([temp], jnp.float32)
-        kp_1 = self._resolve_kp(p)
-        seed_1 = self._resolve_seed(p)
-        bid_1, bval_1 = self._resolve_bias(p)
-        ad_1 = jnp.asarray([p.adapter], jnp.int32)
+        # Two child phases say what the chip waits for inside an
+        # admission: the host arrays made device arrays, then the two
+        # program calls until they return (the admit call's own two
+        # small arrays are made between them, as before).
+        with self._phase("prefill_stage"):
+            prompt = np.zeros((1, w), np.int32)
+            prompt[0, : len(p.tokens)] = p.tokens
+            temp = (
+                self._temperature
+                if p.temperature is None
+                else float(p.temperature)
+            )
+            temp_1 = jnp.asarray([temp], jnp.float32)
+            kp_1 = self._resolve_kp(p)
+            seed_1 = self._resolve_seed(p)
+            bid_1, bval_1 = self._resolve_bias(p)
+            ad_1 = jnp.asarray([p.adapter], jnp.int32)
+            prompt_1 = jnp.asarray(prompt)
+            len_1 = jnp.asarray([len(p.tokens)], jnp.int32)
         # the prefill program creates its single-row cache: it attends
         # among its own w positions
         self._count_prefill(len(p.tokens), w, handed_cache=False)
-        cache_1, tok_1, pos_1, lp_1 = self._prefill_fn(w)(
-            self._params,
-            jnp.asarray(prompt),
-            jnp.asarray([len(p.tokens)], jnp.int32),
-            temp_1,
-            ad_1,
-            kp_1,
-            seed_1,
-            bid_1,
-            bval_1,
-        )
-        (
-            cache, tok, pos, temps, ads, kps, seeds, pens, counts,
-            bids, bvals,
-        ) = self._admit_fn(
-            cache, cache_1, jnp.int32(row), tok, tok_1, pos, pos_1,
-            temps, temp_1, ads, ad_1, kps, kp_1, seeds, seed_1,
-            pens, self._resolve_pen(p), counts, bids, bid_1, bvals,
-            bval_1,
-        )
+        with self._phase("prefill_launch"):
+            cache_1, tok_1, pos_1, lp_1 = self._prefill_fn(w)(
+                self._params,
+                prompt_1,
+                len_1,
+                temp_1,
+                ad_1,
+                kp_1,
+                seed_1,
+                bid_1,
+                bval_1,
+            )
+            (
+                cache, tok, pos, temps, ads, kps, seeds, pens, counts,
+                bids, bvals,
+            ) = self._admit_fn(
+                cache, cache_1, jnp.int32(row), tok, tok_1, pos, pos_1,
+                temps, temp_1, ads, ad_1, kps, kp_1, seeds, seed_1,
+                pens, self._resolve_pen(p), counts, bids, bid_1, bvals,
+                bval_1,
+            )
+            self._clock_launched()
         # Async admission: prefill + admit are DISPATCHED (jax enqueues
         # without a device sync); the first token's fetch is deferred to
         # _resolve_first_tokens on the normal fetch path, so a burst of
@@ -3186,20 +3290,28 @@ class ContinuousBatcher:
         cover."""
         if not self._pending_first:
             return
-        for row, tok_1, lp_1 in self._pending_first:
-            p, out, lps = self._live[row]
-            if p.resolved:  # failed (watchdog/deadline) before token 0
-                self._live[row] = None
-                self._gates_arr = None
-                continue
-            first = int(np.asarray(tok_1)[0])
-            lp = float(np.asarray(lp_1)[0])
-            out.append(first)
-            lps.append(lp)
-            self._emit(p, first, lp)
-            if self._finished(p, out, first):
-                self._retire(row)
-        self._pending_first.clear()
+        n = len(self._pending_first)
+        # the scheduler's wait for the prefill programs: each row's
+        # fetch returns when its prefill has run, a completion
+        with self._phase("first_token", rows=n):
+            for i, (row, tok_1, lp_1) in enumerate(self._pending_first):
+                p, out, lps = self._live[row]
+                if p.resolved:  # failed (watchdog/deadline) before token 0
+                    self._live[row] = None
+                    self._gates_arr = None
+                    continue
+                first = int(np.asarray(tok_1)[0])
+                lp = float(np.asarray(lp_1)[0])
+                self._clock_completed(
+                    self._m_device_prefill_s,
+                    in_flight=bool(self._window) or i + 1 < n,
+                )
+                out.append(first)
+                lps.append(lp)
+                self._emit(p, first, lp)
+                if self._finished(p, out, first):
+                    self._retire(row)
+            self._pending_first.clear()
 
     @staticmethod
     def _block_ready(packed) -> bool:
@@ -3221,6 +3333,10 @@ class ContinuousBatcher:
         # the exact stall the scheduler watchdog exists to detect
         failpoint("engine.fetch")
         host = np.asarray(jax.device_get(packed))
+        self._clock_completed(
+            self._m_device_decode_s,
+            in_flight=bool(self._window or self._pending_first),
+        )
         self._progress_ts = time.monotonic()
         if self._n_routed:
             self._count_routed(host[2:].reshape(-1)[: self._n_routed])
@@ -3239,15 +3355,18 @@ class ContinuousBatcher:
             for counter, labels in feeds:
                 counter.inc(n, **labels)
 
-    def _sweep_block(self, k: int, host: np.ndarray) -> None:
+    def _sweep_block(self, k: int, host: np.ndarray, rows: int) -> None:
         """Host sweep of one fetched block: append tokens/logprobs,
         emit to streams, retire finished rows. Time spent here while
         another block is still in flight is overlap the pipeline hid —
         tracked in overlap_hidden (the serial loop paid it on the
-        critical path)."""
+        critical path). ``rows`` were live when the block was
+        dispatched: what of its ``k * rows`` slot-steps appends no
+        token here was computed for a row that had ended, a discard."""
         host_tok = host[0]
         host_lp = host[1].view(np.float32)
         t0 = time.monotonic()
+        appended = 0
         with self._phase("sweep"):
             for j in range(k):
                 for row, entry in enumerate(self._live):
@@ -3263,6 +3382,7 @@ class ContinuousBatcher:
                         continue
                     t = int(host_tok[j, row])
                     out.append(t)
+                    appended += 1
                     lps.append(float(host_lp[j, row]))
                     self._emit(p, t, lps[-1])
                     if self._finished(p, out, t):
@@ -3280,6 +3400,7 @@ class ContinuousBatcher:
                         tokens=k,
                     )
                     p.trace_mark = now
+        self._m_discarded.inc(k * rows - appended)
         if self._window:
             dur = time.monotonic() - t0
             self._overlap_hidden_s += dur
@@ -3307,16 +3428,16 @@ class ContinuousBatcher:
         if all(e is None for e in self._live):
             # every row already retired: the in-flight blocks hold only
             # discards — drop the references without fetching
-            self._window.clear()
+            self._drop_window()
             return
         self._drain_stalls += 1
         self._m_drains.inc(reason=reason)
         with self._phase("drain", reason=reason):
             while self._window:
-                k0, packed = self._window.popleft()
+                k0, packed, rows = self._window.popleft()
                 with self._phase("fetch"):
                     host = self._fetch_packed(packed)
-                self._sweep_block(k0, host)
+                self._sweep_block(k0, host, rows)
 
     def _finished(self, p: _Pending, out: list[int], last: int) -> bool:
         if p.cancelled:
@@ -3543,7 +3664,7 @@ class ContinuousBatcher:
         device blocks unfetched (their rows' requests already failed),
         free every slot whose request the watchdog resolved, and keep
         going."""
-        self._window.clear()
+        self._drop_window()
         self._pending_first.clear()
         for row, entry in enumerate(self._live):
             if entry is not None and entry[0].resolved:
@@ -3616,7 +3737,7 @@ class ContinuousBatcher:
                     # abrupt shutdown: in-flight device work and
                     # unresolved first tokens are dropped unfetched —
                     # every owning request fails below anyway
-                    self._window.clear()
+                    self._drop_window()
                     self._pending_first.clear()
                     if self._job is not None:
                         self._fail_one(self._job.p, err)
@@ -3645,7 +3766,7 @@ class ContinuousBatcher:
                     # every row retired mid-window: the remaining
                     # in-flight blocks hold only discards — drop them
                     # without fetching (nothing to sweep)
-                    self._window.clear()
+                    self._drop_window()
                 idle = (
                     all(e is None for e in self._live)
                     and self._job is None
@@ -3673,11 +3794,13 @@ class ContinuousBatcher:
                     ):
                         break  # one chunked prefill at a time
                     try:
-                        item = (
-                            self._queue.get()
-                            if idle
-                            else self._queue.get_nowait()
-                        )
+                        if idle:
+                            # the engine holds nothing: the completion
+                            # clock stands until the next launch
+                            self._clock_at = self._clock_fed_at = None
+                            item = self._queue.get()
+                        else:
+                            item = self._queue.get_nowait()
                     except queue.Empty:
                         break
                     if item is self._STOP:
@@ -3813,17 +3936,18 @@ class ContinuousBatcher:
                             kps, seeds, pens, counts, bids, bvals,
                             self._gates_dev(),
                         )
+                        self._clock_launched()
                         self.steps += k
                         self._m_steps.inc(k)
-                        live = k * sum(e is not None for e in self._live)
-                        self._m_live_steps.inc(live)
+                        rows = sum(e is not None for e in self._live)
+                        self._m_live_steps.inc(k * rows)
                         self._m_recurrent.inc(
-                            live * self._recurrent_row_bytes
+                            k * rows * self._recurrent_row_bytes
                         )
                         if k < self._decode_block:
                             self._m_fallback_steps.inc(k)
                         self._count_kv_positions(k)
-                        self._window.append((k, packed))
+                        self._window.append((k, packed, rows))
                         self._progress_ts = time.monotonic()
                 # Deferred admission first tokens resolve AFTER the
                 # dispatch above, so their device_get overlaps the
@@ -3839,12 +3963,12 @@ class ContinuousBatcher:
                     len(self._window) >= depth
                     or self._block_ready(self._window[0][1])
                 ):
-                    k0, packed = self._window.popleft()
+                    k0, packed, rows = self._window.popleft()
                     with self._phase("fetch"):
                         # ONE fetch: (2, k, slots) int32; row 1 carries
                         # the fp32 logprob bits (see _block_fn)
                         host = self._fetch_packed(packed)
-                    self._sweep_block(k0, host)
+                    self._sweep_block(k0, host, rows)
         except BaseException as e:  # noqa: BLE001 - ferry to waiters
             logger.exception("continuous-batcher loop died")
             # Refuse new submits FIRST (a dead loop never answers), then
@@ -3852,7 +3976,7 @@ class ContinuousBatcher:
             # nor the queue) and everything parked or queued.
             with self._submit_lock:
                 self._closed = True
-            self._window.clear()
+            self._drop_window()
             self._pending_first.clear()
             if self._inflight is not None:
                 self._fail_one(self._inflight, e)
