@@ -48,6 +48,11 @@ INTERPRET = False
 # a kernel may use; and 4 key-side vectors x 32 heads make one (128, 128)
 # tile to transpose.
 _STEP_HEADS = 32
+# Positions of a sub-block of the chunked form's chunk: only the sub-blocks
+# on a chunk's diagonal form the (t, s, channel) decays, the blocks below
+# them are products on the MXU. 16 from the chip at chunk 32 (PERF.md §6,
+# PR 36): 3.18 ms a 1024-wide row and layer where 8 takes 3.29.
+_SUB = 16
 
 
 def _pallas_step(S) -> bool:
@@ -170,6 +175,70 @@ def _unit_lower_inverse(L):
     return jax.lax.fori_loop(0, C, row, jnp.zeros_like(L))
 
 
+def _block_lower_inverse(L, sub):
+    """:func:`_unit_lower_inverse` by sub-blocks of ``sub`` positions:
+    the diagonal blocks by substitution (``sub`` steps for all of them at
+    once), then the block rows in order, each by products with the rows
+    above it: ``T_i,<r = -T_ii L_i,<r T_<r,<r``."""
+    C = L.shape[-1]
+    n = C // sub
+    diag = _unit_lower_inverse(jnp.stack(
+        [L[..., i * sub:(i + 1) * sub, i * sub:(i + 1) * sub] for i in range(n)],
+        axis=-3,
+    ))  # (..., n, sub, sub)
+    zeros = jnp.zeros(L.shape[:-2] + (sub, C - sub), L.dtype)
+    T = jnp.concatenate([diag[..., 0, :, :], zeros], axis=-1)  # (..., r, C)
+    for i in range(1, n):
+        r = i * sub
+        x = jnp.einsum(
+            "...ts,...sj->...tj", L[..., r:r + sub, :r], T[..., :r],
+            precision=_HI,
+        )
+        off = -jnp.einsum(
+            "...ts,...sj->...tj", diag[..., i, :, :], x, precision=_HI
+        )
+        row = [off, diag[..., i, :, :], zeros[..., : C - r - sub]]
+        T = jnp.concatenate([T, jnp.concatenate(row, axis=-1)], axis=-2)
+    return T
+
+
+def _scores(q, k, G, sub):
+    """``A`` (k with k, strictly lower) and ``B`` (q with k, lower) of
+    chunks ``(..., C, d_k)`` cut into sub-blocks of ``sub`` positions.
+
+    For an earlier ``s`` and a later ``t`` whose sub-block starts at
+    ``r``, ``exp(G_t - G_s) = exp(G_t - G_r) exp(G_r - G_s)``, both
+    factors at most 1 (each underflows only where their product does).
+    So a block row's scores against the sub-blocks before it are one
+    product of its rows scaled by ``exp(G_t - G_r)`` with every earlier
+    key scaled by ``exp(G_r - G_s)``; only the diagonal sub-blocks form
+    the (t, s, channel) decays.
+    """
+    *lead, C, dk = q.shape
+    n = C // sub
+    qb, kb, Gb = (t.reshape(*lead, n, sub, dk) for t in (q, k, G))
+    tri = jnp.tril(jnp.ones((sub, sub), bool))
+    seg = Gb[..., :, None, :] - Gb[..., None, :, :]  # (..., n, t, s, dk)
+    ks = kb[..., None, :, :] * jnp.exp(jnp.where(tri[..., None], seg, -jnp.inf))
+    A = jnp.sum(kb[..., :, None, :] * ks, axis=-1)  # (..., n, sub, sub)
+    B = jnp.sum(qb[..., :, None, :] * ks, axis=-1)
+    Gr = Gb[..., :1, :]  # (..., n, 1, dk): each sub-block's first position
+    to_r = jnp.exp(Gb - Gr)
+    before = jnp.tri(n, k=-1, dtype=bool)[..., None, None]  # sub-block j < i
+    k_r = (kb[..., None, :, :, :] * jnp.exp(jnp.where(
+        before, Gr[..., :, None, :, :] - Gb[..., None, :, :, :], -jnp.inf
+    ))).reshape(*lead, n, C, dk)  # (..., i, s, dk): s before sub-block i
+    in_diag = (jnp.arange(C) // sub == jnp.arange(n)[:, None])[:, None, :]
+
+    def whole(diag, rows):
+        off = jnp.einsum(
+            "...itk,...isk->...its", rows * to_r, k_r, precision=_HI
+        )  # (..., i, t, s)
+        return jnp.where(in_diag, jnp.tile(diag, n), off).reshape(*lead, C, C)
+
+    return whole(A, kb), whole(B, qb)
+
+
 def kda_chunked(
     q, k, v, log_alpha, beta, *, chunk: int = 32, initial_state=None,
     valid=None,
@@ -194,12 +263,13 @@ def kda_chunked(
         o_t = S_0^T (q_t * exp G_t) + sum_{s<=t} B_ts u_s     B: q_t for k_t
         S_C = Diag(exp G_C) S_0 + sum_s (k_s * exp(G_C - G_s)) u_s^T
 
-    ``A``, ``B`` and the inverse are made for every chunk at once; the
-    state then follows the chunks sequentially, three small products a
-    chunk. ``chunk`` 32 from the chip (PERF.md §6, PR 33): one row of 1024
-    positions, 64 heads of 128 x 128, ms: 5.8 at 32, 7.7 at 64, 12.2 at
-    128 (the (t, s, channel) decays grow with the chunk, the sequential
-    steps shrink with it).
+    ``A`` and ``B`` are made a chunk at a time by sub-blocks
+    (:func:`_scores`), the inverse for every chunk at once by sub-blocks;
+    the state then follows the chunks sequentially, five small products a
+    chunk, reading ``q``, ``k``, ``v`` and ``G`` as they are stored.
+    ``chunk`` 32 from the chip (PERF.md §6, PR 36): one row of 1024
+    positions, 64 heads of 128 x 128, ms: 3.2 at 32, 3.5 at 64, 4.4 at
+    128 (the form before the sub-blocks: 5.7, 7.6, 12.0).
     """
     rows, L, h, dk = q.shape
     dv = v.shape[-1]
@@ -218,36 +288,18 @@ def kda_chunked(
             for t in (q, k, v, g, beta)
         )
     nc = (L + pad) // C
-    # chunks first: what is mapped and scanned over
-    q, k, v, g = (
-        t.reshape(rows, nc, C, h, -1).transpose(1, 0, 3, 2, 4)
-        for t in (q, k, v, g)
-    )  # (nc, rows, h, C, d)
+    # (rows, nc, C, h, d) as stored, what the carry reads a chunk of
+    q, k, v, g = (t.reshape(rows, nc, C, h, -1) for t in (q, k, v, g))
+    G = jnp.cumsum(g, axis=2)
+    # chunks first, then heads: what the scores and the solve take
+    qh, kh, Gh = (t.transpose(1, 0, 3, 2, 4) for t in (q, k, G))
     beta = beta.reshape(rows, nc, C, h).transpose(1, 0, 3, 2)  # (nc, rows, h, C)
-    G = jnp.cumsum(g, axis=-2)
-    lower = jnp.tril(jnp.ones((C, C), bool))
-
-    def scores(args):
-        """A (strictly lower) and B (lower) of one chunk."""
-        qc, kc, Gc = args  # (rows, h, C, dk)
-        seg = Gc[..., :, None, :] - Gc[..., None, :, :]  # (rows, h, t, s, dk)
-        decay = jnp.exp(jnp.where(lower[..., None], seg, -jnp.inf))
-        ks = kc[..., None, :, :] * decay
-        return (
-            jnp.sum(kc[..., :, None, :] * ks, axis=-1),
-            jnp.sum(qc[..., :, None, :] * ks, axis=-1),
-        )
-
-    A, B = jax.lax.map(scores, (q, k, G))  # (nc, rows, h, C, C)
-    A = jnp.where(jnp.tril(lower, -1), A, 0.0)
-    T = _unit_lower_inverse(beta[..., None] * A)
-    from_start = jnp.exp(G)  # a position's decay since the chunk's start
-    rhs = jnp.concatenate([k * from_start, v], axis=-1) * beta[..., None]
-    WU = jnp.einsum("...ts,...sd->...td", T, rhs, precision=_HI)
-    W, Ut = WU[..., :dk], WU[..., dk:]
-    q_in = q * from_start
-    k_end = k * jnp.exp(G[..., -1:, :] - G)
-    end_decay = jnp.exp(G[..., -1, :])  # (nc, rows, h, dk)
+    sub = _SUB if C % _SUB == 0 else C
+    # a chunk at a time: faster on the chip than all chunks at once, whose
+    # copies into sub-block order cost more (PERF.md §6, PR 36)
+    A, B = jax.lax.map(lambda a: _scores(*a, sub), (qh, kh, Gh))
+    A = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1), A, 0.0)
+    T = _block_lower_inverse(beta[..., None] * A, sub)  # (nc, rows, h, C, C)
     S0 = (
         jnp.zeros((rows, h, dk, dv), f32)
         if initial_state is None
@@ -255,16 +307,26 @@ def kda_chunked(
     )
 
     def carry(S, inp):
-        w, ut, qi, b, ke, dec = inp
-        u = ut - jnp.einsum("bhtk,bhkv->bhtv", w, S, precision=_HI)
-        o = jnp.einsum("bhtk,bhkv->bhtv", qi, S, precision=_HI) + jnp.einsum(
-            "bhts,bhsv->bhtv", b, u, precision=_HI
+        ti, b, bc, qc, kc, vc, Gc = inp  # the last four (rows, C, h, d)
+        end = Gc[:, -1:]
+        # the corrections: U = T (Diag(b) (V - (K * exp G) S))
+        ku = kc * jnp.exp(Gc)
+        p = jnp.einsum("bthk,bhkv->bhtv", ku, S, precision=_HI)
+        u = jnp.einsum(
+            "bhts,bhsv->bhtv", ti, bc[..., None] * (jnp.swapaxes(vc, 1, 2) - p),
+            precision=_HI,
         )
-        S = dec[..., None] * S + jnp.einsum(
-            "bhtk,bhtv->bhkv", ke, u, precision=_HI
+        o = jnp.einsum(
+            "bthk,bhkv->bthv", qc * jnp.exp(Gc), S, precision=_HI
+        ) + jnp.einsum("bhts,bhsv->bthv", b, u, precision=_HI)
+        S = jnp.exp(end[:, 0, :, :, None]) * S + jnp.einsum(
+            "bthk,bhtv->bhkv", kc * jnp.exp(end - Gc), u, precision=_HI
         )
         return S, o
 
-    final, o = jax.lax.scan(carry, S0, (W, Ut, q_in, B, k_end, end_decay))
-    o = o.transpose(1, 0, 3, 2, 4).reshape(rows, nc * C, h, dv)
+    final, o = jax.lax.scan(
+        carry, S0,
+        (T, B, beta) + tuple(t.swapaxes(0, 1) for t in (q, k, v, G)),
+    )
+    o = o.swapaxes(0, 1).reshape(rows, nc * C, h, dv)
     return o[:, :L], final

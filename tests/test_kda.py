@@ -48,15 +48,63 @@ def recurrence(q, k, v, g, beta, S0=None, valid=None):
     return out, S
 
 
-@pytest.mark.parametrize("L, chunk", [(37, 8), (16, 8), (5, 8), (64, 16), (33, 64)])
-def test_chunked_equals_recurrence(L, chunk):
-    """Across chunk boundaries, for a width that is no multiple of the
-    chunk, and for one shorter than it."""
+def _holes(L, sub):
+    """Invalid positions on the first and the last position of sub-blocks
+    (the second and the third), on a later one's first and at the end."""
+    valid = np.ones((2, L), bool)
+    valid[0, [sub, 2 * sub - 1, 3 * sub - 1]] = False
+    valid[1, [4 * sub, L - 1]] = False
+    return valid
+
+
+@pytest.mark.parametrize("L, chunk, holes", [
+    (37, 8, False), (16, 8, False), (5, 8, False), (64, 16, False),
+    (33, 64, False),
+    (70, 32, False), (96, 32, False),  # chunks of several sub-blocks
+    (40, 12, False),  # a chunk no sub-block size divides: one sub-block
+    (19, 4, False),  # a chunk smaller than a sub-block
+    (70, 32, True),  # holes on sub-blocks' first and last positions
+])
+def test_chunked_equals_recurrence(L, chunk, holes):
+    """Across chunk and sub-block boundaries, for a width that is no
+    multiple of the chunk, for one shorter than it, and with invalid
+    positions where a sub-block's decays are split."""
     q, k, v, g, beta, _ = draws(L, L=L)
-    want_o, want_S = recurrence(q, k, v, g, beta)
-    o, S = jax.jit(lambda *a: kda.kda_chunked(*a, chunk=chunk))(q, k, v, g, beta)
-    np.testing.assert_allclose(o, want_o, atol=2e-5)
+    valid = _holes(L, kda._SUB) if holes else None
+    want_o, want_S = recurrence(q, k, v, g, beta, valid=valid)
+    o, S = jax.jit(lambda *a: kda.kda_chunked(*a, chunk=chunk, valid=(
+        None if valid is None else jnp.asarray(valid)
+    )))(q, k, v, g, beta)
+    keep = np.ones(q.shape[:2], bool) if valid is None else valid
+    np.testing.assert_allclose(np.asarray(o)[keep], want_o[keep], atol=2e-5)
     np.testing.assert_allclose(S, want_S, atol=2e-5)
+
+
+def _shapes(jaxpr):
+    """Every intermediate's shape in ``jaxpr`` and the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield tuple(getattr(var.aval, "shape", ()))
+        for param in eqn.params.values():
+            for inner in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _shapes(inner)
+
+
+def test_only_the_diagonal_sub_blocks_form_the_per_channel_decays():
+    """At chunk 32 no intermediate holds a (t, s, channel) tensor over the
+    whole chunk; the diagonal sub-blocks' (sub, sub, channel) one is
+    there."""
+    dk, sub = 12, kda._SUB
+    assert 32 % sub == 0 and sub < 32
+    q, k, v, g, beta, _ = draws(10, rows=1, L=64, h=2, dk=dk)
+    jaxpr = jax.make_jaxpr(lambda *a: kda.kda_chunked(*a, chunk=32))(
+        q, k, v, g, beta
+    )
+    tails = {shape[-3:] for shape in _shapes(jaxpr.jaxpr)}
+    assert (32, 32, dk) not in tails
+    assert (sub, sub, dk) in tails
 
 
 def test_chunked_from_an_initial_state():
@@ -109,12 +157,14 @@ def test_all_invalid_returns_the_state_bit_for_bit():
     np.testing.assert_array_equal(S, S0)
 
 
-def test_strong_decay_neither_overflows_nor_loses_the_answer():
+@pytest.mark.parametrize("chunk", [64, 32])
+def test_strong_decay_neither_overflows_nor_loses_the_answer(chunk):
     """Cumulative log-decays of a chunk reach -380 here: a quotient of
-    cumulative products would be inf / 0; differences are not."""
+    cumulative products would be inf / 0; differences are not. Across
+    sub-blocks the two factors of a decay underflow where it does."""
     q, k, v, g, beta, S0 = draws(5, L=64, strong=True)
     want_o, want_S = recurrence(q, k, v, g, beta, S0)
-    o, S = kda.kda_chunked(q, k, v, g, beta, chunk=64, initial_state=S0)
+    o, S = kda.kda_chunked(q, k, v, g, beta, chunk=chunk, initial_state=S0)
     assert np.isfinite(np.asarray(o)).all()
     np.testing.assert_allclose(o, want_o, atol=5e-5)
     np.testing.assert_allclose(S, want_S, atol=5e-5)

@@ -267,6 +267,28 @@ def test_kda_step_compiles_for_v5e(one_chip, rows):
     )
 
 
+def test_kda_chunked_compiles_for_v5e_within_the_parents_temporaries(one_chip):
+    """The chunked delta rule at the Solar-Open2 cell's prefill (one row
+    of 1024 positions, 64 heads of a 128 x 128 float32 state, chunk 32,
+    ``valid``) compiles for the chip, and its temporaries stay within the
+    134,572,544 bytes that the form before the sub-blocks asked for at
+    this shape (compile, PR 36)."""
+    from tensorflowonspark_tpu.ops.kda import kda_chunked
+
+    rows, L, h, d = 1, 1024, 64, 128
+    f32 = jnp.float32
+    shapes = [((rows, L, h, d), f32)] * 4 + [
+        ((rows, L, h), f32), ((rows, h, d, d), f32), ((rows, L), jnp.bool_),
+    ]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    compiled = jax.jit(
+        lambda q, k, v, g, b, s0, valid: kda_chunked(
+            q, k, v, g, b, chunk=32, initial_state=s0, valid=valid
+        )
+    ).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 134_572_544
+
+
 @pytest.mark.parametrize("rows", [1024, 8192])  # a decode step's, a prefill's
 @pytest.mark.parametrize("banks,d,f", [
     (40, 4096, 1280), (40, 1280, 4096),  # Solar-Open2: gate / up, down
