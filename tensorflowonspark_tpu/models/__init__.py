@@ -39,6 +39,11 @@ from tensorflowonspark_tpu.models.pangu_moe import (  # noqa: F401
     PanguMoEConfig,
     pangu_moe_param_shardings,
 )
+from tensorflowonspark_tpu.models.phi4_flash import (  # noqa: F401
+    Phi4Flash,
+    Phi4FlashConfig,
+    phi4_flash_param_shardings,
+)
 from tensorflowonspark_tpu.models.resnet import (  # noqa: F401
     ResNet,
     ResNetConfig,

@@ -2,7 +2,9 @@
 it: a flax ``cache`` collection whose leaves are told apart by name.
 
 - K/V leaves (``k``, ``v`` and, under ``kv_cache_dtype="int8"``,
-  ``k_scale``, ``v_scale``): one entry a position, (rows, positions, ...).
+  ``k_scale``, ``v_scale``): one entry a position, (rows, positions, ...),
+  or a position's heads as rows, (rows, positions x heads, d), under
+  ``llama.Attention``'s ``kv_heads_in_rows``.
 - Latent leaves (``latent``): one entry a position like K/V, (rows,
   positions, width), but no heads: the compressed key-value of latent
   attention and the rotary key every head shares, which a decode step
@@ -14,8 +16,9 @@ it: a flax ``cache`` collection whose leaves are told apart by name.
   token the row has consumed. They cannot be resumed from an earlier
   position, nor masked after the fact: a token that must not count must
   not be applied. ``ssm`` is the Mamba-2 state (rows, h, p, N) of
-  ``models/falcon_h1.py``, ``kda`` the delta-rule state (rows, h, d_k,
-  d_v) of ``models/solar_open2.py``, ``conv`` either model's last
+  ``models/falcon_h1.py`` or the Mamba-1 state (rows, N, c) of
+  ``models/phi4_flash.py``, ``kda`` the delta-rule state (rows, h, d_k,
+  d_v) of ``models/solar_open2.py``, ``conv`` any of these models' last
   ``k - 1`` inputs of a depthwise convolution, (rows, k - 1, c).
 - Counters (``moe_counts``): what the decode steps have routed, summed
   on the device since the cache was made and wrapping as int32 does. No
@@ -29,10 +32,15 @@ Every leaf but the scalar write index and the counters has the row first,
 which is all the engine's admission scatter and donation need to know of
 a leaf. A layer need not hold every kind: a model whose layers differ
 (``models/solar_open2.py``) has K/V in some and recurrent leaves in the
-others, and the engine asks of the tree, never of a layer.
+others, and the engine asks of the tree, never of a layer. Nor need the
+K/V layers agree on a length: a windowed layer may keep a ring of its
+window beside a dense plane of ``max_seq_len`` (``models/phi4_flash.py``),
+and the engine counts each at its own (:func:`kv_layers`).
 """
 
 from __future__ import annotations
+
+from collections.abc import Mapping
 
 import jax
 import jax.numpy as jnp
@@ -80,15 +88,39 @@ def starts_sequence(module, leaf: str) -> bool:
     return not module.has_variable("cache", leaf)
 
 
-def keys_scored(width: int, cache_len: int, handed_cache: bool) -> int:
+def keys_scored(
+    width: int, cache_len: int, handed_cache: bool, window: int | None = None
+) -> int:
     """The keys each query of a ``width``-position prefill call is
     scored against, as :func:`starts_sequence` decides it in the models:
-    the call's own ``width`` where it creates its cache, every one of
-    the ``cache_len`` slots where it was handed one (a chunk, a prefix
-    resume: the einsum over the cache). What the engine counts per
-    dispatched prefill program, so that the counter follows the models'
-    rule and not a copy of it."""
-    return cache_len if handed_cache else width
+    the call's own ``width`` where it creates its cache (at most the
+    layer's ``window``: the flash kernel skips the tiles outside it),
+    every one of the ``cache_len`` slots where it was handed one (a
+    chunk, a prefix resume: the einsum over the cache). What the engine
+    counts per dispatched prefill program and K/V layer, so that the
+    counter follows the models' rule and not a copy of it."""
+    if handed_cache:
+        return cache_len
+    return width if window is None else min(width, window)
+
+
+def kv_layers(tree) -> list[tuple[int, bool]]:
+    """For each layer of a cache tree that holds one entry a position
+    (a ``k`` or ``latent`` leaf): its positions, and whether it records
+    them (a ``pos`` leaf beside it: the layer reads under a window). What
+    the engine counts each layer's reads by, whatever lengths the layers'
+    planes have (``models/phi4_flash.py``: rings beside a dense plane)."""
+    out = []
+    for key, node in tree.items():
+        if not isinstance(node, Mapping):
+            continue
+        plane = node.get("k", node.get("latent"))
+        if plane is not None and not isinstance(plane, Mapping):
+            # the id plane has one entry a position however K/V lie
+            out.append((node.get("seg", plane).shape[1], "pos" in node))
+        else:
+            out += kv_layers(node)
+    return out
 
 
 def _leaf_name(path) -> str:
@@ -120,8 +152,8 @@ def init_cache(shapes):
     plane, which is -1 ("never written") so a rolling cache cannot
     mistake a stale slot for a valid position 0. Keep in lockstep with
     the ``self.variable`` inits in ``llama.Attention._cached_attention``,
-    ``falcon_h1.Mixer``, ``pangu_moe``'s ``LatentAttention`` / ``Block`` and
-    ``solar_open2``'s ``DeltaMixer`` / ``Block``.
+    ``falcon_h1.Mixer``, ``pangu_moe``'s ``LatentAttention`` / ``Block``,
+    ``solar_open2``'s ``DeltaMixer`` / ``Block`` and ``phi4_flash.Mamba``.
     """
 
     def init(path, s):

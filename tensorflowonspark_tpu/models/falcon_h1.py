@@ -100,11 +100,13 @@ class FalconH1Config:
     kv_cache_dtype: str = "model"
     # What llama.Attention reads of its config and this architecture does
     # not vary: full causal attention, unscaled rotary positions, no
-    # bias, no output gate.
+    # bias, no output gate, one map a head, K/V stored by position.
     attention_impl: str = "auto"
     attention_bias: bool = False
     use_rope: bool = True
     attention_output_gate: bool = False
+    differential_lambda_init: None = None
+    kv_heads_in_rows: bool = False
     rope_scaling: None = None
     sliding_window: None = None
     kv_cache_len: None = None

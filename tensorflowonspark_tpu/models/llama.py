@@ -129,6 +129,19 @@ class LlamaConfig:
     # channel (models/solar_open2.py). False makes no ``g_proj`` and
     # traces nothing.
     attention_output_gate: bool = False
+    # Differential attention (Diff Transformer, arXiv:2410.05258) with
+    # this lambda_init: each head is two softmax maps over the same keys,
+    # the second taken away at a learned weight, then normed
+    # (``differential_queries`` / ``Attention._differential``;
+    # models/phi4_flash.py). None: ordinary attention, a Python branch
+    # that traces nothing.
+    differential_lambda_init: float | None = None
+    # The K/V planes stored (rows, positions x kv heads, head_dim), a
+    # position's heads as rows, in place of (rows, positions, kv heads,
+    # head_dim): the TPU tiles a plane's two minor dimensions, and a head
+    # count that is no multiple of 8 (models/phi4_flash.py's 10 pairs)
+    # would pad to the next. False stores and traces what it always did.
+    kv_heads_in_rows: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -350,37 +363,68 @@ class Attention(nn.Module):
     input before ``o_proj``. ``cfg`` is a
     :class:`LlamaConfig` or any config that carries the attributes read
     here (``models/falcon_h1.py`` passes its own, whose ``head_dim`` is
-    not ``hidden_size / num_heads``)."""
+    not ``hidden_size / num_heads``).
+
+    Where ``cfg.differential_lambda_init`` is set the layer is
+    differential (:func:`differential_queries`): ``num_heads`` counts
+    query rows, two a differential head, and ``num_kv_heads`` /
+    ``head_dim`` the K/V pairs as stored, each two published heads side by
+    side; the query projection is half as wide.
+
+    ``return_kv`` also returns the K/V that another layer may attend:
+    those in hand without ``decode``, the cache's planes after this
+    call's write with it. ``shared_kv`` is such a pair: the layer then
+    projects queries only, writes no cache and attends the pair (causal
+    in hand; in ``decode``, one position a row over the dense planes up
+    to its position: :func:`attend_plane`)."""
 
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(
         self, x, positions, segment_ids=None, decode=False, padded=False,
-        adapter_ids=None, valid=None,
+        adapter_ids=None, valid=None, shared_kv=None, return_kv=False,
     ):
         cfg = self.cfg
         dense = lambda feats, name, b=False: QDense(  # noqa: E731
             feats, cfg.dtype, use_bias=b, name=name
         )
         ab = cfg.attention_bias
-        q = dense(cfg.num_heads * cfg.head_dim, "q_proj", ab)(x, adapter_ids)
-        k = dense(cfg.num_kv_heads * cfg.head_dim, "k_proj", ab)(
-            x, adapter_ids
-        )
-        v = dense(cfg.num_kv_heads * cfg.head_dim, "v_proj", ab)(
-            x, adapter_ids
-        )
+        diff = cfg.differential_lambda_init is not None
+        q = dense(cfg.num_heads * cfg.head_dim // (2 if diff else 1),
+                  "q_proj", ab)(x, adapter_ids)
+        if shared_kv is None:
+            k = dense(cfg.num_kv_heads * cfg.head_dim, "k_proj", ab)(
+                x, adapter_ids
+            )
+            v = dense(cfg.num_kv_heads * cfg.head_dim, "v_proj", ab)(
+                x, adapter_ids
+            )
         b, s, _ = x.shape
-        q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-        if cfg.key_multiplier != 1.0:
+        if diff:
+            q = differential_queries(q.reshape(b, s, cfg.num_heads, -1))
+        else:
+            q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
+        if shared_kv is None:
+            k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        else:
+            k, v = shared_kv
+        # shared keys come as the layer that made them left them
+        if cfg.key_multiplier != 1.0 and shared_kv is None:
             k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
         if cfg.use_rope:
             q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        if decode:
+            if shared_kv is None:
+                k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        if shared_kv is not None and decode:
+            if s != 1:
+                raise ValueError(
+                    f"a layer that reads another's cache takes one position "
+                    f"a row, got {s}"
+                )
+            out = attend_plane(q[:, 0], k, v, positions[:, 0] + 1, cfg)[:, None]
+        elif decode:
             if segment_ids is not None and padded:
                 raise ValueError(
                     "segment_ids with padded=True is unsupported: padded "
@@ -390,12 +434,18 @@ class Attention(nn.Module):
                 )
             out = self._cached_attention(q, k, v, positions, padded,
                                          segment_ids, valid)
+            if return_kv:
+                k = self.get_variable("cache", "k")
+                v = self.get_variable("cache", "v")
         else:
             out = dot_product_attention(
                 q, k, v, causal=True, segment_ids=segment_ids,
                 impl=cfg.attention_impl, window=cfg.sliding_window,
             )
-        out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
+        if diff:
+            out = self._differential(out)
+        else:
+            out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
         if cfg.attention_output_gate:
             with jax.named_scope("attn.gate"):
                 gate = dense(cfg.num_heads * cfg.head_dim, "g_proj")(
@@ -404,7 +454,39 @@ class Attention(nn.Module):
                 out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
                     out.dtype
                 )
-        return dense(cfg.hidden_size, "o_proj")(out, adapter_ids)
+        out = dense(cfg.hidden_size, "o_proj")(out, adapter_ids)
+        return (out, (k, v)) if return_kv else out
+
+    def _differential(self, out):
+        """The two maps of each differential head made one: ``out``
+        (b, s, 2h, 2d) holds, for head ``i``, rows ``2i`` (``q1`` against
+        ``k1``) and ``2i + 1`` (``q2`` against ``k2``), each over ``[v1 |
+        v2]``. ``a = a1 - lambda a2``, ``lambda = exp(lq1 . lk1) - exp(lq2
+        . lk2) + lambda_init``, then RMSNorm over the head's 2d values and
+        ``1 - lambda_init``."""
+        cfg = self.cfg
+        b, s, rows, width = out.shape
+        lam0 = cfg.differential_lambda_init
+        f32 = jnp.float32
+        with jax.named_scope("sambay.diff"):
+            lv = {
+                n: self.param(n, nn.initializers.normal(0.1), (width // 2,))
+                for n in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+            }
+            dot = lambda a, c: jnp.sum(  # noqa: E731
+                lv[a].astype(f32) * lv[c].astype(f32)
+            )
+            lam = (
+                jnp.exp(dot("lambda_q1", "lambda_k1"))
+                - jnp.exp(dot("lambda_q2", "lambda_k2")) + lam0
+            )
+            pair = out.astype(f32).reshape(b, s, rows // 2, 2, width)
+            a = pair[:, :, :, 0] - lam * pair[:, :, :, 1]
+            scale = self.param("subln", nn.initializers.ones, (width,))
+            a = a * jax.lax.rsqrt(
+                jnp.mean(a * a, axis=-1, keepdims=True) + cfg.rms_norm_eps
+            ) * (scale.astype(f32) * (1.0 - lam0))
+            return a.reshape(b, s, rows // 2 * width).astype(cfg.dtype)
 
     def _cached_attention(
         self, q, k, v, positions, padded=False, segment_ids=None,
@@ -474,10 +556,11 @@ class Attention(nn.Module):
           is HBM-bandwidth-bound: a padded step of one position against
           the dense model-dtype cache on a single TPU goes to
           ``ops/decode_attention.py``, which reads only the blocks a
-          row has written; every other continuing caller (chunks,
+          row has written, as does the same step against a ring of
+          exactly its window; every other continuing caller (chunks,
           prefix resumes, speculative verification, uniform and packed
-          rows, the rolling and int8 caches, a mesh, the CPU) takes the
-          einsum over the whole cache.
+          rows, a ring longer than its window, the int8 cache, a mesh,
+          the CPU) takes the einsum over the whole cache.
         """
         cfg = self.cfg
         b, s = q.shape[:2]
@@ -499,9 +582,12 @@ class Attention(nn.Module):
                     "rolling kv_cache_len: per-document positions "
                     "collide under slot = position % C"
                 )
-            if C < cfg.sliding_window + s - 1:
+            if not fresh and C < cfg.sliding_window + s - 1:
                 # a write of s positions may not wrap onto slots that
-                # queries in the SAME call still attend
+                # queries in the SAME call still attend (a call that
+                # starts its sequence attends the K/V in hand, not the
+                # ring: it may be wider than the ring, and stores the
+                # last C of its positions)
                 raise ValueError(
                     f"kv_cache_len={C} must be >= sliding_window "
                     f"({cfg.sliding_window}) + write width ({s}) - 1; "
@@ -510,14 +596,12 @@ class Attention(nn.Module):
                 )
         int8_kv = cfg.kv_cache_dtype == "int8"
         kv_store = jnp.int8 if int8_kv else cfg.dtype
-        ck = self.variable(
-            "cache", "k", jnp.zeros,
-            (b, C, cfg.num_kv_heads, cfg.head_dim), kv_store,
-        )
-        cv = self.variable(
-            "cache", "v", jnp.zeros,
-            (b, C, cfg.num_kv_heads, cfg.head_dim), kv_store,
-        )
+        kvh, flat = cfg.num_kv_heads, cfg.kv_heads_in_rows
+        if flat and int8_kv:
+            raise ValueError("kv_heads_in_rows is unsupported with an int8 cache")
+        plane = (b, C * kvh, cfg.head_dim) if flat else (b, C, kvh, cfg.head_dim)
+        ck = self.variable("cache", "k", jnp.zeros, plane, kv_store)
+        cv = self.variable("cache", "v", jnp.zeros, plane, kv_store)
         if int8_kv:
             # Per-token per-head max-abs scales. fp32: 4 bytes per
             # head-token next to head_dim int8 bytes (~3% at d=128).
@@ -573,6 +657,21 @@ class Attention(nn.Module):
 
         k_new, ks_new = store(k)
         v_new, vs_new = store(v)
+
+        def as_rows(new):
+            """New K/V (b, s, heads, d) as the plane holds them: a
+            position's heads as its rows where the plane is flat."""
+            return new.reshape(b, s * kvh, -1) if flat else new
+
+        def put(x, rows, at, new, **drop):
+            """``new`` (b, s, heads, d) written at slots ``at`` (b, s)."""
+            if flat:
+                at = (at[..., None] * kvh + jnp.arange(kvh)).reshape(b, s * kvh)
+            return x.at[rows, at].set(as_rows(new), **drop)
+
+        def heads(x):
+            """A plane as (b, C, kv heads, d)."""
+            return x.reshape(b, C, kvh, -1) if flat else x
         if rolling:
             # slot = position % C for BOTH padded and uniform rows: the
             # mask below is purely positional (via the pos plane), so
@@ -580,21 +679,32 @@ class Attention(nn.Module):
             # unnecessary here
             rows = jnp.arange(b)[:, None]
             slots = positions % C
-            ck.value = ck.value.at[rows, slots].set(k_new)
-            cv.value = cv.value.at[rows, slots].set(v_new)
+            drop = {}
+            if s > C:
+                # Wider than the ring (a call that starts its sequence):
+                # two positions share a slot, so only each row's last C
+                # valid positions are written, the others dropped.
+                real = positions if valid is None else jnp.where(
+                    valid, positions, -1)
+                keep = positions > jnp.max(real, axis=1, keepdims=True) - C
+                if valid is not None:
+                    keep = keep & valid
+                slots, drop = jnp.where(keep, slots, C), {"mode": "drop"}
+            ck.value = put(ck.value, rows, slots, k_new, **drop)
+            cv.value = put(cv.value, rows, slots, v_new, **drop)
             if int8_kv:
-                cks.value = cks.value.at[rows, slots].set(ks_new)
-                cvs.value = cvs.value.at[rows, slots].set(vs_new)
-            cs.value = cs.value.at[rows, slots].set(seg)
-            cp.value = cp.value.at[rows, slots].set(positions)
+                cks.value = cks.value.at[rows, slots].set(ks_new, **drop)
+                cvs.value = cvs.value.at[rows, slots].set(vs_new, **drop)
+            cs.value = cs.value.at[rows, slots].set(seg, **drop)
+            cp.value = cp.value.at[rows, slots].set(positions, **drop)
             slot_q = None  # unused: rolling masks by position only
         elif padded:
             rows = jnp.arange(b)[:, None]
             at = positions
             if valid is not None:
                 at = jnp.where(valid, positions, C)  # out of range: dropped
-            ck.value = ck.value.at[rows, at].set(k_new, mode="drop")
-            cv.value = cv.value.at[rows, at].set(v_new, mode="drop")
+            ck.value = put(ck.value, rows, at, k_new, mode="drop")
+            cv.value = put(cv.value, rows, at, v_new, mode="drop")
             if int8_kv:
                 cks.value = cks.value.at[rows, at].set(ks_new, mode="drop")
                 cvs.value = cvs.value.at[rows, at].set(vs_new, mode="drop")
@@ -604,12 +714,9 @@ class Attention(nn.Module):
             # packed+padded combination is rejected in __call__)
             slot_q = positions
         else:
-            ck.value = jax.lax.dynamic_update_slice(
-                ck.value, k_new, (0, cur, 0, 0)
-            )
-            cv.value = jax.lax.dynamic_update_slice(
-                cv.value, v_new, (0, cur, 0, 0)
-            )
+            at = (0, cur * kvh, 0) if flat else (0, cur, 0, 0)
+            ck.value = jax.lax.dynamic_update_slice(ck.value, as_rows(k_new), at)
+            cv.value = jax.lax.dynamic_update_slice(cv.value, as_rows(v_new), at)
             if int8_kv:
                 cks.value = jax.lax.dynamic_update_slice(
                     cks.value, ks_new, (0, cur, 0)
@@ -639,10 +746,16 @@ class Attention(nn.Module):
             # TPU: the kernel is told each row's written length (a slot
             # is its position here) and fetches no block past it, nor
             # before the window. The id plane is not read: ``padded``
-            # with ids was refused in __call__.
+            # with ids was refused in __call__. A ring of exactly its
+            # window: its first slots up to the row's length, all of
+            # them once full (each then holds one of the window's
+            # positions).
+            length = positions[:, 0] + 1
             out = decode_attention(
-                q[:, 0], ck.value, cv.value, positions[:, 0] + 1,
-                window=cfg.sliding_window,
+                q[:, 0], ck.value, cv.value,
+                jnp.minimum(length, C) if rolling else length,
+                window=None if rolling else cfg.sliding_window,
+                **({"kv_heads": kvh} if flat else {}),
             )
             return out[:, None]
         # Grouped einsum against the un-repeated cache: materializing a
@@ -662,7 +775,7 @@ class Attention(nn.Module):
             jnp.einsum(
                 "bqhrd,bkhd->bhrqk",
                 qg,
-                ck.value.astype(cfg.dtype),
+                heads(ck.value).astype(cfg.dtype),
                 preferred_element_type=jnp.float32,
             )
             * cfg.head_dim**-0.5
@@ -711,8 +824,51 @@ class Attention(nn.Module):
         if int8_kv:
             probs = probs * cvs.value.transpose(0, 2, 1)[:, :, None, None, :]
         probs = probs.astype(cfg.dtype)
-        out = jnp.einsum("bhrqk,bkhd->bqhrd", probs, cv.value.astype(cfg.dtype))
+        out = jnp.einsum("bhrqk,bkhd->bqhrd", probs, heads(cv.value).astype(cfg.dtype))
         return out.reshape(b, s, cfg.num_heads, cfg.head_dim)
+
+
+def differential_queries(q):
+    """Differential heads' queries (b, s, 2h, d), rows ``2i``, ``2i + 1``
+    being ``q1``, ``q2`` of head ``i``, as rows of width 2d against K/V
+    stored in pairs ``[k1 | k2]``, ``[v1 | v2]``: row ``2i`` is ``[q1, 0]``,
+    row ``2i + 1`` ``[0, q2]``, so that one attention over the pairs makes
+    both maps of every head, reading each K/V block once. Scaled by
+    ``sqrt(2)``: the attention's ``1 / sqrt(2d)`` is then the published
+    ``1 / sqrt(d)``."""
+    b, s, rows, d = q.shape
+    lane = jnp.arange(2 * d)[None, :] < d
+    first = jnp.arange(rows)[:, None] % 2 == 0
+    mask = jnp.where(lane == first, 2.0**0.5, 0.0).astype(jnp.float32)
+    wide = jnp.concatenate([q, q], axis=-1).astype(jnp.float32)
+    return (wide * mask).astype(q.dtype)
+
+
+def attend_plane(q, ck, cv, lengths, cfg):
+    """One query position a row, ``q`` (b, heads, d), over a dense K/V
+    plane (b, C, kv_heads, d) whose slot is its position (flat, (b, C x
+    kv_heads, d), where ``cfg.kv_heads_in_rows``), the first ``lengths``
+    (b,) of them: ``decode_attention`` on one TPU (the blocks
+    a row has written), else the grouped einsum over the plane. What a
+    layer that reads another layer's cache attends with
+    (``Attention(shared_kv=...)``)."""
+    kvh = cfg.num_kv_heads
+    flat = cfg.kv_heads_in_rows
+    if cache_block_k(cfg) is not None:
+        return decode_attention(q, ck, cv, lengths, **({"kv_heads": kvh} if flat else {}))
+    b, heads, d = q.shape
+    if flat:
+        ck, cv = (x.reshape(b, -1, kvh, d) for x in (ck, cv))
+    qg = q.reshape(b, kvh, heads // kvh, d)
+    logits = jnp.einsum(
+        "bhrd,bkhd->bhrk", qg, ck.astype(cfg.dtype),
+        preferred_element_type=jnp.float32,
+    ) * d**-0.5
+    written = jnp.arange(ck.shape[1])[None, :] < lengths[:, None]
+    logits = jnp.where(written[:, None, None, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
+    out = jnp.einsum("bhrk,bkhd->bhrd", probs, cv.astype(cfg.dtype))
+    return out.reshape(b, heads, d)
 
 
 class MLP(nn.Module):

@@ -121,7 +121,8 @@ class SolarOpen2Config:
     max_seq_len: int = 4096
     dtype: jnp.dtype = jnp.bfloat16
     # What llama.Attention reads of its config and this architecture does
-    # not vary: full causal attention, no bias, keys as projected.
+    # not vary: full causal attention, no bias, keys as projected, one
+    # map a head, K/V stored by position.
     attention_impl: str = "auto"
     attention_bias: bool = False
     rope_scaling: None = None
@@ -129,6 +130,8 @@ class SolarOpen2Config:
     kv_cache_len: None = None
     kv_cache_dtype: str = "model"
     key_multiplier: float = 1.0
+    differential_lambda_init: None = None
+    kv_heads_in_rows: bool = False
 
     @property
     def held(self) -> int:

@@ -332,6 +332,37 @@ def _build_solar_open2(tiny):
     )
 
 
+def _build_phi4_flash(tiny):
+    from tensorflowonspark_tpu.models import phi4_flash as P
+
+    # full size: the defaults are Phi-4-mini-flash-reasoning's published
+    # config
+    cfg = P.Phi4FlashConfig.tiny() if tiny else P.Phi4FlashConfig()
+    model = P.Phi4Flash(cfg)
+
+    def make_input(b):
+        rng = np.random.default_rng(0)
+        s = min(cfg.max_seq_len, 32 if tiny else 1024)
+        return {
+            "tokens": rng.integers(0, cfg.vocab_size, size=(b, s + 1)).astype(
+                np.int32
+            )
+        }
+
+    def make_loss():
+        token_loss = P.phi4_flash_loss_fn(model)
+        return lambda p, batch: token_loss(p, batch["tokens"])
+
+    return ZooEntry(
+        name="phi4_mini_flash",
+        kind="tokens",
+        model=model,
+        make_input=make_input,
+        param_shardings=P.phi4_flash_param_shardings,
+        make_loss=make_loss,
+    )
+
+
 _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "resnet18": lambda tiny, nc: _build_resnet("resnet18", tiny, nc),
     "resnet34": lambda tiny, nc: _build_resnet("resnet34", tiny, nc),
@@ -351,6 +382,7 @@ _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "falcon_h1_34b": lambda tiny, nc: _build_falcon_h1(tiny),
     "pangu_ultra_moe_718b": lambda tiny, nc: _build_pangu_moe(tiny),
     "solar_open2_250b": lambda tiny, nc: _build_solar_open2(tiny),
+    "phi4_mini_flash": lambda tiny, nc: _build_phi4_flash(tiny),
 }
 
 

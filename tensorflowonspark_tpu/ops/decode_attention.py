@@ -165,17 +165,24 @@ def decode_attention(
     *,
     window: int | None = None,
     block_k: int | None = None,
+    kv_heads: int | None = None,
 ) -> jax.Array:
     """Attention of one query position a row over its written cache.
 
     ``q`` (rows, heads, d); ``k_cache`` / ``v_cache`` (rows, C, kv_heads,
-    d) as stored; ``lengths`` (rows,) int32, the positions each row has
+    d) as stored, or (rows, C * kv_heads, d) with ``kv_heads`` given (a
+    position's heads as rows: how the kernel reads a block in any case,
+    and a layout that pads nothing where ``kv_heads`` is no multiple of
+    a tile's 8 rows); ``lengths`` (rows,) int32, the positions each row has
     written, the query's own included: position ``p`` is attended iff
     ``max(0, length - window) <= p < length``. Returns (rows, heads, d)
     in ``q``'s dtype. Nothing past a row's last live block is fetched.
     """
     rows, heads, d = q.shape
-    _, C, kv_heads, _ = k_cache.shape
+    if kv_heads is None:
+        _, C, kv_heads, _ = k_cache.shape
+    else:
+        C = k_cache.shape[1] // kv_heads
     if heads % kv_heads:
         raise ValueError(f"q heads {heads} not divisible by kv heads {kv_heads}")
     if window is not None and window < 1:
@@ -200,6 +207,11 @@ def decode_attention(
         first, last = live_blocks(jnp, lens[r], window, block_k)
         return r, jnp.maximum(j - (C // block_k - 1 - last), first), 0, 0
 
+    kv_spec = pl.BlockSpec((1, block_k, kv_heads, d), kv_block)
+    if k_cache.ndim == 3:
+        kv_spec = pl.BlockSpec(
+            (1, n, d), lambda r, j, lens: kv_block(r, j, lens)[:3]
+        )
     out = pl.pallas_call(
         functools.partial(
             _kernel, block_k=block_k, kv_heads=kv_heads, scale=d**-0.5,
@@ -211,8 +223,8 @@ def decode_attention(
             in_specs=[
                 pl.BlockSpec((1, hp, d), lambda r, j, lens: (r, 0, 0)),
                 pl.BlockSpec((hp, n), lambda r, j, lens: (0, 0)),
-                pl.BlockSpec((1, block_k, kv_heads, d), kv_block),
-                pl.BlockSpec((1, block_k, kv_heads, d), kv_block),
+                kv_spec,
+                kv_spec,
             ],
             out_specs=pl.BlockSpec((1, hp, d), lambda r, j, lens: (r, 0, 0)),
             scratch_shapes=[
@@ -392,17 +404,20 @@ def latent_decode_attention(
 def cache_block_k(cfg) -> int | None:
     """``block_k`` of the kernel that a padded one-position step against
     ``cfg``'s cache takes in this process, or None where that step keeps
-    the einsum: a rolling or an int8 cache, an ambient mesh (GSPMD
-    partitions the einsum and cannot partition a ``pallas_call``), no
-    TPU. ``cfg`` is a model config as ``llama.Attention`` reads it, or
-    one whose cache entry is a latent (it then carries ``kv_lora_rank``
-    and ``qk_rope_head_dim``: ``models/pangu_moe.py``)."""
+    the einsum: a rolling cache longer than its window, an int8 cache, an
+    ambient mesh (GSPMD partitions the einsum and cannot partition a
+    ``pallas_call``), no TPU. A ring of exactly its window
+    (``models/phi4_flash.py``) takes the kernel: once full, every slot it
+    holds is attended, in whatever order. ``cfg`` is a model config as
+    ``llama.Attention`` reads it, or one whose cache entry is a latent (it
+    then carries ``kv_lora_rank`` and ``qk_rope_head_dim``:
+    ``models/pangu_moe.py``)."""
     from tensorflowonspark_tpu.ops import attention
     from tensorflowonspark_tpu.parallel.context import current_mesh
 
     C = cfg.kv_cache_len or cfg.max_seq_len
     if (
-        C < cfg.max_seq_len
+        (C < cfg.max_seq_len and C != cfg.sliding_window)
         or cfg.kv_cache_dtype != "model"
         or current_mesh() is not None
         or not attention._on_tpu()

@@ -88,6 +88,7 @@ import numpy as np
 from tensorflowonspark_tpu.models.decode_cache import (
     init_cache,
     keys_scored,
+    kv_layers,
     leaf_kind,
 )
 from tensorflowonspark_tpu.obs import registry as obs_registry
@@ -1038,21 +1039,24 @@ class ContinuousBatcher:
         self._m_prefill_kv_scored = self.metrics.counter(
             "engine_prefill_kv_positions_scored_total",
             "(query, key) pairs the dispatched prefill programs score, "
-            "a layer and head: per call its width x the keys a query "
+            "a head and K/V layer (their mean, each at its own length "
+            "and window): per call its width x the keys a query "
             "of it is scored against (decode_cache.keys_scored: the "
-            "width where the call creates its cache and attends among "
-            "its own positions, the cache length where a chunk runs "
-            "the einsum over the cache it was handed)",
+            "width, at most the window, where the call creates its "
+            "cache and attends among its own positions, the cache "
+            "length where a chunk runs the einsum over the cache it "
+            "was handed)",
         )
         self._m_prefill_kv_span = self.metrics.counter(
             "engine_prefill_kv_positions_span_total",
             "(query, key) pairs the dispatched prefill programs span: "
-            "width x cache length a call",
+            "width x cache length a call and K/V layer (their mean)",
         )
         self._m_kv_read = self.metrics.counter(
             "engine_decode_kv_positions_read_total",
             "cache positions the dispatched decode steps fetch, a "
-            "layer and plane: per step and slot the blocks the "
+            "plane and K/V layer (their mean, each at its own length "
+            "and window): per step and slot the blocks the "
             "decode-attention kernel reads at the slot's position "
             "(ceil(length / block_k) x block_k, clipped to the "
             "window), or the whole cache row where the einsum runs",
@@ -1060,7 +1064,7 @@ class ContinuousBatcher:
         self._m_kv_span = self.metrics.counter(
             "engine_decode_kv_positions_span_total",
             "cache positions the dispatched decode steps span: "
-            "steps x slots x cache length",
+            "steps x slots x cache length (the K/V layers' mean)",
         )
         # recurrent leaves have the row first: a slot's share of them
         self._recurrent_row_bytes = cache_bytes["recurrent"] // self._slots
@@ -1169,17 +1173,22 @@ class ContinuousBatcher:
                 for counter, labels in feeds:
                     counter.inc(0, **labels)
             self._routed_seen = np.zeros((self._n_routed,), np.uint32)
-        # The granule in which a decode step reads a cache row: the
-        # kernel's block, asked as the step's trace will ask (under this
-        # engine's mesh), or the whole row where the einsum runs. And
-        # the host's copy of every slot's device position: set at
-        # admission, advanced with each dispatched step, so counting
-        # fetches no device value.
-        self._kv_len = cfg.kv_cache_len or cfg.max_seq_len
-        with use_mesh(mesh):
-            self._kv_block = (
-                decode_attention.cache_block_k(cfg) or self._kv_len
-            )
+        # The K/V layers by what a step reads of each: its plane's
+        # length, its window (a layer that records positions reads under
+        # the model's) and the granule in which a decode step reads a
+        # row: the kernel's block on a dense plane, asked as the step's
+        # trace will ask (under this engine's mesh), or the whole row
+        # where the einsum runs. And the host's copy of every
+        # slot's device position: set at admission, advanced with each
+        # dispatched step, so counting fetches no device value.
+        groups: collections.Counter = collections.Counter()
+        for length, windowed in kv_layers(self._batch_cache_shapes):
+            with use_mesh(mesh):
+                block = decode_attention.cache_block_k(
+                    dataclasses.replace(cfg, kv_cache_len=length)
+                )
+            groups[(length, cfg.sliding_window if windowed else None, block or length)] += 1
+        self._kv_groups = sorted(groups.items(), key=repr)
         self._row_pos = np.zeros((self._slots,), np.int64)
         self._m_phase = self.metrics.histogram(
             "engine_request_phase_seconds",
@@ -2452,16 +2461,28 @@ class ContinuousBatcher:
 
         return body
 
+    def _per_kv_layer(self, count) -> float:
+        """``count(length, window, block)`` over the K/V layers, each at
+        its own, as the mean a layer: where every layer has the same
+        plane, exactly that layer's count."""
+        layers = sum(n for _, n in self._kv_groups)
+        total = sum(n * count(*g) for g, n in self._kv_groups)
+        if not layers:
+            return 0
+        return total // layers if len(self._kv_groups) == 1 else total / layers
+
     def _prefill_pairs(self, width: int, handed_cache: bool) -> dict:
         """What one dispatched prefill program of ``width`` positions
         scores and spans, in (query, key) pairs a layer and head: the
         ``engine.prefill`` span's arguments, and what
         :meth:`_count_prefill` adds to the registry."""
         return {
-            "kv_scored": width * keys_scored(
-                width, self._kv_len, handed_cache
+            "kv_scored": self._per_kv_layer(
+                lambda length, window, _: width * keys_scored(
+                    width, length, handed_cache, window
+                )
             ),
-            "kv_span": width * self._kv_len,
+            "kv_span": self._per_kv_layer(lambda length, *_: width * length),
         }
 
     def _count_prefill(
@@ -2485,13 +2506,16 @@ class ContinuousBatcher:
         self._row_pos = np.minimum(
             self._row_pos + k, cfg.max_seq_len - 1
         )
-        self._m_kv_span.inc(at.size * self._kv_len)
+        self._m_kv_span.inc(
+            self._per_kv_layer(lambda length, *_: at.size * length)
+        )
         self._m_kv_read.inc(
-            int(
-                decode_attention.positions_read(
-                    at + 1, self._kv_len, cfg.sliding_window,
-                    self._kv_block,
-                ).sum()
+            self._per_kv_layer(
+                lambda length, window, block: int(
+                    decode_attention.positions_read(
+                        at + 1, length, window, block
+                    ).sum()
+                )
             )
         )
 

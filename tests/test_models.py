@@ -1137,11 +1137,18 @@ def test_rolling_kv_cache_validation():
         dtype=jnp.float32, remat=False, sliding_window=8, kv_cache_len=10
     )
     model2 = Llama(cfg2)
+    # a call that starts its sequence attends the K/V in hand, so it may
+    # be as wide as it likes; one that was handed the ring may not wrap
+    # onto slots it still attends
+    variables = model2.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32), decode=True,
+    )
     with pytest.raises(ValueError, match="write width"):
         # width-8 write into a 10-slot cache with window 8: 10 < 8+8-1
-        model2.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
-            decode=True,
+        model2.apply(
+            variables, jnp.zeros((1, 8), jnp.int32),
+            positions=jnp.arange(8, 16, dtype=jnp.int32)[None],
+            decode=True, mutable=["cache"],
         )
 
 

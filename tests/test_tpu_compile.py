@@ -430,3 +430,85 @@ def test_engine_prefill_compiles_for_v5e_with_the_flash_kernel(
     ).compile().as_text()
     assert "tpu_custom_call" in text
     assert not re.findall(rf"\[(?:\d+,)*{width},{cfg.max_seq_len}\]", text)
+
+
+def _phi4_programs(one_chip):
+    """The SambaY cell's engine programs at its published widths, slots
+    and cache, six layers deep (each kind of layer once, the windowed and
+    the Mamba layer twice), compiled for the chip; programs only."""
+    from tensorflowonspark_tpu.models.phi4_flash import Phi4Flash, Phi4FlashConfig
+    from tensorflowonspark_tpu.serving.engine import ContinuousBatcher
+
+    cfg = Phi4FlashConfig(
+        vocab_size=2048, num_layers=6,
+        layer_types=("mamba", "swa", "mamba", "full", "gmu", "cross"),
+        max_seq_len=3072,
+    )
+    model = Phi4Flash(cfg)
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    ))
+    eng = ContinuousBatcher.__new__(ContinuousBatcher)
+    eng._model, eng._mesh, eng._slots, eng._params = model, None, 128, params
+    eng._block_cache, eng._prefill_cache = {}, {}
+    return cfg, eng, spec
+
+
+def test_sambay_decode_block_compiles_for_v5e(one_chip, monkeypatch):
+    """``jit_block`` k=8 at 128 slots: every K/V read is the
+    decode-attention kernel (the ring, the full layer's plane, the cross
+    layer over it), the planes lie flat (a position's 10 pairs as rows,
+    no padding to 16) and none is copied whole."""
+    monkeypatch.setattr(attn_mod, "TREAT_AS_TPU", True)
+    from tensorflowonspark_tpu.serving.engine import _BIAS_SLOTS
+
+    cfg, eng, spec = _phi4_programs(one_chip)
+    slots, vocab = 128, cfg.vocab_size
+    cache = jax.tree.map(spec, eng._cache_shapes(slots))
+    assert cache["layer3"]["attn"]["k"].shape == (slots, 3072 * 10, 128)
+    assert cache["layer1"]["attn"]["k"].shape == (slots, 512 * 10, 128)
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = eng._block_fn(8).lower(
+        eng._params, cache, arr(i32, slots), arr(i32, slots), arr(f32, slots),
+        arr(i32, slots), arr(f32, slots, 3), arr(jnp.uint32, slots),
+        arr(f32, slots, 2), arr(f32, slots, vocab),
+        arr(i32, slots, _BIAS_SLOTS), arr(f32, slots, _BIAS_SLOTS),
+        arr(jnp.bool_, 4),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%decode_attention[.0-9]* = ", text)) == 3
+    assert not re.findall(r"= bf16\[128,(3072|512),10,128\]", text)
+    assert not re.findall(r"= bf16\[128,(30720|5120),128\]\S* copy\(", text)
+
+
+def test_sambay_prefill_compiles_for_v5e_with_the_scan_kernel(one_chip, monkeypatch):
+    """The 1024-wide prefill: the Mamba layers' scan is the Pallas kernel,
+    the attention layers the flash kernel among the prompt's own
+    positions, the cross layer the decode-attention kernel at the last
+    position over the full layer's plane."""
+    monkeypatch.setattr(attn_mod, "TREAT_AS_TPU", True)
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a: one)
+    from tensorflowonspark_tpu.serving.engine import _BIAS_SLOTS
+
+    _, eng, _ = _phi4_programs(one_chip)
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32, i32, w = jnp.float32, jnp.int32, 1024
+    text = eng._prefill_fn(w).lower(
+        eng._params, arr(i32, 1, w), arr(i32, 1), arr(f32, 1), arr(i32, 1),
+        arr(f32, 1, 3), arr(jnp.uint32, 1), arr(i32, 1, _BIAS_SLOTS),
+        arr(f32, 1, _BIAS_SLOTS),
+    ).compile().as_text()
+    assert len(re.findall(r"%selective_scan[.0-9]* = ", text)) == 2
+    assert len(re.findall(r"%decode_attention[.0-9]* = ", text)) == 1
