@@ -3,12 +3,24 @@ KV cache, reading only what the row has written.
 
 The cache is taken as the model stores it, ``(rows, C, kv_heads, d)``,
 and a grid step streams one block of ``block_k`` positions with all its
-KV heads. Each row's written length arrives as a prefetched scalar: the
-K and V index maps clamp the block index into the row's live range, so a
-step outside it names a block that is already resident and the pipeline
-starts no DMA for it, and ``pl.when`` skips its compute. A row
-of 600 written positions in a cache of 2560 moves a quarter of the
-bytes the masked einsum over the whole plane moves.
+KV heads. The grid visits only the live blocks: the positions
+``max(0, length - window) .. length - 1`` of each row, by its written
+length. It is one dimension long, one step per live (row, block) pair,
+the rows in order and each row's blocks in order, and its extent is the
+traced count of those pairs. Two scalar-prefetched tables, built with
+XLA from the lengths (:func:`step_tables`), give each step its row and
+its block; the K and V index maps read the block, the query's and the
+output's read the row. A row of 600 written positions in a cache of
+2560 moves a quarter of the bytes the masked einsum over the whole plane
+moves, in three grid steps of 256 where a grid over every block would
+take ten.
+
+The pipeline fetches one step ahead, so a row's first block is fetched
+under the previous row's last block of work. A row's first DMA must
+never be exposed: a grid whose live blocks came first in each row lost
+a block's copy time per row, and one that padded every row to all its
+blocks hid it under dead steps that cost a step's fixed time each. Here
+every step is live and every copy but the first of the call is hidden.
 
 Inside a block the position and KV-head dimensions are one: the block is
 read as ``(block_k * kv_heads, d)`` rows, which is how it lies in memory,
@@ -22,6 +34,9 @@ of the sublanes it is stored in. Logits, the running maximum, the sum
 and the accumulator are float32 (online softmax across the row's
 blocks); probabilities narrow to the cache's dtype before the second
 matmul, as the einsum path narrows them.
+
+The Pallas interpreter (``INTERPRET``, on the CPU) runs the same kernel
+over the same traced extent.
 """
 
 from __future__ import annotations
@@ -96,18 +111,41 @@ def _head_bias(heads: int, heads_padded: int, kv_heads: int, block_k: int):
     return np.where(own == col, 0.0, NEG_INF).astype(np.float32)
 
 
+def step_tables(lengths, C: int, window: int | None, block_k: int):
+    """The grid of a call: its extent, the count of live (row, block)
+    pairs, and per step the row and the block, the rows in order and each
+    row's blocks in order (``jnp``, inside the caller's program). Both
+    tables are ``rows * C // block_k`` long, the static bound; the steps
+    past the extent repeat the last live one, so that any entry read there
+    names a block already resident. ``lengths`` as the kernel
+    takes them, in ``[1, C]``: every row has at least one live block."""
+    rows = lengths.shape[0]
+    first, last = live_blocks(jnp, lengths, window, block_k)
+    first = jnp.broadcast_to(first, last.shape)
+    count = last - first + 1
+    end = jnp.cumsum(count)
+    step = jnp.arange(rows * (C // block_k), dtype=jnp.int32)
+    # a step's row: the rows whose steps all come before it
+    row = jnp.minimum(
+        jnp.sum(step[:, None] >= end[None, :], axis=1, dtype=jnp.int32),
+        rows - 1,
+    )
+    blk = jnp.minimum(first[row] + step - (end - count)[row], last[row])
+    return end[-1], row, blk
+
+
 def _kernel(
-    len_ref, q_ref, bias_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+    len_ref, row_ref, blk_ref, q_ref, bias_ref, k_ref, v_ref, o_ref,
+    m_ref, l_ref, acc_ref,
     *, block_k: int, kv_heads: int, scale: float, window: int | None,
 ):
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
+    i = pl.program_id(0)
+    length = len_ref[row_ref[i]]
+    blk = blk_ref[i]
     first, last = live_blocks(jnp, length, window, block_k)
-    # the row's live blocks are its LAST grid steps (see kv_block)
-    blk = j - (pl.num_programs(1) - 1 - last)
     n = block_k * kv_heads
 
-    @pl.when(j == 0)
+    @pl.when(blk == first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -148,11 +186,10 @@ def _kernel(
     whole = (blk + 1) * block_k <= length
     if window is not None:
         whole = whole & (blk * block_k >= length - window)
-    live = blk >= first
-    pl.when(live & whole)(functools.partial(step, False))
-    pl.when(live & ~whole)(functools.partial(step, True))
+    pl.when(whole)(functools.partial(step, False))
+    pl.when(~whole)(functools.partial(step, True))
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(blk == last)
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
 
@@ -176,7 +213,7 @@ def decode_attention(
     a tile's 8 rows); ``lengths`` (rows,) int32, the positions each row has
     written, the query's own included: position ``p`` is attended iff
     ``max(0, length - window) <= p < length``. Returns (rows, heads, d)
-    in ``q``'s dtype. Nothing past a row's last live block is fetched.
+    in ``q``'s dtype. Only a row's live blocks are visited and fetched.
     """
     rows, heads, d = q.shape
     if kv_heads is None:
@@ -197,20 +234,17 @@ def decode_attention(
         q = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
     n = block_k * kv_heads
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, C)
+    steps, row, blk = step_tables(lengths, C, window, block_k)
 
-    def kv_block(r, j, lens):
-        # A row's live blocks first..last take its last grid steps and
-        # the dead steps before them name ``first``: the pipeline, which
-        # fetches one step ahead, then starts a row's first block under
-        # the previous row's last block of work, not under a dead step
-        # that is over before the copy is.
-        first, last = live_blocks(jnp, lens[r], window, block_k)
-        return r, jnp.maximum(j - (C // block_k - 1 - last), first), 0, 0
+    def by_row(i, lens, row, blk):
+        return row[i], 0, 0
 
-    kv_spec = pl.BlockSpec((1, block_k, kv_heads, d), kv_block)
+    kv_spec = pl.BlockSpec(
+        (1, block_k, kv_heads, d), lambda i, lens, row, blk: (row[i], blk[i], 0, 0)
+    )
     if k_cache.ndim == 3:
         kv_spec = pl.BlockSpec(
-            (1, n, d), lambda r, j, lens: kv_block(r, j, lens)[:3]
+            (1, n, d), lambda i, lens, row, blk: (row[i], blk[i], 0)
         )
     out = pl.pallas_call(
         functools.partial(
@@ -218,15 +252,15 @@ def decode_attention(
             window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(rows, C // block_k),
+            num_scalar_prefetch=3,
+            grid=(steps,),
             in_specs=[
-                pl.BlockSpec((1, hp, d), lambda r, j, lens: (r, 0, 0)),
-                pl.BlockSpec((hp, n), lambda r, j, lens: (0, 0)),
+                pl.BlockSpec((1, hp, d), by_row),
+                pl.BlockSpec((hp, n), lambda i, lens, row, blk: (0, 0)),
                 kv_spec,
                 kv_spec,
             ],
-            out_specs=pl.BlockSpec((1, hp, d), lambda r, j, lens: (r, 0, 0)),
+            out_specs=pl.BlockSpec((1, hp, d), by_row),
             scratch_shapes=[
                 pltpu.VMEM((hp, NUM_LANES), jnp.float32),
                 pltpu.VMEM((hp, NUM_LANES), jnp.float32),
@@ -236,8 +270,8 @@ def decode_attention(
         out_shape=jax.ShapeDtypeStruct((rows, hp, d), q.dtype),
         interpret=INTERPRET,
         name="decode_attention",
-    )(lengths, q, jnp.asarray(_head_bias(heads, hp, kv_heads, block_k)),
-      k_cache, v_cache)
+    )(lengths, row, blk, q,
+      jnp.asarray(_head_bias(heads, hp, kv_heads, block_k)), k_cache, v_cache)
     return out[:, :heads]
 
 
@@ -371,8 +405,11 @@ def latent_decode_attention(
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, C)
 
     def latent_block(r, j, lens):
-        # live blocks on the row's last grid steps, as decode_attention's
-        # kv_block has them and for its reason
+        # A row's live blocks take its last grid steps and the dead steps
+        # before them name block 0: the pipeline, which fetches one step
+        # ahead, then starts a row's first block under the previous row's
+        # last block of work, not under a dead step that is over before
+        # the copy is.
         _, last = live_blocks(jnp, lens[r], None, block_k)
         return r, jnp.maximum(j - (C // block_k - 1 - last), 0), 0
 

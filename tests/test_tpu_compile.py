@@ -218,6 +218,29 @@ def test_decode_attention_compiles_for_v5e(one_chip, case):
     assert not _plane_copies(text, rows, C, kv_heads, d)
 
 
+# (C, step calls) of SambaY's decode step: the shared plane of 3072
+# positions, a ring of 512; 128 rows, 40 query rows over 10 K/V pairs of 128
+SAMBAY_PLANES = {"shared_plane": 3072, "ring": 512}
+
+
+@pytest.mark.parametrize("plane", sorted(SAMBAY_PLANES))
+def test_decode_attention_on_a_flat_plane_compiles_for_v5e(one_chip, plane):
+    """The kernel on SambaY's flat planes (a position's 10 pairs as rows):
+    one call named ``decode_attention`` whose result is ``bf16[128,48,128]``
+    (what the benchmark counts a step's calls by), the plane neither
+    copied nor transposed in front of it."""
+    rows, heads, kv_heads, d, C = 128, 40, 10, 128, SAMBAY_PLANES[plane]
+    flat = ((rows, C * kv_heads, d), BF16)
+    text = _compiled_text(
+        lambda q, k, v, n: decode_attention(q, k, v, n, kv_heads=kv_heads),
+        one_chip, ((rows, heads, d), BF16), flat, flat, ((rows,), jnp.int32),
+    )
+    calls = re.findall(r"%decode_attention\S* = bf16\[128,48,128\]\S* custom-call", text)
+    assert len(calls) == 1
+    copies = rf"= bf16\[{rows},{C * kv_heads},{d}\]\S* (copy|transpose)\("
+    assert not [line for line in text.splitlines() if re.search(copies, line)]
+
+
 def _latent_copies(text: str, rows, C, width) -> list[str]:
     plane = rf"= bf16\[{rows},{C},{width}\]\S* (copy|transpose)\("
     return [line.strip() for line in text.splitlines() if re.search(plane, line)]
