@@ -108,6 +108,7 @@ class FalconH1Config:
     differential_lambda_init: None = None
     kv_heads_in_rows: bool = False
     rope_scaling: None = None
+    rotary_dim: None = None
     sliding_window: None = None
     kv_cache_len: None = None
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import flax.linen as nn
 import jax
@@ -47,15 +48,23 @@ from tensorflowonspark_tpu.parallel.context import use_mesh
 
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
-    """Llama-3-style RoPE frequency rescaling (hashable, so configs
-    carrying it still key jit/lru caches).
+    """RoPE frequency rescaling (hashable, so configs carrying it still
+    key jit/lru caches).
 
     ``kind='llama3'``: wavelengths longer than
     ``original_max_seq_len/low_freq_factor`` divide by ``factor``,
     shorter than ``original_max_seq_len/high_freq_factor`` stay, the
     band between interpolates smoothly — the published Llama-3.1
     long-context recipe. ``kind='linear'``: every frequency divides by
-    ``factor`` (position interpolation).
+    ``factor`` (position interpolation). ``kind='yarn'`` (YaRN,
+    arXiv:2309.00071, as HF's ``_compute_yarn_parameters`` computes it):
+    the frequencies that turn more than ``beta_fast`` times over
+    ``original_max_seq_len`` positions stay, those that turn fewer than
+    ``beta_slow`` times divide by ``factor``, a linear ramp over the
+    indices between (the correction range, floored and ceiled); cos and
+    sin are multiplied by ``attention_factor`` (None: ``0.1 ln(factor) +
+    1``). The ``low_freq_factor`` / ``high_freq_factor`` are llama3's,
+    the betas and ``attention_factor`` yarn's.
     """
 
     kind: str = "llama3"
@@ -63,6 +72,19 @@ class RopeScaling:
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_seq_len: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
+
+    @property
+    def cos_sin_scale(self) -> float:
+        """What cos and sin are multiplied by: yarn's attention factor,
+        1 for every other kind."""
+        if self.kind != "yarn":
+            return 1.0
+        if self.attention_factor is not None:
+            return self.attention_factor
+        return 0.1 * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,11 +146,16 @@ class LlamaConfig:
     # (models/solar_open2.py). A Python branch: true traces what it
     # always traced.
     use_rope: bool = True
-    # An elementwise gate on the attention's output before ``o_proj``:
-    # ``sigmoid(g_proj(x))`` from the layer's input, one value an output
-    # channel (models/solar_open2.py). False makes no ``g_proj`` and
-    # traces nothing.
-    attention_output_gate: bool = False
+    # A gate on the attention's output before ``o_proj``:
+    # ``sigmoid(g_proj(x))`` from the layer's input. True: one value an
+    # output channel (models/solar_open2.py); "per_head": one value a
+    # query head, over its head_dim outputs (models/laguna.py). False
+    # makes no ``g_proj`` and traces nothing.
+    attention_output_gate: bool | str = False
+    # Rotate only the first ``rotary_dim`` channels of each query and key
+    # head (a partial rotary factor; models/laguna.py's full layers). None
+    # rotates the whole head and traces what it always traced.
+    rotary_dim: int | None = None
     # Differential attention (Diff Transformer, arXiv:2410.05258) with
     # this lambda_init: each head is two softmax maps over the same keys,
     # the second taken away at a learned weight, then normed
@@ -273,6 +300,8 @@ def _scaled_rope_freqs(
         return freqs
     if scaling.kind == "linear":
         return freqs / scaling.factor
+    if scaling.kind == "yarn":
+        return _yarn_freqs(freqs, d, theta, scaling)
     if scaling.kind != "llama3":
         raise ValueError(f"unknown rope_scaling kind {scaling.kind!r}")
     # Llama-3.1 recipe: long wavelengths compress by `factor`, short
@@ -291,18 +320,49 @@ def _scaled_rope_freqs(
     return jnp.where(mid, interp, out)
 
 
+def _yarn_freqs(freqs, d: int, theta: float, scaling: "RopeScaling"):
+    """YaRN's blend of ``freqs`` (extrapolated) and ``freqs / factor``
+    (interpolated) over a ramp between the correction range's ends."""
+    orig = float(scaling.original_max_seq_len)
+
+    def correction_dim(rotations):
+        return d * math.log(orig / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    # ramp 0: the frequency kept (extrapolated); 1: divided by the factor
+    return freqs / scaling.factor * ramp + freqs * (1 - ramp)
+
+
 def rope(
     x: jax.Array,
     positions: jax.Array,
     theta: float,
     scaling: "RopeScaling | None" = None,
+    rotary_dim: int | None = None,
 ) -> jax.Array:
-    """Rotary embedding; x (B, S, H, D), positions (B, S)."""
+    """Rotary embedding; x (B, S, H, D), positions (B, S). ``scaling``
+    rescales the frequencies (``RopeScaling``: ``llama3``, ``linear`` or
+    ``yarn``, whose attention factor multiplies cos and sin).
+    ``rotary_dim`` (the partial rotary fraction times D): only the first
+    ``rotary_dim`` channels of a head rotate, split in halves as HF's
+    ``rotate_half`` splits them, with the frequencies of a head of that
+    width; the others pass through. None rotates the whole head."""
     d = x.shape[-1]
+    if rotary_dim is not None and rotary_dim != d:
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, theta, scaling),
+             x[..., rotary_dim:]], axis=-1,
+        )
     freqs = _scaled_rope_freqs(d, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaling is not None and scaling.cos_sin_scale != 1.0:
+        cos, sin = cos * scaling.cos_sin_scale, sin * scaling.cos_sin_scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -357,10 +417,11 @@ class QDense(nn.Module):
 
 
 class Attention(nn.Module):
-    """Grouped-query attention with rotary positions (``cfg.use_rope``)
-    and, in ``decode=True``, the static-shape KV cache; where
+    """Grouped-query attention with rotary positions (``cfg.use_rope``,
+    over the first ``cfg.rotary_dim`` channels of a head where that is
+    set) and, in ``decode=True``, the static-shape KV cache; where
     ``cfg.attention_output_gate`` is set, gated by a projection of its
-    input before ``o_proj``. ``cfg`` is a
+    input before ``o_proj``, a value an output channel or a head. ``cfg`` is a
     :class:`LlamaConfig` or any config that carries the attributes read
     here (``models/falcon_h1.py`` passes its own, whose ``head_dim`` is
     not ``hidden_size / num_heads``).
@@ -414,9 +475,9 @@ class Attention(nn.Module):
         if cfg.key_multiplier != 1.0 and shared_kv is None:
             k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
         if cfg.use_rope:
-            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling, cfg.rotary_dim)
             if shared_kv is None:
-                k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+                k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling, cfg.rotary_dim)
         if shared_kv is not None and decode:
             if s != 1:
                 raise ValueError(
@@ -447,13 +508,16 @@ class Attention(nn.Module):
         else:
             out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
         if cfg.attention_output_gate:
+            per_head = cfg.attention_output_gate == "per_head"
             with jax.named_scope("attn.gate"):
-                gate = dense(cfg.num_heads * cfg.head_dim, "g_proj")(
-                    x, adapter_ids
-                )
-                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
-                    out.dtype
-                )
+                gate = dense(
+                    cfg.num_heads * (1 if per_head else cfg.head_dim), "g_proj"
+                )(x, adapter_ids)
+                gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+                if per_head:
+                    # a head's value over its head_dim outputs
+                    gate = jnp.repeat(gate, cfg.head_dim, axis=-1)
+                out = out * gate
         out = dense(cfg.hidden_size, "o_proj")(out, adapter_ids)
         return (out, (k, v)) if return_kv else out
 

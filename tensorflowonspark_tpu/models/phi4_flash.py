@@ -112,6 +112,7 @@ class AttentionView:
     use_rope: bool = False
     rope_theta: float = 10000.0
     rope_scaling: None = None
+    rotary_dim: None = None
     kv_cache_dtype: str = "model"
     key_multiplier: float = 1.0
     attention_output_gate: bool = False

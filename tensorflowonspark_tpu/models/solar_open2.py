@@ -126,6 +126,7 @@ class SolarOpen2Config:
     attention_impl: str = "auto"
     attention_bias: bool = False
     rope_scaling: None = None
+    rotary_dim: None = None
     sliding_window: None = None
     kv_cache_len: None = None
     kv_cache_dtype: str = "model"

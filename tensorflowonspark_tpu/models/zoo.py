@@ -363,6 +363,36 @@ def _build_phi4_flash(tiny):
     )
 
 
+def _build_laguna(tiny):
+    from tensorflowonspark_tpu.models import laguna as L
+
+    # full size: the defaults are Laguna-XS.2's published config
+    cfg = L.LagunaConfig.tiny() if tiny else L.LagunaConfig()
+    model = L.Laguna(cfg)
+
+    def make_input(b):
+        rng = np.random.default_rng(0)
+        s = min(cfg.max_seq_len, 32 if tiny else 1024)
+        return {
+            "tokens": rng.integers(0, cfg.vocab_size, size=(b, s + 1)).astype(
+                np.int32
+            )
+        }
+
+    def make_loss():
+        token_loss = L.laguna_loss_fn(model)
+        return lambda p, batch: token_loss(p, batch["tokens"])
+
+    return ZooEntry(
+        name="laguna_xs2",
+        kind="tokens",
+        model=model,
+        make_input=make_input,
+        param_shardings=L.laguna_param_shardings,
+        make_loss=make_loss,
+    )
+
+
 _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "resnet18": lambda tiny, nc: _build_resnet("resnet18", tiny, nc),
     "resnet34": lambda tiny, nc: _build_resnet("resnet34", tiny, nc),
@@ -383,6 +413,7 @@ _BUILDERS: dict[str, Callable[..., ZooEntry]] = {
     "pangu_ultra_moe_718b": lambda tiny, nc: _build_pangu_moe(tiny),
     "solar_open2_250b": lambda tiny, nc: _build_solar_open2(tiny),
     "phi4_mini_flash": lambda tiny, nc: _build_phi4_flash(tiny),
+    "laguna_xs2": lambda tiny, nc: _build_laguna(tiny),
 }
 
 

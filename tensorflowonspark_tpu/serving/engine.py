@@ -1061,6 +1061,15 @@ class ContinuousBatcher:
             "(ceil(length / block_k) x block_k, clipped to the "
             "window), or the whole cache row where the einsum runs",
         )
+        self._m_kv_read_kind = self.metrics.counter(
+            "engine_decode_kv_positions_read_by_kind_total",
+            "cache positions the dispatched decode steps fetch, as "
+            "engine_decode_kv_positions_read_total counts them, summed "
+            "over the K/V layers of a kind: window (a layer that reads "
+            "under a window, ring or dense plane) or full (one that "
+            "reads every position before), each layer at its own "
+            "length and window",
+        )
         self._m_kv_span = self.metrics.counter(
             "engine_decode_kv_positions_span_total",
             "cache positions the dispatched decode steps span: "
@@ -1189,6 +1198,8 @@ class ContinuousBatcher:
                 )
             groups[(length, cfg.sliding_window if windowed else None, block or length)] += 1
         self._kv_groups = sorted(groups.items(), key=repr)
+        for kind in sorted({"window" if g[1] else "full" for g, _ in self._kv_groups}):
+            self._m_kv_read_kind.inc(0, kind=kind)
         self._row_pos = np.zeros((self._slots,), np.int64)
         self._m_phase = self.metrics.histogram(
             "engine_request_phase_seconds",
@@ -2509,15 +2520,13 @@ class ContinuousBatcher:
         self._m_kv_span.inc(
             self._per_kv_layer(lambda length, *_: at.size * length)
         )
-        self._m_kv_read.inc(
-            self._per_kv_layer(
-                lambda length, window, block: int(
-                    decode_attention.positions_read(
-                        at + 1, length, window, block
-                    ).sum()
-                )
-            )
-        )
+        read = {
+            g: int(decode_attention.positions_read(at + 1, *g).sum())
+            for g, _ in self._kv_groups
+        }
+        self._m_kv_read.inc(self._per_kv_layer(lambda *g: read[g]))
+        for g, n in self._kv_groups:
+            self._m_kv_read_kind.inc(n * read[g], kind="window" if g[1] else "full")
 
     def _block_compiler_options(self):
         """What the decode block asks of the TPU compiler, None

@@ -189,10 +189,14 @@ def test_bn_stats_kernels_compile_for_v5e(one_chip, kernel, rows, channels):
     assert "tpu_custom_call" in text
 
 
-# (rows, heads, kv_heads, d, C, window) of the two serve cells' decode steps
+# (rows, heads, kv_heads, d, C, window) of the serve cells' decode steps;
+# Laguna's full layers (48 query rows over a plane of 14,336) and windowed
+# ones (64 over a ring of exactly its window: no window given)
 DECODE_CASES = {
     "mistral7b_16x2560": (16, 32, 8, 128, 2560, 4096),
     "falconh1_48x2048": (48, 20, 4, 128, 2048, None),
+    "laguna_plane_48x14336": (48, 48, 8, 128, 14336, None),
+    "laguna_ring_48x512": (48, 64, 8, 128, 512, None),
 }
 
 
@@ -206,7 +210,7 @@ def _plane_copies(text: str, rows, C, kv_heads, d) -> list[str]:
 
 @pytest.mark.parametrize("case", sorted(DECODE_CASES))
 def test_decode_attention_compiles_for_v5e(one_chip, case):
-    """The kernel at both serve cells' shapes, on the cache as stored:
+    """The kernel at the serve cells' shapes, on the cache as stored:
     Mosaic takes the 4-D planes in the layout XLA keeps them in."""
     rows, heads, kv_heads, d, C, window = DECODE_CASES[case]
     plane = ((rows, C, kv_heads, d), BF16)
@@ -316,6 +320,7 @@ def test_kda_chunked_compiles_for_v5e_within_the_parents_temporaries(one_chip):
 @pytest.mark.parametrize("banks,d,f", [
     (40, 4096, 1280), (40, 1280, 4096),  # Solar-Open2: gate / up, down
     (16, 7680, 2048), (16, 2048, 7680),  # the expert cell's
+    (256, 2048, 512), (256, 512, 2048),  # Laguna's: every expert held
 ])
 def test_grouped_matmul_compiles_for_v5e_at_the_tiles_it_chooses(
     one_chip, monkeypatch, rows, banks, d, f
